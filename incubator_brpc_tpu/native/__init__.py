@@ -6,7 +6,10 @@ whose framing/dispatch cycle never touches the GIL, with a built-in
 native echo fast path and a Python callback for everything else, plus a
 pooled-connection client whose round trips run with the GIL released.
 
-Compiled on demand with g++ (cached as _engine.so next to this file);
+Compiled on demand with g++ into ``_engine-<key>.so`` next to this
+file, where ``<key>`` hashes the source and the compile command: a
+stale or foreign build (the chip tool copies untracked files along with
+the tree) never has the current name, so it is never loaded.
 ``available()`` gates every caller so environments without a toolchain
 degrade to the pure-Python transport.
 """
@@ -14,6 +17,8 @@ degrade to the pure-Python transport.
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 import threading
@@ -49,8 +54,33 @@ if SANITIZE not in _SAN_FLAGS:
         f"{sorted(k for k in _SAN_FLAGS if k)} or unset"
     )
 _SUFFIX = f".{SANITIZE}" if SANITIZE else ""
-_SO = os.path.join(_HERE, f"_engine{_SUFFIX}.so")
-_FC_SO = os.path.join(_HERE, f"_fastcall{_SUFFIX}.so")
+
+
+def _engine_cmd():
+    return ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-pthread",
+            *_SAN_FLAGS[SANITIZE], _SRC]
+
+
+def _fastcall_cmd():
+    import sysconfig
+
+    inc = sysconfig.get_paths()["include"]
+    return ["gcc", "-O2", "-shared", "-fPIC", f"-I{inc}",
+            *_SAN_FLAGS[SANITIZE], _FC_SRC]
+
+
+def _keyed_so(stem: str, src: str, cmd) -> str:
+    """``<stem><suffix>-<key>.so``: key = sha256 of the source bytes and
+    the compile command, so the name changes whenever either does."""
+    h = hashlib.sha256()
+    with open(src, "rb") as f:
+        h.update(f.read())
+    h.update("\0".join(cmd).encode())
+    return os.path.join(_HERE, f"{stem}{_SUFFIX}-{h.hexdigest()[:16]}.so")
+
+
+_SO = _keyed_so("_engine", _SRC, _engine_cmd())
+_FC_SO = _keyed_so("_fastcall", _FC_SRC, _fastcall_cmd())
 
 
 def sanitizer_preload(mode: Optional[str] = None) -> Optional[str]:
@@ -245,58 +275,26 @@ def bench_redis(
     }
 
 
-def _build() -> Optional[str]:
-    """Compile engine.cpp → _engine.so if stale/missing; returns error."""
+def _compile(so: str, cmd, stem: str) -> Optional[str]:
+    """Build ``so`` with ``cmd`` unless it exists; returns an error or
+    None.  The temporary name is per process (concurrent test workers
+    may build at once); builds under older keys are removed."""
     try:
-        if os.path.exists(_SO) and os.path.getmtime(_SO) >= os.path.getmtime(
-            _SRC
-        ):
+        if os.path.exists(so):
             return None
-        tmp = _SO + ".tmp"
+        tmp = f"{so}.{os.getpid()}.tmp"
         proc = subprocess.run(
-            [
-                "g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-pthread",
-                *_SAN_FLAGS[SANITIZE],
-                _SRC, "-o", tmp,
-            ],
-            capture_output=True,
-            text=True,
-            timeout=120,
+            [*cmd, "-o", tmp], capture_output=True, text=True, timeout=120,
         )
         if proc.returncode != 0:
-            return f"g++ failed: {proc.stderr[-800:]}"
-        os.replace(tmp, _SO)
-        return None
-    except Exception as e:  # noqa: BLE001
-        return f"build error: {e!r}"
-
-
-def _build_fastcall() -> Optional[str]:
-    """Compile fastcall.c → _fastcall.so (CPython extension).  Optional:
-    callers fall back to ctypes when it's missing, so any failure just
-    means the slower boundary."""
-    try:
-        if os.path.exists(_FC_SO) and os.path.getmtime(
-            _FC_SO
-        ) >= os.path.getmtime(_FC_SRC):
-            return None
-        import sysconfig
-
-        inc = sysconfig.get_paths()["include"]
-        tmp = _FC_SO + ".tmp"
-        proc = subprocess.run(
-            [
-                "gcc", "-O2", "-shared", "-fPIC", f"-I{inc}",
-                *_SAN_FLAGS[SANITIZE],
-                _FC_SRC, "-o", tmp,
-            ],
-            capture_output=True,
-            text=True,
-            timeout=120,
-        )
-        if proc.returncode != 0:
-            return f"gcc failed: {proc.stderr[-400:]}"
-        os.replace(tmp, _FC_SO)
+            return f"{cmd[0]} failed: {proc.stderr[-800:]}"
+        os.replace(tmp, so)
+        for old in glob.glob(os.path.join(_HERE, f"{stem}{_SUFFIX}-*.so")):
+            if old != so:
+                try:
+                    os.remove(old)
+                except OSError:
+                    pass
         return None
     except Exception as e:  # noqa: BLE001
         return f"build error: {e!r}"
@@ -306,7 +304,8 @@ def _load_fastcall(lib) -> None:
     """Import the extension and inject the engine's nc_mux_call address
     (resolved from the already-loaded _engine.so — no link dependency)."""
     global _fastcall
-    if _build_fastcall() is not None:
+    # optional: without the extension callers keep the ctypes boundary
+    if _compile(_FC_SO, _fastcall_cmd(), "_fastcall") is not None:
         return
     try:
         import importlib.util
@@ -334,7 +333,7 @@ def _load():
     with _build_lock:
         if _lib is not None or _lib_err is not None:
             return
-        err = _build()
+        err = _compile(_SO, _engine_cmd(), "_engine")
         if err is not None:
             _lib_err = err
             return

@@ -21,17 +21,41 @@ from jax.experimental.pallas import tpu as pltpu
 
 _LANE = 128
 
+# Grid-block bounds of the copy/checksum kernels.  A block holds at most
+# _BLOCK_ROWS_CAP rows and, widened to the f32 the checksum sums in, at
+# most _BLOCK_F32_BYTES: in and out blocks double-buffer (4 blocks) next
+# to one f32 temporary, so a kernel stays near 10 MiB of v5e's 16 MiB
+# scoped VMEM whatever the row width (256 rows alone overflow it for
+# f32 (4096, 4096) or bf16 (1024, 8192)).
+_BLOCK_ROWS_CAP = 256
+_BLOCK_F32_BYTES = 2 << 20
 
-def _fit_block_rows(m: int, cap: int = 256) -> int:
-    """Largest grid-block row count ≤ cap that divides m — the ONE
+# dtypes the Mosaic kernels compile for (tests/test_chip_compile.py
+# compiles each for v5e).  Everything else, float16 among them (Mosaic:
+# "Invalid vector type for load"), rides the XLA-copy lane.
+KERNEL_DTYPES = frozenset(
+    jnp.dtype(t)
+    for t in (jnp.float32, jnp.bfloat16, jnp.int32, jnp.int16, jnp.int8,
+              jnp.uint32, jnp.uint16, jnp.uint8)
+)
+
+
+def _fit_block_rows(m: int, n: int, dtype) -> int:
+    """Grid-block row count for an (m, n) view of ``dtype`` — the ONE
     place the copy/checksum kernels derive their block layout, so the
-    whole-frame and chunked variants decompose a given array into the
-    SAME block sequence (the property their checksums' bit-equality
-    rests on)."""
-    rows = min(cap, m)
+    whole-frame, chunked and DMA variants decompose a given array into
+    the SAME block sequence (the property their checksums' bit-equality
+    rests on).  The whole view when it fits the block bounds; else the
+    largest power of two under them that divides m.  0 when that block
+    breaks the dtype's sublane tiling (8 rows of 32-bit, 16 of 16-bit,
+    32 of 8-bit): the view does not tile."""
+    cap = max(1, min(_BLOCK_ROWS_CAP, _BLOCK_F32_BYTES // (4 * n)))
+    if m <= cap:
+        return m
+    rows = 1 << (cap.bit_length() - 1)
     while m % rows:
         rows //= 2
-    return max(rows, 1)
+    return rows if rows % (32 // jnp.dtype(dtype).itemsize) == 0 else 0
 
 
 def lanes_view(arr):
@@ -40,38 +64,31 @@ def lanes_view(arr):
     place the lane decomposition is decided: the whole-frame, fused-
     chunked, and pipelined transmit paths must reshape identically or
     their checksums stop being comparable."""
-    if arr.ndim == 2 and arr.shape[1] % _LANE == 0 and arr.shape[0] > 0:
+    if (
+        arr.ndim == 2 and arr.shape[1] % _LANE == 0 and arr.shape[0] > 0
+        and _fit_block_rows(*arr.shape, arr.dtype)
+    ):
         return arr
     total = arr.size
     if total <= 0 or total % _LANE:
         return None
-    lanes = next(
-        m for m in (4096, 2048, 1024, 512, 256, 128) if total % m == 0
-    )
-    return arr.reshape(total // lanes, lanes)
+    for lanes in (4096, 2048, 1024, 512, 256, 128):
+        if total % lanes == 0 and _fit_block_rows(
+            total // lanes, lanes, arr.dtype
+        ):
+            return arr.reshape(total // lanes, lanes)
+    return None
 
 
-def _copy_kernel(in_ref, out_ref):
-    out_ref[:] = in_ref[:]
-
-
-@functools.partial(jax.jit, static_argnames=("chunk_rows",))
-def device_copy(x: jax.Array, chunk_rows: int = 256) -> jax.Array:
-    """HBM→HBM copy through VMEM with a pipelined (auto double-buffered)
-    grid. x must be 2D with last dim a multiple of 128."""
-    m, n = x.shape
-    rows = min(chunk_rows, m)
-    while m % rows:
-        rows //= 2
-    rows = max(rows, 1)
-    grid = (m // rows,)
-    return pl.pallas_call(
-        _copy_kernel,
-        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-        grid=grid,
-        in_specs=[pl.BlockSpec((rows, n), lambda i: (i, 0), memory_space=pltpu.VMEM)],
-        out_specs=pl.BlockSpec((rows, n), lambda i: (i, 0), memory_space=pltpu.VMEM),
-    )(x)
+def _lane_sums(blk):
+    """Per-lane f32 sums of one block: the checksum's one widening rule,
+    shared by every kernel.  Mosaic has no unsigned-to-float cast, so
+    unsigned lanes widen through int32 first (exact for uint8/uint16;
+    uint32 wraps mod 2**32, which keeps the sum a deterministic
+    integrity value)."""
+    if jnp.issubdtype(blk.dtype, jnp.unsignedinteger):
+        blk = blk.astype(jnp.int32)
+    return jnp.sum(blk.astype(jnp.float32), axis=0, keepdims=True)
 
 
 def _copy_csum_kernel(in_ref, out_ref, acc_ref):
@@ -85,20 +102,20 @@ def _copy_csum_kernel(in_ref, out_ref, acc_ref):
     out_ref[:] = blk
     # running checksum per lane-column, folded on host side; f32 sum is
     # the VPU-friendly stand-in for the reference's crc32c framing check
-    acc_ref[:] += jnp.sum(blk.astype(jnp.float32), axis=0, keepdims=True)
+    acc_ref[:] += _lane_sums(blk)
 
 
-@functools.partial(jax.jit, static_argnames=("chunk_rows", "interpret"))
-def device_copy_with_checksum(
-    x: jax.Array, chunk_rows: int = 256, interpret: bool = False
-):
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def device_copy_with_checksum(x: jax.Array, interpret: bool = False):
     """Fused transmit-and-verify: copies the payload and produces a
     per-lane checksum in one pass over HBM (one read instead of two).
     ``interpret=True`` runs the SAME kernel through the Pallas
     interpreter — the off-TPU compile gates exercise the real op's
     semantics instead of a lookalike (pallas_guide: interpret mode)."""
     m, n = x.shape
-    rows = _fit_block_rows(m, chunk_rows)
+    rows = _fit_block_rows(m, n, x.dtype)
+    if not rows:
+        raise ValueError(f"{x.dtype} array of shape {x.shape} does not tile")
     grid = (m // rows,)
     # one spec construction for both paths: only memory_space differs
     # (the interpreter has no VMEM)
@@ -138,7 +155,7 @@ def _copy_csum_carry_kernel(in_ref, carry_ref, out_ref, acc_ref):
 
     blk = in_ref[:]
     out_ref[:] = blk
-    acc_ref[:] += jnp.sum(blk.astype(jnp.float32), axis=0, keepdims=True)
+    acc_ref[:] += _lane_sums(blk)
 
 
 def _copy_csum_carry_slot_kernel(in_ref, carry_ref, slot_ref, out_ref, acc_ref):
@@ -232,7 +249,7 @@ def chunk_plan_for(arr, chunk_bytes: int):
     from incubator_brpc_tpu.utils.segmentation import plan_row_chunks
 
     m, n = v.shape
-    block_rows = _fit_block_rows(m)
+    block_rows = _fit_block_rows(m, n, v.dtype)
     chunks = plan_row_chunks(
         m, n * jnp.dtype(v.dtype).itemsize, chunk_bytes, block_rows
     )
@@ -309,13 +326,17 @@ def device_copy_with_checksum_chunked(
 # grid kernel.  tests/test_ici_pipeline.py pins this in interpret mode.
 
 
-def _dma_copy_csum_body(nstages: int, stage_rows: int, block_rows: int):
+def _dma_copy_csum_body(nstages: int, stage_rows: int, block_rows: int,
+                        aliased: bool):
     """Kernel body factory (static shape closure): double-buffered
-    HBM→VMEM→HBM copy with the chained per-block checksum."""
+    HBM→VMEM→HBM copy with the chained per-block checksum.  ``aliased``
+    takes the donated slot ref that ``input_output_aliases={2: 0}``
+    adds as the third input; it aliases ``out_hbm`` and is never read."""
 
-    def kernel(x_hbm, carry_ref, out_hbm, acc_ref,
-               in_buf, out_buf, in_sems, out_sems):
-        from jax.experimental.pallas import tpu as pltpu  # local: kernel-only
+    def kernel(x_hbm, carry_ref, *refs):
+        if aliased:
+            refs = refs[1:]
+        out_hbm, acc_ref, in_buf, out_buf, in_sems, out_sems = refs
 
         bps = stage_rows // block_rows  # checksum blocks per stage
 
@@ -354,9 +375,7 @@ def _dma_copy_csum_body(nstages: int, stage_rows: int, block_rows: int):
             out_buf[slot] = stage
             a = acc_ref[:]
             for b in range(bps):  # static unroll: block-order additions
-                blk = stage[b * block_rows:(b + 1) * block_rows]
-                a = a + jnp.sum(blk.astype(jnp.float32), axis=0,
-                                keepdims=True)
+                a = a + _lane_sums(stage[b * block_rows:(b + 1) * block_rows])
             acc_ref[:] = a
             out_dma(k, slot).start()
             return 0
@@ -377,7 +396,7 @@ def _dma_call(x, carry, block_rows: int, stage_rows: int,
     nstages = m // stage_rows
     ms = {} if interpret else {"memory_space": pltpu.VMEM}
     lane = pl.BlockSpec((1, n), lambda: (0, 0), **ms)
-    any_spec = pl.BlockSpec(memory_space=pltpu.ANY)
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
     in_specs = [any_spec, lane]
     operands = [x, carry]
     kw = {"interpret": True} if interpret else {}
@@ -386,7 +405,8 @@ def _dma_call(x, carry, block_rows: int, stage_rows: int,
         operands.append(slot)
         kw["input_output_aliases"] = {2: 0}
     return pl.pallas_call(
-        _dma_copy_csum_body(nstages, stage_rows, block_rows),
+        _dma_copy_csum_body(nstages, stage_rows, block_rows,
+                            slot is not None),
         out_shape=(
             jax.ShapeDtypeStruct((m, n), x.dtype),
             jax.ShapeDtypeStruct((1, n), jnp.float32),
@@ -443,42 +463,36 @@ def device_copy_with_checksum_dma_into(
 
 def pallas_stage_rows(v, block_rows: int) -> int:
     """The DMA stage size for lane view ``v`` — segmentation policy
-    (fit_stage_rows) applied to the transfer kernels' block layout."""
+    (fit_stage_rows) applied to the transfer kernels' block layout.
+    0 when the stage is not a whole number of packed rows (2 rows of
+    16-bit, 4 of 8-bit): Mosaic refuses such a VMEM slice, and the DMA
+    lane declines the frame."""
     from incubator_brpc_tpu.utils.segmentation import fit_stage_rows
 
     m, n = v.shape
-    return fit_stage_rows(m, n * jnp.dtype(v.dtype).itemsize, block_rows)
+    itemsize = jnp.dtype(v.dtype).itemsize
+    rows = fit_stage_rows(m, n * itemsize, block_rows)
+    return 0 if rows % max(1, 4 // itemsize) else rows
 
 
 def device_copy_with_checksum_pallas(
     x: jax.Array, chunk_bytes: int = 8 << 20, interpret: bool = False,
-    plan=None, slot=None,
+    plan=None,
 ):
     """Frame-level entry for the Pallas DMA transmit: plans the layout
     (``chunk_plan_for`` — the one plan source, so chaos walks and bench
     step counts agree with the other modes), sizes the VMEM stages, and
-    issues ONE fused kernel dispatch.  ``slot`` (optional, TPU-only) is
-    a donated frame-shaped staging buffer.  Returns (out, csum); raises
+    issues ONE fused kernel dispatch.  Returns (out, csum); raises
     ValueError for arrays that don't lane-tile."""
     v, block_rows, chunks = (
         plan if plan is not None else chunk_plan_for(x, chunk_bytes)
     )
-    if v is None:
+    stage_rows = pallas_stage_rows(v, block_rows) if v is not None else 0
+    if not stage_rows:
         raise ValueError(f"array of shape {x.shape} does not lane-tile")
-    stage_rows = pallas_stage_rows(v, block_rows)
-    if slot is not None and not interpret:
-        try:
-            out, csum = device_copy_with_checksum_dma_into(
-                v, slot, block_rows, stage_rows
-            )
-        except Exception:  # noqa: BLE001 — donation quirk: allocate
-            out, csum = device_copy_with_checksum_dma(
-                v, block_rows, stage_rows, interpret
-            )
-    else:
-        out, csum = device_copy_with_checksum_dma(
-            v, block_rows, stage_rows, interpret
-        )
+    out, csum = device_copy_with_checksum_dma(
+        v, block_rows, stage_rows, interpret
+    )
     return (out if v is x else out.reshape(x.shape)), csum
 
 
@@ -493,8 +507,7 @@ def transmit_array_chunked(arr, chunk_bytes: int = 8 << 20, plan=None):
     walk) doesn't plan twice."""
     from incubator_brpc_tpu.utils.segmentation import MIN_CHUNKS
 
-    use_pallas = _on_tpu(arr) and jnp.issubdtype(arr.dtype, jnp.number)
-    if use_pallas and int(arr.nbytes) >= MIN_CHUNKS * chunk_bytes:
+    if kernel_lane(arr) and int(arr.nbytes) >= MIN_CHUNKS * chunk_bytes:
         v, block_rows, chunks = (
             plan if plan is not None else chunk_plan_for(arr, chunk_bytes)
         )
@@ -522,22 +535,30 @@ def _on_tpu(arr) -> bool:
         return False
 
 
+def kernel_lane(arr) -> bool:
+    """True when ``arr`` may take the Pallas transmit kernels: it lives
+    on TPU and its dtype is one they compile for (KERNEL_DTYPES).  The
+    one gate of every transmit path; what fails it takes the XLA-copy
+    lane."""
+    return _on_tpu(arr) and arr.dtype in KERNEL_DTYPES
+
+
 def transmit_array(arr):
     """One ICI "transmission" of an HBM payload: the op the fabric runs
     per device segment on same-chip delivery (the analog of the wire hop
     RDMA WRITE performs; rdma/rdma_endpoint.cpp CutFromIOBufList).
 
     Runs the fused Pallas copy+checksum when the array tiles onto the
-    VPU lanes, an XLA copy otherwise (and always off-TPU, where the
-    Mosaic kernel can't run). Returns ``(new_array, checksum_or_None)``;
-    nothing here syncs to host — the checksum stays device-resident.
+    VPU lanes (``lanes_view``), an XLA copy otherwise (and always
+    off-TPU, where the Mosaic kernel can't run, or for a dtype outside
+    KERNEL_DTYPES). Returns ``(new_array, checksum_or_None)``; nothing
+    here syncs to host — the checksum stays device-resident.
     """
-    use_pallas = _on_tpu(arr) and jnp.issubdtype(arr.dtype, jnp.number)
-    if use_pallas:
-        if arr.ndim == 2 and arr.shape[1] % _LANE == 0 and arr.shape[0] > 0:
+    if kernel_lane(arr):
+        v = lanes_view(arr)
+        if v is arr:
             return device_copy_with_checksum(arr)
-        total = arr.size
-        if total > 0 and total % _LANE == 0:
+        if v is not None:
             return _transmit_reshaped(arr)
     return _xla_copy(arr), None
 

@@ -1021,12 +1021,12 @@ class Server:
         from incubator_brpc_tpu.parallel.ici import get_fabric
 
         if device is None:
-            try:
-                import jax
+            # no fallback to device=None: a server that cannot find its
+            # chip must fail here, not serve host-placed payloads
+            import jax
 
-                device = jax.devices()[chip_id % len(jax.devices())]
-            except Exception:
-                device = None
+            devices = jax.devices()
+            device = devices[chip_id % len(devices)]
         try:
             self._ici_port = get_fabric().register(
                 (slice_id, chip_id), server=self, device=device

@@ -114,7 +114,7 @@ def test_per_chunk_kernel_chain_matches_whole_frame():
 
     m, n = 384, 128
     x = jnp.asarray(np.random.RandomState(0).randn(m, n).astype(np.float32))
-    block_rows = _fit_block_rows(m)
+    block_rows = _fit_block_rows(m, n, x.dtype)
     acc = jnp.zeros((1, n), jnp.float32)
     outs = []
     for off in range(0, m, 128):
@@ -902,3 +902,112 @@ def test_pallas_stacked_transmit_coalesces_same_shape_segments(
     for (ref, a) in pairs[:4]:
         assert ref.csum is None, "integrity rides the stack checksum"
         np.testing.assert_array_equal(np.asarray(ref.array), np.asarray(a))
+
+
+# ---- PR 21: what the TPU compiler refused (tests/test_chip_compile.py
+# compiles these for v5e; here the interpreter pins their semantics) ----
+
+
+@pytest.mark.parametrize(
+    "dtype", ["float32", "uint8", "uint16", "uint32", "int8", "bfloat16"]
+)
+def test_every_lane_agrees_per_kernel_dtype_interpret(dtype):
+    """Whole-frame, fused-chunked, DMA and donated-slot DMA kernels copy
+    exactly and give ONE checksum for every kernel dtype — unsigned
+    payloads (the cache's uint8 values) widen through int32, since
+    Mosaic has no unsigned->f32 cast (uint32 wraps, deterministically)."""
+    import numpy as np
+    import jax.numpy as jnp
+
+    from incubator_brpc_tpu.ops import transfer as T
+
+    rs = np.random.RandomState(7)
+    if dtype in ("float32", "bfloat16"):
+        x = jnp.asarray(rs.randn(512, 256).astype(np.float32), dtype)
+    else:
+        info = np.iinfo(dtype)
+        x = jnp.asarray(
+            rs.randint(info.min, int(info.max) + 1, (512, 256), np.int64)
+            .astype(dtype)
+        )
+    assert x.dtype in T.KERNEL_DTYPES
+    v, br, chunks = T.chunk_plan_for(x, 64 << 10)
+    sr = T.pallas_stage_rows(v, br)
+    assert len(chunks) >= 2 and sr, (br, chunks, sr)
+    carry = jnp.zeros((1, 256), jnp.float32)
+    into_out, into_acc = T._dma_call(v, carry, br, sr, True,
+                                     slot=jnp.zeros_like(v))
+    runs = {
+        "whole": T.device_copy_with_checksum(x, interpret=True),
+        "fused": T.device_copy_with_checksum_chunked(
+            x, chunk_bytes=64 << 10, interpret=True
+        ),
+        "dma": T.device_copy_with_checksum_pallas(
+            x, chunk_bytes=64 << 10, interpret=True
+        ),
+        "dma_into": (into_out, jnp.sum(into_acc)),
+    }
+    for name, (out, _) in runs.items():
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(x), name)
+    sums = {name: float(c) for name, (_, c) in runs.items()}
+    assert len(set(sums.values())) == 1, sums
+    xn = np.asarray(x)
+    if xn.dtype.kind == "u":
+        xn = xn.astype(np.int32)  # the kernels' widening rule
+    ref = float(np.sum(xn.astype(np.float64)))
+    assert abs(sums["whole"] - ref) <= 1e-5 * max(1.0, abs(ref)), (sums, ref)
+
+
+def test_block_rows_are_sized_by_bytes():
+    """A fixed 256 rows overflowed v5e's VMEM on wide rows; the block is
+    now bounded by bytes too, and a block that breaks the dtype's
+    sublane tiling means the view does not tile."""
+    import jax.numpy as jnp
+
+    from incubator_brpc_tpu.ops.transfer import _fit_block_rows, lanes_view
+
+    f32, bf16, u8 = jnp.float32, jnp.bfloat16, jnp.uint8
+    assert _fit_block_rows(8192, 2048, f32) == 256  # bench plan unchanged
+    assert _fit_block_rows(4096, 4096, f32) == 128
+    assert _fit_block_rows(1024, 8192, bf16) == 64
+    assert _fit_block_rows(1000, 128, f32) == 8
+    assert _fit_block_rows(1000, 128, bf16) == 0  # 8 rows < bf16's 16
+    assert _fit_block_rows(3, 4096, u8) == 3      # a whole view always tiles
+    # 1D values pick the first lane count whose rows tile
+    assert lanes_view(jnp.zeros(1 << 20, u8)).shape == (256, 4096)
+    assert lanes_view(jnp.zeros(4096 * 1000, u8)).shape == (4000, 1024)
+    assert lanes_view(jnp.zeros(1000, u8)) is None
+
+
+def test_dma_lane_declines_stages_of_partial_packed_rows(
+    pipelined_fabric, monkeypatch
+):
+    """Mosaic refuses a DMA stage that is not a whole number of packed
+    rows (2 rows of bf16, 4 of uint8): such a frame takes the whole-frame
+    kernel, counted as a pallas fallback, and still arrives intact."""
+    import functools
+
+    import jax.numpy as jnp
+
+    from incubator_brpc_tpu.ops import transfer as T
+    from incubator_brpc_tpu.parallel.ici import StagingRing, ici_pallas_fallbacks
+
+    monkeypatch.setattr(T, "_on_tpu", lambda arr: True)
+    monkeypatch.setattr(
+        T, "device_copy_with_checksum",
+        functools.partial(T.device_copy_with_checksum, interpret=True),
+    )
+
+    class _Shim:
+        coords = (0, 0)
+        device = None
+        staging = StagingRing(depth=2)
+
+    x = jnp.arange(3 * 4096, dtype=jnp.int32).astype(jnp.uint8).reshape(3, 4096)
+    v, br, _ = T.chunk_plan_for(x, 64)
+    assert T.pallas_stage_rows(v, br) == 0
+    pipelined_fabric.chunk_mode = "pallas"
+    falls0 = int(ici_pallas_fallbacks.get_value())
+    out, csum = pipelined_fabric._transmit_pallas(x, _Shim(), None)
+    assert int(ici_pallas_fallbacks.get_value()) == falls0 + 1
+    assert csum is not None and bool(jnp.array_equal(out, x))

@@ -442,3 +442,29 @@ def test_native_channel_over_uds(tmp_path):
         ch.close()
     finally:
         srv.stop()
+
+
+def test_native_build_is_keyed_on_source_and_flags(tmp_path):
+    """The engine loads only from ``_engine<suffix>-<key>.so``, key =
+    hash of the source and the compile command: an edited source or
+    changed flags name a different file, so a stale or copied-in build
+    is never loaded."""
+    import os
+    import re
+
+    from incubator_brpc_tpu import native
+
+    name = os.path.basename(native._SO)
+    assert re.fullmatch(
+        rf"_engine{re.escape(native._SUFFIX)}-[0-9a-f]{{16}}\.so", name
+    ), name
+    src = tmp_path / "engine.cpp"
+    with open(native._SRC, "rb") as f:
+        src.write_bytes(f.read())
+    cmd = native._engine_cmd()
+    keys = {native._keyed_so("_engine", str(src), cmd)}
+    assert keys == {native._SO}
+    keys.add(native._keyed_so("_engine", str(src), cmd + ["-DSTALE"]))
+    src.write_bytes(src.read_bytes() + b"\n// edited\n")
+    keys.add(native._keyed_so("_engine", str(src), cmd))
+    assert len(keys) == 3

@@ -7,7 +7,7 @@
 #   tools/sanitize.sh asan -k mux  # extra args forwarded to pytest
 #
 # BRPC_NATIVE_SANITIZE selects instrumented build flags and a distinct
-# artifact name (_engine.<mode>.so) inside incubator_brpc_tpu/native;
+# artifact name (_engine.<mode>-<key>.so) inside incubator_brpc_tpu/native;
 # the sanitizer runtime must be LD_PRELOADed because stock CPython is
 # not linked against it.  ASan leak checking is disabled: CPython's
 # arena allocator holds blocks for the process lifetime and the lane
