@@ -19,16 +19,18 @@ registry:
    registry does not know about surface as an explicit ``<dark>``
    bucket — a ledger drifting from reality fails loudly, it never lies.
 
-2. **Device-time attribution** — kernel dispatch sites (FusedKernel,
+2. **Kernel-family attribution** — kernel dispatch sites (FusedKernel,
    the sharded collective, decode step, ICI chunk pipeline, PS
    forward) wrap their dispatch in :class:`kernel_section`, feeding
-   per-family execution counts and device-time EMAs.  Timing is taken
-   at already-sanctioned completion points (the manifested host pulls
-   that already follow a dispatch) — never by adding a ``block_until_ready``
-   to a hot path, so the PR 10 transfer witness stays green.
-   ``/hotspots/device?seconds=N`` arms an on-demand
-   ``jax.profiler.trace`` window and summarizes the always-on counters
-   over it per kernel family.
+   per-family execution counts and host-clock dispatch-time EMAs
+   (``rpc_kernel_dispatch_us_*``: the host's time around a dispatch
+   that is not synchronised, so not device time).  No section adds a
+   ``block_until_ready`` to a hot path, so the transfer witness stays
+   green.  ``/hotspots/device?seconds=N`` arms an on-demand
+   ``jax.profiler.trace`` window: each family's device time there is
+   read from the XLA Modules events of that trace, linked to the
+   section that dispatched them, and the window's rpcz spans are
+   written beside the ``.xplane.pb`` on the trace's clock.
 
 3. **Runtime occupancy sampler** — per-worker run-queue depth, steals,
    runs, parks and task queue-wait from runtime/scheduler's plain
@@ -43,15 +45,20 @@ to account for.
 
 from __future__ import annotations
 
+import bisect
+import json
+import os
 import sys
 import tempfile
 import threading
 import time
-from typing import Dict, Optional
+import warnings
+from typing import Dict, List, Optional
 
 from incubator_brpc_tpu.metrics.multi_dimension import MultiDimension
 from incubator_brpc_tpu.metrics.passive_status import PassiveStatus, Status
 from incubator_brpc_tpu.metrics.reducer import Adder
+from incubator_brpc_tpu.observability import span as _span
 from incubator_brpc_tpu.runtime import scheduler as _sched
 from incubator_brpc_tpu.utils.flags import define_flag
 
@@ -69,7 +76,7 @@ _HBM_FLAG = define_flag(
 _DEVICE_FLAG = define_flag(
     "profiler_device_enabled",
     True,
-    "always-on per-kernel-family device-time attribution",
+    "always-on per-kernel-family dispatch counts and host dispatch time",
     validator=lambda v: isinstance(v, bool),
 )
 _OCC_FLAG = define_flag(
@@ -294,18 +301,18 @@ def render_hbm_growth(top: int = 40) -> str:
 
 
 # ---------------------------------------------------------------------------
-# (2) device-time attribution
+# (2) kernel-family attribution
 # ---------------------------------------------------------------------------
 
 rpc_kernel_executions = MultiDimension(Adder, ["family"]).expose(
     "rpc_kernel_executions"
 )
-rpc_kernel_device_us_total = MultiDimension(Adder, ["family"]).expose(
-    "rpc_kernel_device_us_total"
+rpc_kernel_dispatch_us_total = MultiDimension(Adder, ["family"]).expose(
+    "rpc_kernel_dispatch_us_total"
 )
-rpc_kernel_device_us_ema = MultiDimension(
+rpc_kernel_dispatch_us_ema = MultiDimension(
     lambda: Status(0.0), ["family"]
-).expose("rpc_kernel_device_us_ema")
+).expose("rpc_kernel_dispatch_us_ema")
 
 _EMA_ALPHA = 0.2
 
@@ -316,8 +323,8 @@ class _KernelStat:
     def __init__(self, family: str):
         self.family = family
         self._exec = rpc_kernel_executions.get_stats([family])
-        self._total = rpc_kernel_device_us_total.get_stats([family])
-        self._ema_var = rpc_kernel_device_us_ema.get_stats([family])
+        self._total = rpc_kernel_dispatch_us_total.get_stats([family])
+        self._ema_var = rpc_kernel_dispatch_us_ema.get_stats([family])
         self.ema_us: Optional[float] = None
         self.last_us = 0.0
 
@@ -345,25 +352,40 @@ def _kernel_stat(family: str) -> _KernelStat:
     return st
 
 
-class kernel_section:
-    """Times one kernel-family dispatch window.  Disarmed cost is one
-    flag load; armed cost is two perf_counter reads plus the counter
-    folds.  The window must close at an already-sanctioned completion
-    point (a manifested host pull, or the dispatch return on paths
-    with no pull) — this class never syncs the device itself."""
+# host TraceMe around a section while a profiler session records: the
+# programs launched inside it are charged to its family (device_capture)
+SECTION_PREFIX = "kernel_section:"
 
-    __slots__ = ("family", "_t0")
+
+class kernel_section:
+    """Counts one kernel-family dispatch and times it on the host clock
+    (the dispatch, not the device work: nothing here syncs the device).
+    Disarmed cost is one flag load and one profiler-session check;
+    armed, two perf_counter reads plus the counter folds.  While a
+    profiler session records, the section is also a host trace event
+    (``kernel_section:<family>``) that device_capture links the
+    device's programs to."""
+
+    __slots__ = ("family", "_t0", "_tm")
 
     def __init__(self, family: str):
         self.family = family
         self._t0 = 0
+        self._tm = None
 
     def __enter__(self) -> "kernel_section":
         if _DEVICE_FLAG.value:
             self._t0 = time.perf_counter_ns()
+        if _span._profiler_on():
+            self._tm = sys.modules["jaxlib._profiler"].TraceMe(
+                SECTION_PREFIX + self.family
+            )
+            self._tm.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        if self._tm is not None:
+            self._tm.__exit__(exc_type, exc, tb)
         if self._t0 and exc_type is None:
             _kernel_stat(self.family).note(
                 (time.perf_counter_ns() - self._t0) / 1000.0
@@ -388,12 +410,15 @@ def kernel_snapshot() -> Dict[str, dict]:
 
 
 def render_device(snapshot: Optional[Dict[str, dict]] = None) -> str:
+    """The always-on table: host dispatch time per family (device time
+    comes only from a deep capture, ``?seconds=N``)."""
     snap = snapshot if snapshot is not None else kernel_snapshot()
     out = [
         "--- device",
         f"kernel_families: {len(snap)}",
+        "host dispatch time (device time: ?seconds=N)",
         "",
-        f"{'executions':>12} {'total_us':>14} {'ema_us':>10} "
+        f"{'executions':>12} {'dispatch_us':>14} {'ema_us':>10} "
         f"{'last_us':>10}  family",
     ]
     for family, row in sorted(
@@ -428,13 +453,133 @@ def capture_active() -> bool:
     return _trace_active[0]
 
 
+OUTSIDE_SECTIONS = "<outside any section>"
+
+
+def _stats(ev) -> dict:
+    with warnings.catch_warnings():
+        # jaxlib's stat iterator type warns that it has no __module__
+        warnings.simplefilter("ignore", DeprecationWarning)
+        return {k: v for k, v in ev.stats}
+
+
+def device_us_by_family(pd) -> Optional[Dict[str, float]]:
+    """Device microseconds of a profile's XLA Modules events, per
+    kernel family; None when the profile holds no device program (a
+    CPU backend).
+
+    A launch is followed from the ``kernel_section:<family>`` host event
+    that encloses it: every event nested in a reached event on its line
+    is reached, and so is every event whose flow-consumer id (``_c``)
+    is a reached event's flow-producer id (``_p``) — on a TPU, the
+    Python thread's execute linkage → the PJRT execute → the enqueue on
+    a ``pjrt-tpu-tasks`` thread.  A device program whose ``_c`` or
+    ``run_id`` a reached event carries is charged to the family; any
+    other to ``OUTSIDE_SECTIONS``."""
+    lines = []  # per host line: events sorted by start, their starts
+    consumers: Dict[object, List[tuple]] = {}
+    seeds = []
+    for plane in pd.planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            evs = sorted(
+                ((e.start_ns, e.start_ns + e.duration_ns, e.name, _stats(e))
+                 for e in line.events), key=lambda x: x[0])
+            li = len(lines)
+            lines.append((evs, [x[0] for x in evs]))
+            for ei, (_, _, name, st) in enumerate(evs):
+                if "_c" in st:
+                    consumers.setdefault(st["_c"], []).append((li, ei))
+                if name.startswith(SECTION_PREFIX):
+                    seeds.append((li, ei, name[len(SECTION_PREFIX):]))
+    owner: Dict[tuple, str] = {}  # ("_c" | "run_id", value) -> family
+    seen = set()
+    frontier = list(seeds)
+    while frontier:
+        li, ei, fam = frontier.pop()
+        if (li, ei) in seen:
+            continue
+        seen.add((li, ei))
+        evs, starts = lines[li]
+        t0, t1 = evs[ei][0], evs[ei][1]
+        j = bisect.bisect_left(starts, t0)
+        while j < len(evs) and evs[j][0] <= t1:
+            if evs[j][1] <= t1:
+                st = evs[j][3]
+                if "run_id" in st:
+                    owner.setdefault(("run_id", st["run_id"]), fam)
+                if "_p" in st:
+                    owner.setdefault(("_c", st["_p"]), fam)
+                    for lk, ek in consumers.get(st["_p"], ()):
+                        frontier.append((lk, ek, fam))
+            j += 1
+    out: Dict[str, float] = {}
+    found = False
+    for plane in pd.planes:
+        if not plane.name.startswith("/device:"):
+            continue
+        for line in plane.lines:
+            if line.name != "XLA Modules":
+                continue
+            for e in line.events:
+                found = True
+                st = _stats(e)
+                fam = (owner.get(("_c", st.get("_c")))
+                       or owner.get(("run_id", st.get("run_id")))
+                       or OUTSIDE_SECTIONS)
+                out[fam] = out.get(fam, 0.0) + e.duration_ns / 1000.0
+    return out if found else None
+
+
+def profile_start_ns(pd) -> Optional[int]:
+    """The profile's wall-clock start (its events' offsets are from
+    it): the rpcz span clock times 1000."""
+    for plane in pd.planes:
+        v = _stats(plane).get("profile_start_time")
+        if v is not None:
+            return int(v)
+    return None
+
+
+def _newest_xplane(trace_dir: str) -> Optional[str]:
+    found = []
+    for d, _, files in os.walk(trace_dir):
+        found += [os.path.join(d, f) for f in files if f.endswith(".xplane.pb")]
+    return max(found, key=os.path.getmtime) if found else None
+
+
+def write_capture_spans(path: str, cap, base_ns: Optional[int]) -> None:
+    """The capture's spans as JSON (the /rpcz/export span form), each
+    with ``start_offset_ns`` from the profile's start when known."""
+    from incubator_brpc_tpu.observability.cluster import span_to_dict
+
+    spans = []
+    for sp in cap.spans:
+        d = span_to_dict(sp)
+        if base_ns is not None:
+            d["start_offset_ns"] = sp.start_us * 1000 - base_ns
+        spans.append(d)
+    with open(path, "w") as f:
+        json.dump({
+            "profile_start_time_ns": base_ns,
+            "capture": {"start_us": cap.start_us, "stop_us": cap.stop_us,
+                        "overflow": cap.overflow},
+            "spans": spans,
+        }, f)
+
+
 def device_capture(seconds: float) -> dict:
     """Arm a ``jax.profiler.trace`` window for ``seconds`` and return a
-    per-kernel-family summary of what executed inside it.  The chaos
-    site ``profile.capture`` sits on this path: ``drop`` fails the
-    capture (CaptureError → error page), ``delay_us`` stretches its
-    start.  The trace session is disarmed in a ``finally`` — a failed
-    or chaos-faulted capture can never leak an armed profiler."""
+    per-kernel-family summary of what executed inside it: dispatches
+    and host dispatch time from the counters, device time from the
+    trace's XLA Modules events (None where the trace has none), and the
+    rpcz spans the window captured (also written as ``rpcz_spans.json``
+    beside the ``.xplane.pb``).  The chaos site ``profile.capture`` sits
+    on this path: ``drop`` fails the capture (CaptureError → error
+    page), ``delay_us`` stretches its start.  The trace session is
+    disarmed in a ``finally`` — a failed or chaos-faulted capture can
+    never leak an armed profiler."""
     from incubator_brpc_tpu.chaos import injector as _chaos
 
     seconds = min(max(float(seconds), 0.0), MAX_CAPTURE_SECONDS)
@@ -453,6 +598,7 @@ def device_capture(seconds: float) -> dict:
     try:
         before = kernel_snapshot()
         t0 = time.perf_counter()
+        t0_us = time.time_ns() // 1000
         jax = sys.modules.get("jax")
         trace_dir: Optional[str] = None
         trace_error: Optional[str] = None
@@ -478,6 +624,22 @@ def device_capture(seconds: float) -> dict:
                     trace_error = trace_error or repr(e)
                 _trace_active[0] = False
         after = kernel_snapshot()
+        cap = _span.last_capture()
+        if cap is not None and cap.start_us < t0_us:
+            cap = None  # no span was created inside this window
+        device: Optional[Dict[str, float]] = None
+        spans_file: Optional[str] = None
+        xplane = _newest_xplane(trace_dir) if trace_dir else None
+        if xplane is not None:
+            try:
+                pd = jax.profiler.ProfileData.from_file(xplane)
+                device = device_us_by_family(pd)
+                if cap is not None:
+                    spans_file = os.path.join(
+                        os.path.dirname(xplane), "rpcz_spans.json")
+                    write_capture_spans(spans_file, cap, profile_start_ns(pd))
+            except Exception as e:  # noqa: BLE001 — the counters still stand
+                trace_error = trace_error or repr(e)
         rpc_profiler_captures_total << 1
         families: Dict[str, dict] = {}
         for family, row in after.items():
@@ -487,12 +649,20 @@ def device_capture(seconds: float) -> dict:
                 continue
             families[family] = {
                 "executions": d_exec,
-                "device_us": round(row["total_us"] - prev["total_us"], 1),
+                "dispatch_us": round(row["total_us"] - prev["total_us"], 1),
+                "device_us": (round(device.get(family, 0.0), 1)
+                              if device is not None else None),
                 "ema_us": row["ema_us"],
             }
         return {
             "seconds": round(time.perf_counter() - t0, 3),
             "families": families,
+            "device_outside_sections_us": (
+                round(device.get(OUTSIDE_SECTIONS, 0.0), 1)
+                if device is not None else None),
+            "spans": list(cap.spans) if cap is not None else [],
+            "spans_overflow": cap.overflow if cap is not None else 0,
+            "spans_file": spans_file,
             "trace_dir": trace_dir,
             "trace_error": trace_error,
         }
@@ -505,19 +675,30 @@ def render_capture(result: dict) -> str:
         "--- device capture",
         f"window_s: {result['seconds']}",
         f"trace_dir: {result['trace_dir'] or '(none)'}",
+        f"rpcz spans: {len(result['spans'])} "
+        f"(overflow {result['spans_overflow']}) "
+        f"in {result['spans_file'] or '(none)'}",
     ]
     if result["trace_error"]:
         out.append(f"trace: unavailable ({result['trace_error']}) — "
                    f"summary is counter-based")
+    if result["device_outside_sections_us"] is None:
+        out.append("device_us: n/a (the trace holds no device program)")
+    else:
+        out.append(f"device_us outside any section: "
+                   f"{result['device_outside_sections_us']:.1f}")
     out.append("")
-    out.append(f"{'executions':>12} {'device_us':>14} {'ema_us':>10}  family")
+    out.append(f"{'executions':>12} {'device_us':>14} {'dispatch_us':>14} "
+               f"{'ema_us':>10}  family")
     for family, row in sorted(
         result["families"].items(),
-        key=lambda kv: kv[1]["device_us"],
+        key=lambda kv: (kv[1]["device_us"] or 0.0, kv[1]["dispatch_us"]),
         reverse=True,
     ):
+        dev = row["device_us"]
+        dev_s = f"{dev:>14.1f}" if dev is not None else f"{'n/a':>14}"
         out.append(
-            f"{row['executions']:>12} {row['device_us']:>14.1f} "
+            f"{row['executions']:>12} {dev_s} {row['dispatch_us']:>14.1f} "
             f"{row['ema_us']:>10.1f}  {family}"
         )
     if not result["families"]:

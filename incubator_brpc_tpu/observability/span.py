@@ -9,11 +9,22 @@ pipeline (bounded overhead) into an in-memory SpanDB (the reference
 persists to leveldb; /rpcz browses it either way). The parent span for
 nested client calls lives in task-local storage (reference
 bthread::tls_bls, span.h:75-78).
+
+Capture: while a JAX profiler session records, or between
+``start_capture()`` and ``stop_capture()``, every span is created (no
+sampling budget, whatever ``rpcz_enabled`` says) and, once finished,
+kept whole in a bounded in-memory buffer instead of the Collector.
+``last_capture()`` returns that window's spans, its armed interval and
+the count that overflowed the buffer.  Stamps are wall-clock
+(``time.time_ns() // 1000``), the clock of the profiler's
+``profile_start_time``, so captured spans and device ops share one
+timeline.
 """
 
 from __future__ import annotations
 
-import itertools
+import copy
+import sys
 import threading
 import time
 from collections import deque
@@ -90,10 +101,123 @@ def _admit(joined: bool) -> bool:
     w[1] += 1
     return True
 
+
+# ---- capture: every span of a window, kept whole ---------------------------
+
+CAPTURE_MAX_SPANS = 1 << 17
+
+
+class Capture:
+    """One capture window: its finished spans (at most ``capacity``;
+    the rest are counted in ``overflow``), and its armed interval on
+    the span clock (``stop_us`` 0 while still armed)."""
+
+    __slots__ = ("spans", "start_us", "stop_us", "overflow", "capacity")
+
+    def __init__(self):
+        self.spans: List[Span] = []
+        self.start_us = time.time_ns() // 1000
+        self.stop_us = 0
+        self.overflow = 0
+        self.capacity = CAPTURE_MAX_SPANS
+
+    def add(self, span: "Span") -> None:
+        # unlocked: an append is atomic under the GIL, and a racing
+        # pair at the bound overshoots it by at most one span
+        if len(self.spans) < self.capacity:
+            self.spans.append(span)
+        else:
+            self.overflow += 1
+
+
+# [explicit capture, profiler-session capture, last capture armed]
+_cap = [None, None, None]
+_cap_lock = threading.Lock()
+
+
+def _profiler_on() -> bool:
+    """Whether a JAX profiler session records.  Rebinds itself to
+    jaxlib's ``TraceMe.is_enabled`` once jaxlib is loaded: looked up in
+    sys.modules, so this module imports (and runs) without jax."""
+    global _profiler_on
+    mod = sys.modules.get("jaxlib._profiler")
+    if mod is None:
+        return False
+    _profiler_on = mod.TraceMe.is_enabled
+    return _profiler_on()
+
+
+def _capturing() -> Optional[Capture]:
+    """The armed Capture, or None: the one check each span creation
+    site pays.  A profiler session's capture arms at the first span
+    that sees the session and stops at the first that sees it gone."""
+    cap = _cap[0]
+    if cap is not None:
+        return cap
+    if _profiler_on():
+        return _cap[1] or _arm_session()
+    if _cap[1] is not None:
+        _disarm_session()
+    return None
+
+
+def capture_armed() -> bool:
+    """Whether a capture is armed now (a profiler session records, or
+    start_capture() ran)."""
+    return _capturing() is not None
+
+
+def _arm_session() -> Capture:
+    with _cap_lock:
+        if _cap[1] is None:
+            _cap[1] = _cap[2] = Capture()
+        return _cap[1]
+
+
+def _disarm_session() -> None:
+    with _cap_lock:
+        cap, _cap[1] = _cap[1], None
+        if cap is not None:
+            cap.stop_us = time.time_ns() // 1000
+
+
+def start_capture() -> Capture:
+    """Arm a capture now, until stop_capture(); it replaces the last
+    capture's spans."""
+    with _cap_lock:
+        _cap[0] = _cap[2] = Capture()
+        return _cap[0]
+
+
+def stop_capture() -> Optional[Capture]:
+    """Close the capture start_capture() armed; spans that finish
+    later still join it (they were created inside the window)."""
+    with _cap_lock:
+        cap, _cap[0] = _cap[0], None
+        if cap is not None:
+            cap.stop_us = time.time_ns() // 1000
+        return cap
+
+
+def last_capture() -> Optional[Capture]:
+    """A copy of the last capture armed (None before any): its spans
+    with all their stamps, ``start_us``/``stop_us`` (0 while still
+    armed), ``overflow``.  Held until the next capture arms, so a
+    reader after the window has closed still finds it."""
+    _capturing()  # notice a profiler session that has ended
+    cap = _cap[2]
+    if cap is None:
+        return None
+    out = copy.copy(cap)
+    out.spans = list(cap.spans)
+    return out
+
+
 # Phase timestamps an RPC picks up as it crosses the stack (the
 # reference Span's received/start-parse/start-callback/sent stamps,
 # span.h:47): every field is a wall-clock us, 0 = never reached.
 #   received_us        bytes hit the event dispatcher / fabric CQ
+#   dequeued_us        the CQ drain picked the frame up (TCP: = received)
 #   enqueued_us        parsed message handed to a worker queue
 #   parse_done_us      protocol parse produced the message
 #   callback_start_us  user method entered
@@ -102,6 +226,7 @@ def _admit(joined: bool) -> bool:
 #   sent_us            response bytes flushed to the kernel/fabric
 PHASE_FIELDS = (
     "received_us",
+    "dequeued_us",
     "enqueued_us",
     "parse_done_us",
     "callback_start_us",
@@ -113,17 +238,21 @@ PHASE_FIELDS = (
     # Forward), so /latency_breakdown shows host-vs-device per method
     "device_start_us",
     "device_done_us",
+    # ICI leg: placement and transmit dispatch done, delivery not begun
+    "placed_us",
 )
 
 # Named deltas derived from the stamps (what /latency_breakdown
 # aggregates): (phase, from_field, to_field).
 PHASE_DELTAS = (
-    ("parse", "received_us", "parse_done_us"),
+    ("cq_wait", "received_us", "dequeued_us"),
+    ("parse", "dequeued_us", "parse_done_us"),
     ("queue", "enqueued_us", "callback_start_us"),
     ("callback", "callback_start_us", "callback_done_us"),
     ("device", "device_start_us", "device_done_us"),
     ("write", "callback_done_us", "response_write_us"),
     ("send", "response_write_us", "sent_us"),
+    ("place", "start_us", "placed_us"),
 )
 
 
@@ -143,6 +272,7 @@ class Span(Collected):
         "request_size",
         "response_size",
         "_open",  # one-shot close guard (see _try_close)
+        "_capture",  # the Capture that keeps this span (None: rpcz)
     ) + PHASE_FIELDS
 
     def __init__(self, kind: str, service: str = "", method: str = ""):
@@ -160,13 +290,18 @@ class Span(Collected):
         self.request_size = 0
         self.response_size = 0
         self._open = True
-        # phase fields are intentionally NOT initialised: spans are
-        # created per RPC and 7 slot stores per span are measurable on
-        # the hot path. Readers go through phase() / phase_deltas(),
-        # which default unset slots to 0.
+        self._capture = None
+        # every phase starts at 0 (never reached): the Collector drain
+        # reads each PHASE_DELTAS field of every span, and an unset
+        # slot's read raises (and swallows) an AttributeError, several
+        # times dearer than the one store per field here
+        self.received_us = self.dequeued_us = self.enqueued_us = 0
+        self.parse_done_us = self.callback_start_us = 0
+        self.callback_done_us = self.response_write_us = self.sent_us = 0
+        self.device_start_us = self.device_done_us = self.placed_us = 0
 
     def phase(self, field: str) -> int:
-        """Phase stamp value; 0 when never reached (unset slot)."""
+        """Phase stamp value; 0 when never reached."""
         return getattr(self, field, 0)
 
     def _try_close(self) -> bool:
@@ -181,12 +316,15 @@ class Span(Collected):
 
     @classmethod
     def create_client(cls, service: str, method: str) -> Optional["Span"]:
-        if not _RPCZ_FLAG.value:
+        cap = _capturing()
+        if cap is None and not _RPCZ_FLAG.value:
             return None
         parent: Optional[Span] = task_local.get_local(_TLS_KEY)
-        if not _admit(joined=parent is not None):
+        if cap is None and not _admit(joined=parent is not None):
             return None  # over the creation budget: not traced
         span = cls("client", service, method)
+        if cap is not None:
+            span._capture = cap
         if parent is not None:
             span.trace_id = parent.trace_id
             span.parent_span_id = parent.span_id
@@ -201,13 +339,17 @@ class Span(Collected):
         invocation and restores after — leaving it installed would
         misparent later unrelated spans from the same task/thread into
         this finished trace."""
-        if not _RPCZ_FLAG.value:
-            return None
-        if not _admit(joined=bool(trace_id)):
-            return None  # over the creation budget: not traced
-        # propagated trace ids use the (bounded) joined budget so
-        # sampled traces stay complete across the pod
+        cap = _capturing()
+        if cap is None:
+            if not _RPCZ_FLAG.value:
+                return None
+            # propagated trace ids use the (bounded) joined budget so
+            # sampled traces stay complete across the pod
+            if not _admit(joined=bool(trace_id)):
+                return None  # over the creation budget: not traced
         span = cls("server", service, method)
+        if cap is not None:
+            span._capture = cap
         span.trace_id = trace_id or (fast_rand() & 0x7FFFFFFFFFFF)
         span.parent_span_id = parent_span_id
         return span
@@ -221,12 +363,15 @@ class Span(Collected):
         per-chip legs under their RPC. With require_parent (the
         transport paths) a legless context creates nothing — transport
         frames outside any traced RPC would only be ring noise."""
-        if not _RPCZ_FLAG.value:
+        cap = _capturing()
+        if cap is None and not _RPCZ_FLAG.value:
             return None
         parent: Optional[Span] = task_local.get_local(_TLS_KEY)
         if parent is None and require_parent:
             return None
         span = cls("collective", service, method)
+        if cap is not None:
+            span._capture = cap
         if parent is not None:
             span.trace_id = parent.trace_id
             span.parent_span_id = parent.span_id
@@ -266,6 +411,9 @@ class Span(Collected):
         v = getattr(msg, "received_us", 0)
         if v:
             self.received_us = v
+        v = getattr(msg, "dequeued_us", 0)
+        if v:
+            self.dequeued_us = v
         v = getattr(msg, "parse_done_us", 0)
         if v:
             self.parse_done_us = v
@@ -284,14 +432,23 @@ class Span(Collected):
         if self.kind == "server" and self._try_close():
             self.end_us = now
             self.error_code = self.error_code or error_code
-            self.submit()
+            self._finish()
 
     def end(self, error_code: int = 0):
         if not self._try_close():
             return  # already closed (write-completion vs failure race)
         self.end_us = time.time_ns() // 1000
         self.error_code = error_code
-        self.submit()  # through the Collector sampling pipeline
+        self._finish()
+
+    def _finish(self):
+        """A captured span goes whole into its capture; any other
+        through the Collector sampling pipeline."""
+        cap = self._capture
+        if cap is not None:
+            cap.add(self)
+        else:
+            self.submit()
 
     def speed_limit(self) -> int:
         """Submit-side cap for spans. Creation-side admission already

@@ -196,15 +196,9 @@ class IciPort:
         self.messenger = InputMessenger()
         # completion queue: frames arrive here (the "CQ polled instead
         # of epoll"); consumer runs on the runtime like ProcessEvent.
-        # Queue wait feeds /latency_breakdown's _runtime/ici_cq row.
-        from incubator_brpc_tpu.observability.latency_breakdown import (
-            queue_wait_recorder,
-        )
-
-        self._cq = ExecutionQueue(
-            self._drain_completions,
-            wait_recorder=queue_wait_recorder("ici_cq"),
-        )
+        # Each entry's accept stamp is the frame's rpcz received_us; its
+        # wait shows as each span's cq_wait phase.
+        self._cq = ExecutionQueue(self._drain_completions, stamped=True)
         # receive-window flow control (the RDMA endpoint's sq window /
         # socket _overcrowded analog, rdma_endpoint.h:83-137): bytes
         # delivered but not yet consumed.  A stalled consumer pushes
@@ -240,21 +234,29 @@ class IciPort:
         # than per-frame release, and the steady-state drain pays one
         # lock instead of len(batch)
         released = 0
+        entries = list(batch)
         try:
-            for i, (frame, peer_coords) in enumerate(batch):
+            for i, ((frame, peer_coords, parent), received_us) in enumerate(
+                entries
+            ):
                 released += len(frame)
                 if self.closed:
                     # the finally below releases up to THIS frame; the
                     # undrained rest of the batch would leak its window
                     # bytes (and wedge senders at EOVERCROWDED on a
                     # port reopened at these coords) — count them too
-                    released += sum(len(f) for f, _ in batch[i + 1:])
+                    released += sum(len(e[0][0]) for e in entries[i + 1:])
                     return
                 sock = self._conn_socket(peer_coords)
                 if sock is None or sock.failed:
                     continue
-                # rpcz received stamp: the fabric CQ's epoll-IN analog
-                sock.last_read_event_us = _time.time_ns() // 1000
+                # rpcz stamps: received = deliver accepted the frame (the
+                # fabric CQ's epoll-IN analog), dequeued = picked up here;
+                # the sender's span ids ride beside the frame for
+                # protocols without trace meta (RESP)
+                sock.last_read_event_us = received_us
+                sock.last_dequeued_us = _time.time_ns() // 1000
+                sock.last_read_parent = parent
                 sock.read_buf.append(frame)  # ref move, zero-copy
                 try:
                     # the SAME cut/dispatch loop as TCP, auth gate
@@ -268,7 +270,8 @@ class IciPort:
                     self._queued_bytes -= released
 
     def deliver(self, frame: IOBuf, from_coords: Tuple[int, int],
-                inline_ok: bool = False, force: bool = False) -> bool:
+                inline_ok: bool = False, force: bool = False,
+                parent: Optional[Tuple[int, int]] = None) -> bool:
         """Called by the fabric: enqueue a received frame (a completion).
 
         Server ports and bridge-delivered frames go through the
@@ -288,7 +291,9 @@ class IciPort:
         ``send_batch``), queued deliveries are captured per-port and
         the completion queue wakes once at burst close — except frames
         ≥ BURST_BYPASS_BYTES, which dispatch immediately so bulk
-        receive work overlaps the sender's remaining burst."""
+        receive work overlaps the sender's remaining burst.
+
+        ``parent``: the sender's (trace_id, span_id), or None."""
         if self.closed:
             # close raced the fabric's port() lookup: refuse before any
             # credit is reserved (and before a burst could capture a
@@ -304,8 +309,9 @@ class IciPort:
                 # EOVERCROWDED (socket.h _overcrowded analog)
             self._queued_bytes += n
         socket_mod.g_in_bytes << n
+        item = (frame, from_coords, parent)
         if inline_ok and (self.server is None or self.inline_dispatch):
-            if not self._cq.execute_or_inline((frame, from_coords)):
+            if not self._cq.execute_or_inline(item):
                 # queue already stopped (close raced the send): the
                 # frame will never run — release the reservation and
                 # tell the sender, exactly like the queued path below
@@ -315,9 +321,9 @@ class IciPort:
             return True
         pending = getattr(_BURST_TLS, "pending", None)
         if pending is not None and n < BURST_BYPASS_BYTES:
-            pending.setdefault(self, []).append((frame, from_coords))
+            pending.setdefault(self, []).append(item)
             return True
-        if not self._cq.execute((frame, from_coords)):
+        if not self._cq.execute(item):
             # queue already stopped (close raced the send): the frame
             # will never drain — give its window bytes back instead of
             # leaking them against a port reopened at these coords
@@ -337,7 +343,7 @@ class IciPort:
         ``closed`` pre-check keeps the window microscopic, and the drop
         is LOUD here, never silent."""
         if not self._cq.execute_batch(items):
-            n = sum(len(f) for f, _ in items)
+            n = sum(len(it[0]) for it in items)
             with self._qb_lock:
                 self._queued_bytes -= n
             log_error(
@@ -539,8 +545,10 @@ class IciFabric:
         # parented to the active RPC span so fan-out traces show every
         # per-chip hop (skipped entirely outside a traced RPC)
         leg = Span.create_collective("ici", f"{_fmt(src)}->{_fmt(dst)}")
+        parent = None
         if leg is not None:
             leg.request_size = len(frame)
+            parent = (leg.trace_id, leg.parent_span_id)
         try:
             try:
                 if dst_port.device is not None:
@@ -561,6 +569,10 @@ class IciFabric:
                     log_error("ici send %s->%s failed: %r", src, dst, e)
                     return errors.EINTERNAL
                 raise
+            if leg is not None:
+                # placement and transmit dispatch done; delivery (inline:
+                # the whole receive side) not begun
+                leg.placed_us = _time.time_ns() // 1000
             if not _local_only:
                 # bridged inbound frames (_local_only) are RECEIVED
                 # traffic; counting them here would inflate the
@@ -570,7 +582,7 @@ class IciFabric:
             try:
                 delivered = dst_port.deliver(
                     frame, src, inline_ok=not _local_only,
-                    force=ignore_eovercrowded,
+                    force=ignore_eovercrowded, parent=parent,
                 )
             except BaseException:
                 # deliver may have reserved window credits before the

@@ -56,6 +56,7 @@ class HttpMessage:
         "version",
         "progressive_stream",  # _ProgressiveBody for chunked responses
         "received_us",  # rpcz phase stamps (transport cut loop)
+        "dequeued_us",
         "parse_done_us",
         "enqueued_us",
     )
@@ -71,6 +72,7 @@ class HttpMessage:
         self.version = "HTTP/1.1"
         self.progressive_stream = None
         self.received_us = 0
+        self.dequeued_us = 0
         self.parse_done_us = 0
         self.enqueued_us = 0
 

@@ -26,6 +26,7 @@ on the server and the client accumulates replies per (cid, count).
 from __future__ import annotations
 
 import threading
+import time
 from typing import Dict, List, Optional, Tuple
 
 from incubator_brpc_tpu import errors
@@ -536,13 +537,22 @@ def redis_method_spec() -> _RedisMethodSpec:
 
 # ---- protocol callbacks -----------------------------------------------------
 class _WireMsg:
-    """One parsed wire unit: a reply (client side) or command (server)."""
+    """One parsed wire unit: a reply (client side) or command (server),
+    with the rpcz phase stamps the transport cut loop fills in and, for
+    a command that came over the fabric, the sender's (trace_id,
+    span_id): RESP itself carries no trace meta."""
 
-    __slots__ = ("reply", "command")
+    __slots__ = ("reply", "command", "parent", "received_us", "dequeued_us",
+                 "parse_done_us", "enqueued_us")
 
-    def __init__(self, reply=None, command=None):
+    def __init__(self, reply=None, command=None, parent=None):
         self.reply = reply
         self.command = command
+        self.parent = parent
+        self.received_us = 0
+        self.dequeued_us = 0
+        self.parse_done_us = 0
+        self.enqueued_us = 0
 
 
 def parse(buf: IOBuf, sock, read_eof: bool) -> ParseResult:
@@ -566,7 +576,9 @@ def parse(buf: IOBuf, sock, read_eof: bool) -> ParseResult:
             if sock.is_server_side:
                 if value.type != REPLY_ARRAY or not value.value:
                     return ParseResult.bad()
-                return ParseResult.ok(_WireMsg(command=value))
+                return ParseResult.ok(_WireMsg(
+                    command=value,
+                    parent=getattr(sock, "last_read_parent", None)))
             return ParseResult.ok(_WireMsg(reply=value))
     head = buf.fetch(1)
     if not head:
@@ -595,7 +607,8 @@ def parse(buf: IOBuf, sock, read_eof: bool) -> ParseResult:
     if sock.is_server_side:
         if value.type != REPLY_ARRAY or not value.value:
             return ParseResult.bad()
-        return ParseResult.ok(_WireMsg(command=value))
+        return ParseResult.ok(_WireMsg(
+            command=value, parent=getattr(sock, "last_read_parent", None)))
     return ParseResult.ok(_WireMsg(reply=value))
 
 
@@ -641,6 +654,9 @@ def process_response(msg: _WireMsg, sock) -> None:
     ctrl = pool.lock(cid)
     if ctrl is None:
         return
+    if ctrl._span is not None:
+        # client-side phases of the frame that completed the reply
+        ctrl._span.adopt_message_stamps(msg)
     if ctrl._response is not None:
         ctrl._response._set_replies(replies)
     first_err = next((r for r in replies if r.is_error()), None)
@@ -763,10 +779,46 @@ def _command_bytes(part) -> Optional[bytes]:
 
 
 def process_request(msg: _WireMsg, sock) -> None:
+    from incubator_brpc_tpu.observability.span import (
+        Span,
+        capture_armed,
+        swap_current_span,
+    )
+
     server = sock.server
     service = getattr(getattr(server, "options", None), "redis_service", None)
     parts = msg.command.value
     name = _command_bytes(parts[0])
+    # rpcz server span, while a capture is armed: it joins the sender's
+    # trace when the fabric passed its ids beside the frame, a root over
+    # TCP.  Sampled rpcz leaves it out: each span (this one and the
+    # reply's fabric leg) is Collector work on the serving path, and
+    # they raised a YCSB-B p99 by a third on a TPU v5e host
+    span = None
+    if capture_armed():
+        parent = msg.parent
+        span = Span.create_server(
+            "redis",
+            name.decode("utf-8", "replace").upper()
+            if isinstance(name, bytes) else "",
+            parent[0] if parent else 0,
+            parent[1] if parent else 0,
+        )
+    prev_parent = None
+    if span is not None:
+        span.remote_side = str(sock.remote or "")
+        span.adopt_message_stamps(msg)
+        # scoped as the task-local parent: the reply's fabric leg and
+        # nested calls join this trace
+        prev_parent = swap_current_span(span)
+    try:
+        _serve_command(sock, server, service, parts, name, span)
+    finally:
+        if span is not None:
+            swap_current_span(prev_parent)
+
+
+def _serve_command(sock, server, service, parts, name, span) -> None:
     ticket = None
     if service is None:
         reply = RedisReply.error("ERR this server speaks no redis")
@@ -797,6 +849,8 @@ def process_request(msg: _WireMsg, sock) -> None:
             # socket to decide device-resident vs host-materialized
             # replies
             handler = getattr(service, "handle_conn", None)
+            if span is not None:
+                span.callback_start_us = time.time_ns() // 1000
             try:
                 if handler is not None:
                     reply = handler(cmd, args, sock)
@@ -805,10 +859,18 @@ def process_request(msg: _WireMsg, sock) -> None:
             except BaseException:
                 if ticket is not None:
                     ticket.release()
+                if span is not None:
+                    span.end(errors.EINTERNAL)
                 raise
+            if span is not None:
+                span.callback_done_us = time.time_ns() // 1000
     out = IOBuf()
     pack_reply_into(reply, out)
-    sock.write(out, ignore_eovercrowded=True)
+    if span is not None:
+        # closes at write completion (write_done), as tpu_std's does
+        span.response_size = len(out)
+        span.response_write_us = time.time_ns() // 1000
+    sock.write(out, ignore_eovercrowded=True, span=span)
     if ticket is not None:
         ticket.release()
 

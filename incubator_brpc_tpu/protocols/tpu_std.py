@@ -31,13 +31,15 @@ _MAX_BODY = 2 << 30
 
 
 class TpuStdMessage:
-    __slots__ = ("meta", "payload", "received_us", "parse_done_us", "enqueued_us")
+    __slots__ = ("meta", "payload", "received_us", "dequeued_us",
+                 "parse_done_us", "enqueued_us")
 
     def __init__(self, meta, payload: IOBuf):
         self.meta = meta
         self.payload = payload
         # rpcz phase stamps, filled in by the transport cut loop
         self.received_us = 0
+        self.dequeued_us = 0
         self.parse_done_us = 0
         self.enqueued_us = 0
 

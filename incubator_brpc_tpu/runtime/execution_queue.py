@@ -39,28 +39,34 @@ class ExecutionQueue:
         consumer: Callable[[TaskIterator], None],
         batch_max: int = 64,
         wait_recorder: Optional[Callable[[int], None]] = None,
+        stamped: bool = False,
     ):
         """``wait_recorder(wait_us)`` — optional queue-in/queue-out
         latency observer: each item's time between enqueue and the
         consumer batch picking it up is reported (feeds the _runtime
         rows of /latency_breakdown). A ``gate`` attribute on the
         recorder (a Flag-like object) suppresses even the enqueue-side
-        clock read while ``gate.value`` is false."""
+        clock read while ``gate.value`` is false.
+
+        ``stamped``: every item is stamped when it is accepted (enqueued,
+        or run inline) and the consumer receives ``(item, accepted_us)``
+        pairs — wall-clock us, the rpcz span clock."""
         self._consumer = consumer
         self._batch_max = batch_max
         self._wait_recorder = wait_recorder
         self._wait_gate = getattr(wait_recorder, "gate", None)
-        self._q: deque = deque()  # entries: (item, enqueue_ns | 0)
+        self._stamped = stamped
+        self._q: deque = deque()  # entries: (item, enqueue_us | 0)
         self._lock = threading.Lock()
         self._running = False
         self._stopped = False
         self._drained = threading.Condition(self._lock)
 
     def _entry(self, item):
-        if self._wait_recorder is not None and (
+        if self._stamped or (self._wait_recorder is not None and (
             self._wait_gate is None or self._wait_gate.value
-        ):
-            return (item, _time.monotonic_ns())
+        )):
+            return (item, _time.time_ns() // 1000)
         return (item, 0)
 
     def execute(self, item, urgent: bool = False) -> bool:
@@ -113,7 +119,10 @@ class ExecutionQueue:
                 return True
             self._running = True
         try:
-            self._consumer(TaskIterator([item], stopped=False))
+            self._consumer(TaskIterator(
+                [self._entry(item)] if self._stamped else [item],
+                stopped=False,
+            ))
         except Exception as e:  # noqa: BLE001
             from incubator_brpc_tpu.utils.logging import log_error
 
@@ -137,18 +146,21 @@ class ExecutionQueue:
                     entries = []
                     while self._q and len(entries) < self._batch_max:
                         entries.append(self._q.popleft())
-                    items = [e[0] for e in entries]
+                    items = (
+                        entries if self._stamped else [e[0] for e in entries]
+                    )
                     batch = TaskIterator(items, stopped=False)
             if entries and self._wait_recorder is not None:
                 # queue-out stamp: report each item's wait.  Outside the
                 # queue lock — the recorder is a foreign observer with
                 # its own locks (latency_breakdown); producers must not
                 # contend with recorder work (callback-under-lock rule)
-                now = _time.monotonic_ns()
+                now = _time.time_ns() // 1000
                 for _, t in entries:
                     if t:
                         try:
-                            self._wait_recorder((now - t) // 1000)
+                            # wall clock: a step back reads as no wait
+                            self._wait_recorder(max(0, now - t))
                         except Exception:  # noqa: BLE001
                             pass
             try:

@@ -962,7 +962,8 @@ class Server:
                 # rpcz stamps for the native fallback: the engine cut the
                 # frame off-GIL, so received≈parse_done≈enqueued at entry
                 now_us = _time.time_ns() // 1000
-                msg.received_us = msg.parse_done_us = msg.enqueued_us = now_us
+                msg.received_us = msg.dequeued_us = now_us
+                msg.parse_done_us = msg.enqueued_us = now_us
                 tpu_std.process_request(msg, sock)
         finally:
             if burst:

@@ -130,11 +130,15 @@ class InputMessenger:
             msg = result.message
             # rpcz phase stamps ride on the message to the server span:
             # received = the IN event that carried these bytes (stamped
-            # by the dispatcher / fabric delivery), parse_done = now.
-            # One fused try/one clock read — this runs per message.
+            # by the dispatcher / fabric delivery), dequeued = the fabric
+            # CQ drain picked them up (a kernel socket has no CQ: =
+            # received), parse_done = now.  One fused try/one clock
+            # read — this runs per message.
             try:
                 now = _time.time_ns() // 1000
-                msg.received_us = sock.last_read_event_us or now
+                rx = sock.last_read_event_us or now
+                msg.received_us = rx
+                msg.dequeued_us = sock.last_dequeued_us or rx
                 msg.parse_done_us = now
             except AttributeError:
                 pass  # message type without stamp slots

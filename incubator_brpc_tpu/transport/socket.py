@@ -115,6 +115,10 @@ class Socket:
         # wall-clock us of the latest IN event (rpcz received_us source;
         # set by the event dispatcher / fabric delivery)
         self.last_read_event_us = 0
+        # fabric sockets only: when the CQ drain picked the frame up,
+        # and the sender's (trace_id, span_id) that rode beside it
+        self.last_dequeued_us = 0
+        self.last_read_parent = None
         self.parse_index: Optional[int] = None  # cached protocol index
         self.last_protocol = ""  # protocol of the last request sent
         # HTTP per-connection parse state: MUST reset on slot reuse or a

@@ -277,11 +277,12 @@ def test_receive_window_released_when_port_closes_mid_batch():
     fabric = get_fabric()
     port = fabric.register((0, 93), server=object())
     try:
-        frames = [(IOBuf(b"a" * 128), (0, 94)) for _ in range(5)]
+        # completion-queue entries: ((frame, peer, sender ids), accepted_us)
+        entries = [((IOBuf(b"a" * 128), (0, 94), None), 0) for _ in range(5)]
         with port._qb_lock:
-            port._queued_bytes += sum(len(f) for f, _ in frames)
+            port._queued_bytes += sum(len(e[0][0]) for e in entries)
         port.closed = True
-        port._drain_completions(frames)
+        port._drain_completions(entries)
         assert port._queued_bytes == 0
     finally:
         fabric.unregister(port.coords)

@@ -335,7 +335,7 @@ def _stub_port(fabric, window_bytes=None):
 
     def consumer(batch):
         calls.append(len(batch))
-        for frame, src in batch:
+        for (frame, src, _sender), _accepted_us in batch:
             drained.append(bytes(frame.to_bytes()))
             with port._qb_lock:
                 port._queued_bytes -= len(frame)

@@ -10,6 +10,7 @@ under a spawn storm, and the rpcz ``device`` phase on a batched PS
 Forward.
 """
 
+import json
 import os
 import subprocess
 import sys
@@ -308,6 +309,68 @@ def test_kernel_section_counters_and_gate():
     assert profiling.kernel_snapshot()["test.kern"]["executions"] == (
         snap0["executions"] + 1)
     assert "test.kern" in profiling.render_device()
+    # the host-clock figures are exported under what they measure
+    from incubator_brpc_tpu.metrics.variable import dump_exposed
+
+    names = {n for n, _ in dump_exposed()}
+    assert "rpc_kernel_dispatch_us_total" in names
+    assert not any("kernel_device_us" in n for n in names)
+
+
+def _profile(planes):
+    """A constructed profile: planes of (name, stats, lines of (name,
+    events of (name, start_ns, duration_ns, stats)))."""
+    from types import SimpleNamespace as NS
+
+    return NS(planes=[
+        NS(name=pn, stats=list(pst), lines=[
+            NS(name=ln, events=[NS(name=n, start_ns=s, duration_ns=d,
+                                   stats=list(st.items()))
+                                for n, s, d, st in evs])
+            for ln, evs in lines])
+        for pn, pst, lines in planes])
+
+
+def test_device_time_is_read_from_the_trace_per_family():
+    """A program is charged to the section its launch is followed from:
+    through nested host events and flow ids (a TPU's linkage → PJRT
+    execute → enqueue with the run_id), or a run_id inside the section
+    itself; anything else goes to the outside bucket.  A trace without
+    device programs reads None."""
+    host = ("/host:CPU", [], [
+        ("python3", [
+            (profiling.SECTION_PREFIX + "ici.place", 100, 50, {}),
+            ("Execute linkage", 110, 2, {"_p": 41}),
+            (profiling.SECTION_PREFIX + "ps.forward", 300, 40, {}),
+            ("ExecuteHelper", 310, 5, {"run_id": 8}),
+            ("Execute linkage", 500, 2, {"_p": 43}),  # outside any section
+        ]),
+        ("", [
+            ("PJRT Execute", 112, 30, {"_c": 41}),
+            ("System::Execute", 120, 5, {"_p": 51}),
+            ("PJRT Execute", 502, 30, {"_c": 43}),
+            ("System::Execute", 510, 5, {"_p": 53}),
+        ]),
+        ("pjrt-tpu-tasks/1", [
+            ("IssueSequencedEvent", 126, 20, {"_c": 51}),
+            ("DoEnqueueProgram", 127, 10, {"run_id": 7, "_p": 61}),
+            ("IssueSequencedEvent", 516, 20, {"_c": 53}),
+            ("DoEnqueueProgram", 517, 10, {"run_id": 9, "_p": 63}),
+        ]),
+    ])
+    dev = ("/device:TPU:0", [], [
+        ("XLA Modules", [("jit_copy(1)", 200, 30, {"run_id": 7, "_c": 61}),
+                         ("jit_fwd(2)", 400, 70, {"run_id": 8}),
+                         ("jit_other(3)", 600, 9, {"run_id": 9, "_c": 63})]),
+        ("XLA Ops", [("%copy.1", 200, 30, {"run_id": 7})]),
+    ])
+    env = ("Task Environment", [("profile_start_time", 123)], [])
+    pd = _profile([host, dev, env])
+    assert profiling.device_us_by_family(pd) == {
+        "ici.place": 0.03, "ps.forward": 0.07,
+        profiling.OUTSIDE_SECTIONS: 0.009}
+    assert profiling.profile_start_ns(pd) == 123
+    assert profiling.device_us_by_family(_profile([host, env])) is None
 
 
 def test_concurrent_capture_while_serving(web_server):
@@ -346,10 +409,25 @@ def test_concurrent_capture_while_serving(web_server):
     assert box["result"]["seconds"] >= 0.5
     assert not profiling.capture_active(), "armed trace session leaked"
     ch.close()
-    fams = box["result"]["families"]
+    res = box["result"]
+    fams = res["families"]
     assert fams.get("test.in-window", {}).get("executions", 0) >= 1, fams
-    text = profiling.render_capture(box["result"])
+    # the CPU backend's trace holds no device program: device time is
+    # not measured there, only the host's dispatch time
+    assert fams["test.in-window"]["device_us"] is None
+    assert fams["test.in-window"]["dispatch_us"] > 0
+    # every call made inside the window left its spans in the capture,
+    # written beside the trace on the profile's clock
+    assert any(s.kind == "client" and s.method == "Echo"
+               for s in res["spans"]), len(res["spans"])
+    with open(res["spans_file"]) as f:
+        dumped = json.load(f)
+    assert dumped["profile_start_time_ns"] > 0
+    assert len(dumped["spans"]) == len(res["spans"])
+    assert all(d["start_offset_ns"] >= -1e9 for d in dumped["spans"])
+    text = profiling.render_capture(res)
     assert "--- device capture" in text and "test.in-window" in text
+    assert "rpcz spans:" in text and "n/a" in text
 
 
 def test_chaos_profile_capture_drop_then_recovery(web_server):
