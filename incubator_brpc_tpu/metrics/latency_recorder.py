@@ -277,6 +277,16 @@ class LatencyRecorder(Variable):
                 self._batches.append((threading.current_thread(), buf))
         buf.append(latency_us)
 
+    def update_batched_many(self, values: List[int]) -> None:
+        """update_batched for several observations at once, in order."""
+        tls = self._wtls
+        buf = getattr(tls, "batch", None)
+        if buf is None:
+            buf = tls.batch = []
+            with self._batch_reg_lock:
+                self._batches.append((threading.current_thread(), buf))
+        buf.extend(values)
+
     def _flush_batches(self) -> None:
         """Fold all per-thread batch buffers into the components.
         Concurrent-writer safe under the GIL: we only remove the first

@@ -61,16 +61,22 @@ def _method_key(span) -> str:
     return method or "_unknown"
 
 
-def record_span(span) -> None:
-    """Fold one finished span's phase deltas (called from the Collector
-    drain thread via Span.dump_and_destroy — never the RPC path).
-    update_batched keeps even the drain thread's cost at an append per
-    observation — on a single shared core, drain-thread work still
-    competes with serving."""
-    method = _method_key(span)
-    for phase, delta in span.phase_deltas():
-        recorder(method, phase).update_batched(delta)
-    recorder(method, f"total_{span.kind}").update_batched(span.latency_us)
+def record_spans(spans) -> None:
+    """Fold finished spans' phase deltas, in order (called from the
+    Collector drain thread a slice at a time via Span.dump_many — never
+    the RPC path).  Observations are grouped per recorder and appended
+    with one update_batched_many each: on a single shared core,
+    drain-thread work still competes with serving."""
+    by_rec: Dict[Tuple[str, str], list] = {}
+    for span in spans:
+        method = _method_key(span)
+        for phase, delta in span.phase_deltas():
+            by_rec.setdefault((method, phase), []).append(delta)
+        by_rec.setdefault((method, f"total_{span.kind}"), []).append(
+            span.latency_us
+        )
+    for (method, phase), values in by_rec.items():
+        recorder(method, phase).update_batched_many(values)
 
 
 def queue_wait_recorder(name: str):

@@ -460,11 +460,17 @@ class Span(Collected):
         return _SPAN_RATE_FLAG.value * 32
 
     def dump_and_destroy(self):
-        _span_db.add(self)
+        self.dump_many([self])
+
+    @classmethod
+    def dump_many(cls, spans: List["Span"]) -> None:
+        """One Collector slice: one SpanDB lock and flag read, one
+        /latency_breakdown fold, for every span of the slice."""
+        _span_db.add_many(spans)
         try:
             from incubator_brpc_tpu.observability import latency_breakdown
 
-            latency_breakdown.record_span(self)
+            latency_breakdown.record_spans(spans)
         except Exception:  # noqa: BLE001 — aggregation is best-effort
             pass
 
@@ -550,28 +556,35 @@ class SpanDB:
         return self._db
 
     def add(self, span: Span):
+        self.add_many((span,))
+
+    def add_many(self, spans) -> None:
         """Called from the Collector drain thread (never the RPC path),
-        so the sqlite insert costs nothing on the hot path."""
+        a slice at a time, so the sqlite insert costs nothing on the hot
+        path and the lock, flag read and commit are paid once a slice."""
         with self._lock:
-            self._spans.append(span)
+            self._spans.extend(spans)
             db = self._sqlite()
             if db is not None:
                 try:
-                    db.execute(
+                    db.executemany(
                         "INSERT INTO spans VALUES (?,?,?,?,?,?,?,?,?,?,?)",
-                        (
-                            span.trace_id,
-                            span.span_id,
-                            span.parent_span_id,
-                            span.kind,
-                            span.service,
-                            span.method,
-                            span.start_us,
-                            span.latency_us,
-                            span.error_code,
-                            str(span.remote_side),
-                            span.describe(),
-                        ),
+                        [
+                            (
+                                span.trace_id,
+                                span.span_id,
+                                span.parent_span_id,
+                                span.kind,
+                                span.service,
+                                span.method,
+                                span.start_us,
+                                span.latency_us,
+                                span.error_code,
+                                str(span.remote_side),
+                                span.describe(),
+                            )
+                            for span in spans
+                        ],
                     )
                     db.commit()
                 except Exception:  # noqa: BLE001 — persistence is best-effort

@@ -253,14 +253,19 @@ def test_stitched_trace_across_shard_processes():
         # the local SpanDB holds only the CLIENT side of the trace —
         # the fan-out root and one client span per leg (drained async)
         def local_legs():
+            recent = span_db().recent(300)
             legs = [
                 s
-                for s in span_db().recent(300)
+                for s in recent
                 if s.kind == "client"
                 and s.method == "Echo"
                 and str(s.remote_side) in eps
             ]
-            return legs if len(legs) >= 2 else None
+            # the root ends after its legs and may drain a slice later
+            drained = {s.span_id for s in recent}
+            if len(legs) >= 2 and legs[-1].parent_span_id in drained:
+                return legs
+            return None
 
         legs = _wait_for(local_legs)
         assert legs, "client leg spans never drained"

@@ -532,8 +532,9 @@ def test_stream_metrics_and_status_page():
 
 
 def test_stream_rpcz_span_joined_to_rpc_trace():
-    from incubator_brpc_tpu.utils.flags import set_flag
+    from incubator_brpc_tpu.utils.flags import get_flag, set_flag
 
+    prev = get_flag("rpcz_enabled", True)
     set_flag("rpcz_enabled", True)
     try:
         srv = start_server(StreamingEchoService())
@@ -553,7 +554,7 @@ def test_stream_rpcz_span_joined_to_rpc_trace():
         finally:
             srv.stop()
     finally:
-        set_flag("rpcz_enabled", False)
+        set_flag("rpcz_enabled", prev)
 
 
 # ---- streams over the ICI fabric (device payloads) --------------------------
