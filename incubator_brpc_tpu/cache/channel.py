@@ -13,7 +13,9 @@ manifested scopes only.
 through the store's fused gather into ONE stacked device bulk, which
 `MGetResult` slices rows out of on the consumer device.  ``set_many``
 mirrors it with DMSET — one round trip per routed replica — so bulk
-movers (resharding COPY) cross the wire per destination, not per key.
+movers (resharding COPY) cross the wire per destination, not per key;
+``set_stacked`` sends DMSET's fused form, a (B, L) batch of values as
+one device segment that the replica scatters into its slab rows.
 
 Replication (docs/replication.md): a cache position gains HA by
 listing its member CacheChannels in ``replication.
@@ -32,6 +34,22 @@ from incubator_brpc_tpu.client.controller import Controller
 from incubator_brpc_tpu.protocols import redis as _redis
 from incubator_brpc_tpu.utils.hashes import murmur3_32
 from incubator_brpc_tpu.utils.iobuf import DeviceRef
+
+
+def dmset_fused_command(keys: Sequence[bytes], stacked, lengths) -> tuple:
+    """The components of ``DMSET 1 <lengths> <stacked> <key lengths>
+    <keys>`` (cache/service.py): row i of ``stacked`` ((B, L) uint8)
+    holds key i's value in its first ``lengths[i]`` bytes."""
+    import numpy as np
+
+    keys = [bytes(k) for k in keys]
+    return (
+        "DMSET", b"1",
+        np.fromiter(lengths, "<i4", len(keys)).tobytes(),
+        stacked,
+        np.fromiter(map(len, keys), "<i4", len(keys)).tobytes(),
+        b"".join(keys),
+    )
 
 
 class CacheError(RuntimeError):
@@ -346,6 +364,52 @@ class CacheChannel:
             raise CacheError(
                 0, f"DMSET stored {stored}/{len(pairs)} values"
             )
+        return stored
+
+    def set_stacked(self, keys: Sequence, stacked,
+                    lengths: Optional[Sequence[int]] = None) -> int:
+        """Fused batched SET: row i of ``stacked`` ((B, L) uint8, a
+        device array or host rows) holds key i's value in its first
+        ``lengths[i]`` bytes (all L by default).  Keys group by routed
+        replica as in ``set_many``, and each group ships as ONE fused
+        DMSET whose values cross the fabric as one segment.  Returns
+        the stored count; raises CacheError when any value was refused."""
+        import numpy as np
+
+        bkeys = [k.encode() if isinstance(k, str) else bytes(k) for k in keys]
+        if not bkeys:
+            return 0
+        lengths = np.full(len(bkeys), int(stacked.shape[1])) \
+            if lengths is None else np.fromiter(lengths, np.int64, len(bkeys))
+        balancer = self.balancer()
+        groups: dict = {}
+        if balancer is None:
+            groups[None] = list(range(len(bkeys)))
+        else:
+            from incubator_brpc_tpu.client.load_balancer import SelectIn
+
+            for i, k in enumerate(bkeys):
+                node = balancer.select_server(
+                    SelectIn(request_code=murmur3_32(k))
+                )
+                groups.setdefault(node, []).append(i)
+        if len(groups) == 1:
+            replies = [self._call(bkeys[0], *dmset_fused_command(
+                bkeys, stacked, lengths))]
+        else:
+            calls = []
+            for idxs in groups.values():
+                rows = stacked[np.fromiter(idxs, np.int32, len(idxs))]
+                calls.append((bkeys[idxs[0]], dmset_fused_command(
+                    [bkeys[i] for i in idxs], rows, lengths[idxs])))
+            replies = self._call_window(calls, total_keys=len(bkeys))
+        stored = 0
+        for r in replies:
+            if r.is_error():
+                raise CacheError(0, str(r.value))
+            stored += int(r.value)
+        if stored != len(bkeys):
+            raise CacheError(0, f"DMSET stored {stored}/{len(bkeys)} values")
         return stored
 
     def keys(self) -> List[bytes]:

@@ -16,15 +16,26 @@ hit groups coalesce through the store's fused gather into ONE stacked
 bulk, with a lengths header the client unpacks rows from.  DMSET is the
 write-side mirror — one round trip ingests a whole key range, so the
 resharding coordinator's bulk COPY crosses the wire per DESTINATION,
-not per key.
+not per key.  Its fused form (``DMSET 1 <lengths> <stacked> <key
+lengths> <keys>``) mirrors DMGET's fused reply: a batch of values
+crosses ICI as ONE (B, L) device segment and lands in its slab rows
+through one scatter program, with no per-value parse.
+
+While an rpcz capture is armed, the redis server span gets
+``store_start_us``/``store_done_us`` around the store's part of each
+command (docs/observability.md).
 """
 
 from __future__ import annotations
 
 import threading
+import time
 from typing import List, Optional
 
+import numpy as np
+
 from incubator_brpc_tpu.cache.store import HBMCacheStore
+from incubator_brpc_tpu.observability.span import capture_armed, current_span
 from incubator_brpc_tpu.protocols.memcache import (
     OP_GET,
     STATUS_KEY_NOT_FOUND,
@@ -60,26 +71,36 @@ class HBMCacheService(RedisService):
     # protocols.redis.process_request prefers this over handle()
     def handle_conn(self, command: str, args: List, sock) -> RedisReply:
         self._tls.sock = sock
+        # the redis server span exists only under a capture
+        self._tls.span = current_span() if capture_armed() else None
         try:
             cmd = command.upper()
             if cmd == "DEL":  # python keyword, same aliasing as KVRedisService
-                return RedisReply.integer(
-                    sum(1 for k in args if self.store.delete(k))
-                )
+                self._stamp("store_start_us")
+                n = sum(1 for k in args if self.store.delete(k))
+                self._stamp("store_done_us")
+                return RedisReply.integer(n)
             return self.handle(command, args)
         finally:
-            self._tls.sock = None
+            self._tls.sock = self._tls.span = None
+
+    def _stamp(self, field: str) -> None:
+        span = getattr(self._tls, "span", None)
+        if span is not None:
+            setattr(span, field, time.time_ns() // 1000)
 
     def _value_reply(self, key: bytes) -> RedisReply:
+        self._stamp("store_start_us")
         if _is_ici(self._sock):
             v = self.store.get(key)
-            if v is None:
-                return RedisReply.nil()
-            return RedisReply(REPLY_STRING, v)  # device or host-mode bytes
-        v = self.store.get_host(key)
+        else:
+            v = self.store.get_host(key)
+        self._stamp("store_done_us")
         if v is None:
             return RedisReply.nil()
-        return RedisReply.bulk(v)
+        if isinstance(v, bytes):
+            return RedisReply.bulk(v)
+        return RedisReply(REPLY_STRING, v)  # device, to an ICI peer
 
     # ---- commands (lower-case name == wire name) ---------------------------
     def get(self, key):
@@ -88,7 +109,10 @@ class HBMCacheService(RedisService):
     def set(self, key, value):
         if value is None:
             return RedisReply.error("ERR protocol error: SET value missing")
-        if not self.store.set(key, value):
+        self._stamp("store_start_us")
+        ok = self.store.set(key, value)
+        self._stamp("store_done_us")
+        if not ok:
             return RedisReply.error("ERR value exceeds cache HBM budget")
         return RedisReply.status("OK")
 
@@ -115,49 +139,96 @@ class HBMCacheService(RedisService):
         fused=0: ``payload`` is a per-key array of bulks like MGET."""
         if not keys:
             return RedisReply.error("ERR wrong number of arguments for 'dmget'")
-        values, stacked = self.store.get_many(keys)
-        lengths = RedisReply.array([
-            RedisReply.integer(
-                -1 if v is None
-                else (len(v) if isinstance(v, bytes) else int(v.nbytes))
-            )
-            for v in values
-        ])
-        if stacked is not None and _is_ici(self._sock):
+        ici = _is_ici(self._sock)
+        self._stamp("store_start_us")
+        lengths, stacked, values = self.store.lookup_many(keys, fuse=ici)
+        self._stamp("store_done_us")
+        lengths_r = RedisReply.array([RedisReply.integer(n) for n in lengths])
+        if stacked is not None:
             return RedisReply.array([
                 RedisReply.integer(1),
-                lengths,
+                lengths_r,
                 RedisReply(REPLY_STRING, stacked),
             ])
         per_key = []
-        for k, v in zip(keys, values):
+        for v in values:
             if v is None:
                 per_key.append(RedisReply.nil())
             elif isinstance(v, bytes):
                 per_key.append(RedisReply.bulk(v))
-            elif _is_ici(self._sock):
+            elif ici:
                 per_key.append(RedisReply(REPLY_STRING, v))
             else:
-                per_key.append(RedisReply.bulk(self.store.get_host(k) or b""))
+                per_key.append(RedisReply.bulk(self.store.spill(v)))
         return RedisReply.array([
-            RedisReply.integer(0), lengths, RedisReply.array(per_key),
+            RedisReply.integer(0), lengths_r, RedisReply.array(per_key),
         ])
 
-    def dmset(self, *kv):
-        """Device multi-SET (``DMSET k1 v1 k2 v2 ...``) → integer count
-        of values stored.  The ingest counterpart of DMGET: a resharding
-        COPY range (or any batched writer) lands on a replica as ONE
-        round trip instead of one SET per key — the collective bulk-move
-        leg of the Pallas data plane.  Values over the HBM budget are
-        skipped (count < pairs tells the client which path to retry)."""
-        if not kv or len(kv) % 2:
+    def dmset(self, *args):
+        """Device multi-SET → integer count of values stored.
+
+        ``DMSET k1 v1 k2 v2 ...``: the ingest counterpart of DMGET — a
+        resharding COPY range (or any batched writer) lands on a
+        replica as ONE round trip instead of one SET per key, the
+        collective bulk-move leg of the Pallas data plane.  Byte values
+        land as the fused form's do, one scatter per slab page.
+
+        ``DMSET 1 <lengths> <stacked> <key lengths> <keys>`` (fused;
+        never a pair list, whose arity is even): ``stacked`` is one
+        (B, L) uint8 bulk — a device segment over ICI, B × L host bytes
+        otherwise — whose row i holds key i's value in its first
+        ``lengths[i]`` bytes; ``lengths`` and ``key lengths`` are B
+        little-endian int32 each, ``keys`` the B keys back to back.
+        The rows land through one scatter program per slab page.
+
+        Values over the HBM budget are skipped (count < pairs tells the
+        client which path to retry)."""
+        if len(args) == 5 and args[0] == b"1":
+            return self._dmset_fused(*args[1:])
+        if not args or len(args) % 2:
             return RedisReply.error(
                 "ERR wrong number of arguments for 'dmset'"
             )
-        stored = 0
-        for i in range(0, len(kv), 2):
-            if self.store.set(kv[i], kv[i + 1]):
-                stored += 1
+        keys, values = args[0::2], args[1::2]
+        self._stamp("store_start_us")
+        if all(type(v) is bytes and 0 < len(v) <= self.store.row_max
+               for v in values):
+            # byte values: one scatter program per slab page touched
+            rows = np.zeros((len(values), max(map(len, values))), np.uint8)
+            for row, v in zip(rows, values):
+                row[:len(v)] = np.frombuffer(v, np.uint8)
+            stored = self.store.set_stacked(keys, rows, list(map(len, values)))
+        else:
+            stored = sum(1 for k, v in zip(keys, values) if self.store.set(k, v))
+        self._stamp("store_done_us")
+        return RedisReply.integer(stored)
+
+    def _dmset_fused(self, lengths, stacked, key_lengths, keys):
+        if not all(isinstance(a, bytes) for a in (lengths, key_lengths, keys)):
+            return RedisReply.error("ERR DMSET lengths and keys must be host bulks")
+        if len(lengths) % 4 or len(key_lengths) % 4:
+            return RedisReply.error("ERR DMSET lengths are not int32s")
+        lens = np.frombuffer(lengths, "<i4")
+        klens = np.frombuffer(key_lengths, "<i4")
+        ends = np.cumsum(klens, dtype=np.int64)
+        if len(klens) != len(lens) or (klens < 0).any() or (
+                len(ends) and ends[-1] != len(keys)):
+            return RedisReply.error("ERR DMSET key lengths do not match the keys")
+        starts = (ends - klens).tolist()
+        key_list = [keys[a:b] for a, b in zip(starts, ends.tolist())]
+        if isinstance(stacked, DeviceRef):
+            whole = stacked.whole_array()
+            stacked = whole if whole is not None else bytes(stacked.view())
+        if isinstance(stacked, bytes):
+            if len(lens) == 0 or len(stacked) % len(lens):
+                return RedisReply.error("ERR DMSET stacked bulk is not B rows")
+            stacked = np.frombuffer(stacked, np.uint8).reshape(len(lens), -1)
+        if stacked.ndim != 2 or stacked.shape[0] != len(lens) or (
+                stacked.dtype != np.uint8):
+            return RedisReply.error("ERR DMSET stacked bulk is not (B, L) uint8")
+        self._stamp("store_start_us")
+        stored = self.store.set_stacked(key_list, stacked, lens)
+        self._stamp("store_done_us")
         return RedisReply.integer(stored)
 
     def keys(self, *args):

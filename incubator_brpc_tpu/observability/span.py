@@ -240,6 +240,10 @@ PHASE_FIELDS = (
     "device_done_us",
     # ICI leg: placement and transmit dispatch done, delivery not begun
     "placed_us",
+    # the store's part of a cache command (cache/service.py), stamped
+    # only while a capture is armed
+    "store_start_us",
+    "store_done_us",
 )
 
 # Named deltas derived from the stamps (what /latency_breakdown
@@ -299,6 +303,7 @@ class Span(Collected):
         self.parse_done_us = self.callback_start_us = 0
         self.callback_done_us = self.response_write_us = self.sent_us = 0
         self.device_start_us = self.device_done_us = self.placed_us = 0
+        self.store_start_us = self.store_done_us = 0
 
     def phase(self, field: str) -> int:
         """Phase stamp value; 0 when never reached."""

@@ -44,7 +44,7 @@ from incubator_brpc_tpu.transport import socket as socket_mod
 from incubator_brpc_tpu.transport.input_messenger import InputMessenger
 from incubator_brpc_tpu.transport.socket import Socket, SocketOptions
 from incubator_brpc_tpu.utils.endpoint import EndPoint
-from incubator_brpc_tpu.utils.iobuf import IOBuf, DeviceRef
+from incubator_brpc_tpu.utils.iobuf import IOBuf, DeviceRef, take_handed_off
 from incubator_brpc_tpu.utils.logging import log_error
 
 # thread-local delivery burst (see IciFabric.delivery_burst): while a
@@ -406,8 +406,10 @@ class IciFabric:
         # False (default): same-chip delivery runs every device segment
         # through the Pallas transmit op (ops/transfer.transmit_array) so
         # the payload demonstrably traverses HBM once per hop — the
-        # honest model of an ICI transmission. True: move by reference
-        # (the in-process fast path; no device bytes move).
+        # honest model of an ICI transmission (a segment its producer
+        # handed off, iobuf.hand_off, made that traversal already and
+        # moves by reference). True: move by reference (the in-process
+        # fast path; no device bytes move).
         self.zero_copy = False
         # Large-frame chunk policy (shared with the DCN planner via
         # utils/segmentation.py; docs/ici_pipeline.md):
@@ -477,6 +479,11 @@ class IciFabric:
             ]
 
     def register(self, coords: Tuple[int, int], server=None, device=None) -> IciPort:
+        if device is not None:
+            # the transmit op's module (Pallas) takes most of a second to
+            # import: load it with the port, not inside the first send,
+            # where the caller's RPC deadline is already running
+            import incubator_brpc_tpu.ops.transfer  # noqa: F401
         with self._lock:
             if coords in self._ports and not self._ports[coords].closed:
                 raise ValueError(f"ici coords {coords} already registered")
@@ -675,7 +682,9 @@ class IciFabric:
                 charged = _INFLIGHT_ACCT.adopt(ref.array)
                 if charged:
                     weakref.finalize(ref, _INFLIGHT_ACCT.release, charged)
-            elif not zero_copy:
+            elif not zero_copy and not take_handed_off(arr):
+                # a handed-off buffer (a cache slab read's row slice)
+                # already traversed HBM when its program made it
                 same_chip.append((ref, arr))
         if len(same_chip) > 1 and self.chunk_mode == "pallas":
             # per-destination stacked transmit: same-shape segments of

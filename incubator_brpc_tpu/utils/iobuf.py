@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import ssl as _ssl
 import threading
+import weakref
 from collections import deque
 from typing import Iterable, List, Optional, Tuple
 
@@ -161,6 +162,33 @@ class DeviceRef:
         if self.offset == 0 and self.length == int(self.array.nbytes):
             return self.array
         return None
+
+
+# Buffers their producer gave up to one frame (``hand_off``): a cache
+# slab read's row slice exists only for its reply and traversed HBM
+# when the read made it, so the ICI fabric's same-chip hop moves it by
+# reference instead of copying it a second time.  Keyed by id, holding
+# a weak reference whose callback drops the entry when the array dies,
+# so a reused id never matches.
+_handed_off: dict = {}
+
+
+def _forget(ref) -> None:
+    if _handed_off.get(ref.key) is ref:
+        _handed_off.pop(ref.key, None)
+
+
+def hand_off(array):
+    """Mark ``array`` as a fresh buffer its producer never touches
+    again; returns it."""
+    _handed_off[id(array)] = weakref.KeyedRef(array, _forget, id(array))
+    return array
+
+
+def take_handed_off(array) -> bool:
+    """True, once, for an array given to ``hand_off``."""
+    ref = _handed_off.pop(id(array), None)
+    return ref is not None and ref() is array
 
 
 class IOBuf:

@@ -148,8 +148,13 @@ def test_store_deviceref_whole_array_adopted_zero_copy():
     st = HBMCacheStore(hbm_budget_bytes=1 << 20)
     arr = jnp.arange(64, dtype=jnp.uint8)
     assert st.set(b"dev", DeviceRef(arr))
-    # the ICI SET path: the delivered array is adopted, not copied
-    assert st.get(b"dev") is arr
+    # the ICI SET path: a delivered byte value lands in its slab row
+    # device to device, and reads back bit-equal
+    assert _host_bytes(st.get(b"dev")) == bytes(range(64))
+    # a typed array (an in-process producer's) is adopted, not copied
+    typed = jnp.arange(16, dtype=jnp.float32)
+    assert st.set(b"typed", DeviceRef(typed))
+    assert st.get(b"typed") is typed
 
 
 def test_store_disabled_mode_host_bytes():
@@ -904,10 +909,16 @@ def test_witness_bulk_copy_zero_violations_ledger_balanced():
         w = dw.cross_check()
         assert w["violations"] == [], w["violations"]
         assert dw.retrace_contradictions() == []
-        # ledger balance: after DRAIN every key lives exactly once
+        # ledger balance: after DRAIN every key lives exactly once, in
+        # a slab row, and the ledger holds exactly the stores' pages
         gc.collect()
+        stores = [srv.options.redis_service.store for srv in servers]
+        assert sum(st.hbm_used for st in stores) == 16 * 64
+        assert sum(len(st) for st in stores) == 16
         tags = hbm_profile()["tags"]
-        assert tags.get("cache.values", {{}}).get("bytes") == 16 * 64, tags
+        assert tags.get("cache.slab", {{}}).get("bytes") == sum(
+            st.slab_bytes for st in stores), tags
+        assert "cache.values" not in tags, tags
         for ch in chans:
             ch.close()
         for srv in servers:

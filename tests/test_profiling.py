@@ -101,11 +101,14 @@ def test_hbm_account_contract_and_gate():
 def test_cache_store_accounting_exact_across_evict_replace_flush():
     """cache.values tracks the store bit-exactly through SET, budget
     eviction, replacement, DELETE, and FLUSH — ledger == store's own
-    hbm_used at every step, and back to baseline at the end."""
+    hbm_used at every step, and back to baseline at the end.  Values
+    small enough for slab rows charge their pages to cache.slab, and
+    the two tags together never pass the budget."""
     from incubator_brpc_tpu.cache.store import HBMCacheStore
 
     acct = profiling.hbm_account("cache.values")
-    b0 = acct.live_bytes()
+    slab = profiling.hbm_account("cache.slab")
+    b0, s0 = acct.live_bytes(), slab.live_bytes()
     store = HBMCacheStore(hbm_budget_bytes=3000)
     assert store.set(b"a", b"x" * 1000)
     assert store.set(b"b", b"y" * 1000)
@@ -122,6 +125,17 @@ def test_cache_store_accounting_exact_across_evict_replace_flush():
     assert store.set(b"e", b"q" * 800)
     store.flush()
     assert acct.live_bytes() - b0 == 0, "flush leaked cache.values charge"
+    # slab rows (at most 1/16 of the budget wide: 128 B here) beside a
+    # whole entry: the pages and the entry stay within the budget
+    assert store.set(b"w", b"q" * 2000)
+    for i in range(40):
+        assert store.set(b"s%d" % i, bytes([i]) * 100)
+        held = (acct.live_bytes() - b0) + (slab.live_bytes() - s0)
+        assert held == store.hbm_held <= store.budget
+    assert slab.live_bytes() - s0 == store.slab_bytes > 0
+    assert store.get_host(b"s39") == bytes([39]) * 100
+    store.flush()
+    assert slab.live_bytes() - s0 == 0, "flush leaked cache.slab pages"
 
 
 def test_staging_ring_accounting_acquire_release_evict():
@@ -201,13 +215,13 @@ svc = PsService()
 svc.put_param("w", np.ones((128, 128), np.float32))
 p = profiling.hbm_profile()
 assert p["census"]["available"], p["census"]
-assert p["tags"]["cache.values"]["bytes"] >= 8 * 4096, p["tags"]
+assert p["tags"]["cache.slab"]["bytes"] >= 8 * 4096, p["tags"]
 assert p["tags"]["ps.params"]["bytes"] >= 0, p["tags"]
 span = max(1, p["census"]["bytes"] - p["census_baseline"])
 frac = p["dark_bytes"] / span
 assert frac < 0.05, (p["dark_bytes"], span, p["tags"])
 text = profiling.render_hbm(p)
-assert "<dark>" in text and "cache.values" in text
+assert "<dark>" in text and "cache.slab" in text
 rep = dw.cross_check()
 assert rep["violations"] == [], rep["violations"]
 print("HBM-DARK-OK %.4f" % frac)
@@ -264,6 +278,7 @@ def test_hbm_growth_page_diffs_across_forced_eviction(web_server):
     assert "baseline captured" in body or "growth since last fetch" in body
     # force an eviction (replacement wave shrinks the resident set)
     assert store.set(b"g2", b"b" * 1000)  # evicts g1: -4000 +1000
+    assert store.hbm_held == 1000 <= store.budget
     st, body = _http_get(web_server.port, "/hotspots/hbm?growth=1")
     assert st == 200
     assert "growth since last fetch" in body
