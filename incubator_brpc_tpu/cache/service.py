@@ -21,9 +21,21 @@ lengths> <keys>``) mirrors DMGET's fused reply: a batch of values
 crosses ICI as ONE (B, L) device segment and lands in its slab rows
 through one scatter program, with no per-value parse.
 
+Drain batches: inside a server port's drain scope (``runtime/drain.py``)
+a GET, and a SET of a slab-row value (host bytes of 1 to the store's
+``row_max``), from an ICI peer is deferred to the batch's close, where
+the batch's deferred commands are one ``HBMCacheStore.apply_batch`` call
+(one device program for the lot) and their replies leave in arrival
+order.  The call applies the SETs, then the GETs; a SET that follows a
+GET of its key starts a new batch, so each key's commands take effect
+in arrival order.  Every other command, and every command of a host
+transport, runs at once, after flushing what the batch deferred before
+it.
+
 While an rpcz capture is armed, the redis server span gets
 ``store_start_us``/``store_done_us`` around the store's part of each
-command (docs/observability.md).
+command (docs/observability.md); a deferred command's bracket the
+shared ``apply_batch`` call.
 """
 
 from __future__ import annotations
@@ -44,10 +56,13 @@ from incubator_brpc_tpu.protocols.memcache import (
 )
 from incubator_brpc_tpu.protocols.redis import (
     REPLY_STRING,
+    DeferredReply,
     RedisReply,
     RedisService,
 )
+from incubator_brpc_tpu.runtime.drain import current_drain
 from incubator_brpc_tpu.utils.iobuf import DeviceRef
+from incubator_brpc_tpu.utils.logging import log_error
 
 
 def _is_ici(sock) -> bool:
@@ -70,11 +85,25 @@ class HBMCacheService(RedisService):
 
     # protocols.redis.process_request prefers this over handle()
     def handle_conn(self, command: str, args: List, sock) -> RedisReply:
-        self._tls.sock = sock
+        cmd = command.upper()
         # the redis server span exists only under a capture
-        self._tls.span = current_span() if capture_armed() else None
+        span = current_span() if capture_armed() else None
+        scope = current_drain()
+        if scope is not None:
+            if self._deferrable(cmd, args, sock):
+                if cmd == "SET" and self._get_pending(scope, args[0]):
+                    # a batch reads after it writes: a SET after a GET of
+                    # its key waits for the next batch, so every key sees
+                    # its commands in arrival order
+                    scope.flush()
+                reply = DeferredReply()
+                scope.defer(self._flush_drain, (reply, args, span))
+                return reply
+            # the store sees the batch's deferred commands first
+            scope.flush()
+        self._tls.sock = sock
+        self._tls.span = span
         try:
-            cmd = command.upper()
             if cmd == "DEL":  # python keyword, same aliasing as KVRedisService
                 self._stamp("store_start_us")
                 n = sum(1 for k in args if self.store.delete(k))
@@ -83,6 +112,47 @@ class HBMCacheService(RedisService):
             return self.handle(command, args)
         finally:
             self._tls.sock = self._tls.span = None
+
+    def _deferrable(self, cmd: str, args: List, sock) -> bool:
+        """A GET, or a SET of a slab row's host bytes, from an ICI peer."""
+        if not (_is_ici(sock) and self.store.enabled
+                and args and type(args[0]) is bytes):
+            return False
+        if cmd == "GET":
+            return len(args) == 1
+        return (cmd == "SET" and len(args) == 2 and type(args[1]) is bytes
+                and 0 < len(args[1]) <= self.store.row_max)
+
+    def _get_pending(self, scope, key: bytes) -> bool:
+        return any(len(a) == 1 and a[0] == key
+                   for _, a, _ in scope.pending.get(self._flush_drain, ()))
+
+    def _flush_drain(self, members: List) -> None:
+        """A drain batch's deferred GETs and SETs: one store call, then
+        each reply, in arrival order.  If the store raises, every member
+        gets an error reply."""
+        sets = [(a[0], a[1]) for _, a, _ in members if len(a) == 2]
+        gets = [a[0] for _, a, _ in members if len(a) == 1]
+        start = time.time_ns() // 1000
+        try:
+            stored, values = self.store.apply_batch(sets, gets)
+        except Exception as e:  # noqa: BLE001 — answered below
+            log_error("cache drain batch of %d failed: %r", len(members), e)
+            replies = [RedisReply.error(f"ERR internal: {e}")] * len(members)
+        else:
+            done = time.time_ns() // 1000
+            stored, values = iter(stored), iter(values)
+            replies = [self._set_reply(next(stored)) if len(a) == 2
+                       else self._get_reply(next(values))
+                       for _, a, _ in members]
+            for _, _, span in members:
+                if span is not None:
+                    span.store_start_us, span.store_done_us = start, done
+        for (reply, _, _), r in zip(members, replies):
+            try:
+                reply.send(r)
+            except Exception as e:  # noqa: BLE001 — the others still go
+                log_error("cache drain reply failed: %r", e)
 
     def _stamp(self, field: str) -> None:
         span = getattr(self._tls, "span", None)
@@ -96,11 +166,21 @@ class HBMCacheService(RedisService):
         else:
             v = self.store.get_host(key)
         self._stamp("store_done_us")
+        return self._get_reply(v)
+
+    @staticmethod
+    def _get_reply(v) -> RedisReply:
         if v is None:
             return RedisReply.nil()
         if isinstance(v, bytes):
             return RedisReply.bulk(v)
         return RedisReply(REPLY_STRING, v)  # device, to an ICI peer
+
+    @staticmethod
+    def _set_reply(ok: bool) -> RedisReply:
+        if not ok:
+            return RedisReply.error("ERR value exceeds cache HBM budget")
+        return RedisReply.status("OK")
 
     # ---- commands (lower-case name == wire name) ---------------------------
     def get(self, key):
@@ -112,9 +192,7 @@ class HBMCacheService(RedisService):
         self._stamp("store_start_us")
         ok = self.store.set(key, value)
         self._stamp("store_done_us")
-        if not ok:
-            return RedisReply.error("ERR value exceeds cache HBM budget")
-        return RedisReply.status("OK")
+        return self._set_reply(ok)
 
     def exists(self, key):
         return 1 if key in self.store else 0
@@ -259,6 +337,9 @@ class HBMCacheMemcacheService(MemcacheService):
     def handle_op(self, op, sock):
         import struct
 
+        scope = current_drain()
+        if scope is not None:  # the redis front's deferred commands first
+            scope.flush()
         code = op.opcode
         if code == OP_GET:
             if _is_ici(sock):

@@ -19,6 +19,11 @@ names in a trace stay stable:
   one scatter per page touched (``cache_slab_scatter``).
 - A fused DMGET gathers its rows by index in ONE program
   (``cache_slab_gather``), one stacked wire segment instead of N.
+- A completion-queue drain batch's GETs and SETs (``apply_batch``, from
+  the redis front's drain scope) share ONE program a size class and
+  read length (``cache_slab_scatter_gather``): the writes, then the
+  reads, each read an exact-length output of its own, with every row
+  index and value in one host->device buffer.
 
 Once a page is donated its old buffer is dead: every program that takes
 a page is dispatched under the store's lock, which also serialises the
@@ -43,8 +48,9 @@ back first, then entries are evicted least recently used first until
 one of the two holds.  ``hbm_used`` counts the bytes of the values
 stored (``rpc_cache_hbm_bytes``).  Metrics:
 ``rpc_cache_{hits,misses,evictions,hbm_bytes}`` and
-``rpc_cache_slab_{pages,rows,writes,write_programs}`` (registered in
-METRIC_MODULES for the render lint); HBM ledger tags ``cache.slab`` (a
+``rpc_cache_slab_{pages,rows,writes,write_programs}`` and
+``rpc_cache_slab_batch_{programs,ops}`` (registered in METRIC_MODULES
+for the render lint); HBM ledger tags ``cache.slab`` (a
 charge per page), ``cache.values`` (whole-array entries) and
 ``cache.gather`` (multi-GET stacks).  The chaos site ``cache.lookup``
 (docs/chaos.md) faults individual lookups: drop = forced miss for a
@@ -56,6 +62,7 @@ from __future__ import annotations
 import threading
 import weakref
 from collections import OrderedDict
+from itertools import islice
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -71,12 +78,17 @@ cache_hits = Adder(0).expose("rpc_cache_hits")
 cache_misses = Adder(0).expose("rpc_cache_misses")
 cache_evictions = Adder(0).expose("rpc_cache_evictions")
 cache_hbm_bytes = Adder(0).expose("rpc_cache_hbm_bytes")
-# pages and rows held now; rows written and write programs dispatched,
-# ever (rows per program is the write coalescing)
+# pages and rows held now; rows written and write programs dispatched
+# by set/set_stacked, ever (rows per program is the write coalescing)
 slab_pages = Adder(0).expose("rpc_cache_slab_pages")
 slab_rows = Adder(0).expose("rpc_cache_slab_rows")
 slab_writes = Adder(0).expose("rpc_cache_slab_writes")
 slab_write_programs = Adder(0).expose("rpc_cache_slab_write_programs")
+# drain batches of two or more requests (apply_batch): the GETs and SETs
+# served and the programs dispatched for them, ever (ops per program is
+# how far batching engages)
+slab_batch_programs = Adder(0).expose("rpc_cache_slab_batch_programs")
+slab_batch_ops = Adder(0).expose("rpc_cache_slab_batch_ops")
 
 # HBM heap profiler tags (observability/profiling.py): a slab page holds
 # its charge until FLUSHALL, a whole-array entry its adopt charge on the
@@ -121,6 +133,18 @@ _mget_gather = FusedKernel(
 )
 
 
+# a batch program's slots (requests) are padded up to a bucket, so that
+# it traces at most len(BATCH_BUCKETS) times a (width, length): every
+# one is compiled before its first use (_warm_batch), at ~0.1-0.6 s each
+# on a v5e's compiler (32 and 64 slots would cost 1.8 s more); a larger
+# batch runs as programs of BATCH_MAX
+BATCH_BUCKETS = (1, 2, 4, 8, 16)
+BATCH_MAX = BATCH_BUCKETS[-1]
+# its packed buffer opens with this many int32s a slot: the write row,
+# the read row, and whether the read is of the written page
+_BATCH_INTS = 3
+
+
 def _pad_bucket(n: int) -> int:
     for b in MGET_BUCKETS:
         if n <= b:
@@ -151,6 +175,18 @@ def fused_stack(rows: Sequence) -> object:
     out = _mget_gather(*padded)
     _charge_transient(out)
     return out
+
+
+def _batch_buffer(b: int, width: int, rows_per_page: int):
+    """A batch program's packed uint8 buffer for ``b`` slots, and its
+    (3, b) little-endian int32 head: write rows (padding: past the page,
+    dropped), read rows, and read-the-written-page flags (padding: row 0
+    of the written page).  The b rows of values follow, zero."""
+    packed = np.zeros(b * (_BATCH_INTS * 4 + width), np.uint8)
+    ints = packed[:_BATCH_INTS * 4 * b].view("<i4").reshape(_BATCH_INTS, b)
+    ints[0] = rows_per_page
+    ints[2] = 1
+    return packed, ints
 
 
 # ---- slab programs ---------------------------------------------------------
@@ -212,6 +248,27 @@ def _slab_programs(platform: str) -> Dict[str, object]:
             out = jnp.where((sel == j)[:, None], pages[j][rows, :length], out)
         return out
 
+    def cache_slab_scatter_gather(page, others, packed, length):
+        # one drain batch on one page: B row writes into ``page``, then
+        # B exact-length row reads, read i from the written page or from
+        # others[i] (``packed``: see _batch_buffer)
+        width = page.shape[1]
+        b = packed.shape[0] // (_BATCH_INTS * 4 + width)
+        ints = packed[:_BATCH_INTS * 4 * b].reshape(_BATCH_INTS, b, 4)
+        ints = ints.astype(jnp.int32)
+        wrow, rrow, here = (ints[..., 0] | ints[..., 1] << 8
+                            | ints[..., 2] << 16 | ints[..., 3] << 24)
+        vals = packed[_BATCH_INTS * 4 * b:].reshape(b, width)
+        page = page.at[wrow].set(vals, mode="drop")
+        outs = []
+        for i in range(b):
+            out = jax.lax.dynamic_slice_in_dim(page, rrow[i], 1)[0, :length]
+            if others:
+                other = jax.lax.dynamic_slice_in_dim(others[i], rrow[i], 1)
+                out = jnp.where(here[i] != 0, out, other[0, :length])
+            outs.append(out)
+        return (page, *outs)
+
     opts = _TPU_OPTIONS if platform == "tpu" else None
     progs = _programs[platform] = dict(
         read=jax.jit(cache_slab_read, static_argnames=("length",),
@@ -222,6 +279,8 @@ def _slab_programs(platform: str) -> Dict[str, object]:
                         compiler_options=opts),
         gather=jax.jit(cache_slab_gather, static_argnames=("length",),
                        compiler_options=opts),
+        batch=jax.jit(cache_slab_scatter_gather, static_argnames=("length",),
+                      donate_argnums=(0,), compiler_options=opts),
     )
     return progs
 
@@ -242,7 +301,8 @@ class _SizeClass:
 
     __slots__ = ("width", "rows_per_page", "page_bytes", "pages", "charges",
                  "live", "fresh", "freed", "open", "avail", "next_page",
-                 "digits", "read", "write_row", "scatter", "gather")
+                 "digits", "read", "write_row", "scatter", "gather", "batch",
+                 "warm")
 
     def __init__(self, width: int, page_cap: int, device):
         self.width = width
@@ -261,6 +321,10 @@ class _SizeClass:
         progs = _slab_programs(device.platform)
         self.read, self.write_row = progs["read"], progs["write"]
         self.scatter, self.gather = progs["scatter"], progs["gather"]
+        self.batch = progs["batch"]
+        # (length, reads from other pages) of the batch programs
+        # compiled for every bucket
+        self.warm = set()
 
 
 class _Entry:
@@ -746,6 +810,171 @@ class HBMCacheStore:
         rows = iter(range(len(keys)))
         values = [None if n < 0 else stacked[next(rows)] for n in lengths]
         return values, stacked
+
+    # ---- drain batches ----------------------------------------------------
+    def apply_batch(self, sets: Sequence[Tuple[bytes, bytes]],
+                    gets: Sequence[bytes]) -> Tuple[List[bool], List]:
+        """A completion-queue drain batch's SETs and GETs of slab rows
+        -> (stored, values), one entry a SET and one a GET.
+
+        The requests of one batch are concurrent (none is answered yet),
+        so the batch is linearized as every SET in order, then every GET:
+        the SETs' bookkeeping runs first, as ``set``'s does (a row freed
+        and handed out again in the batch takes its last value only),
+        then each GET is resolved against the index, as ``get`` resolves
+        it.  The device work is one program a size class and read length
+        (``cache_slab_scatter_gather``): the class's row writes, then
+        its row reads, each read an exact-length output of its own (no
+        slice after), with every row index and value in ONE packed
+        host->device buffer.  Writes on further pages of a class take a
+        program each, before the reads.  A batch of one request runs as
+        that request alone (``set``/``get``: a lone GET moves nothing
+        from the host).  SET values are host bytes of 1 to ``row_max``
+        bytes."""
+        sets = [(bytes(k), v) for k, v in sets]
+        for _, v in sets:
+            if not (isinstance(v, (bytes, bytearray, memoryview))
+                    and 0 < len(v) <= self.row_max):
+                raise ValueError("apply_batch stores slab-row byte values only")
+        if not self.enabled or len(sets) + len(gets) == 1:
+            out = ([self.set(k, v) for k, v in sets],
+                   [self.get(k) for k in gets])
+            if self.enabled:  # the batches to come compile nothing
+                self._warm_key(sets[0][0] if sets else gets[0])
+            return out
+        gets = [bytes(k) for k in gets]
+        forced = [self._chaos_drop(k) for k in gets]
+        slab_batch_ops << len(sets) + len(gets)
+        with self._lock:
+            # width -> row -> value; a page given back drops its rows
+            writes = self._pending = {}
+            try:
+                for key, v in sets:
+                    n = len(v)
+                    old = self._d.pop(key, None)
+                    if old is not None:
+                        self._release(old)
+                    row = self._take_row(self._class(_width(n)))
+                    writes.setdefault(_width(n), {})[row] = v
+                    self._d[key] = (row << _LEN_BITS) | n
+                    self._used += n
+                    cache_hbm_bytes << n
+            finally:
+                self._pending = None
+            values: List = [None] * len(gets)
+            reads: Dict[int, Dict[int, List[Tuple[int, int]]]] = {}
+            for i, (key, f) in enumerate(zip(gets, forced)):
+                ent = self._lookup(key, f)
+                if ent is None:
+                    continue
+                if type(ent) is int:
+                    n = ent & _LEN_MASK
+                    reads.setdefault(_width(n), {}).setdefault(n, []).append(
+                        (i, ent >> _LEN_BITS))
+                else:
+                    values[i] = ent.array
+            for w in {**writes, **reads}:
+                if writes.get(w) or w in reads:  # (a page given back
+                    # may have taken a class's writes)
+                    self._batch_class(self._classes[w], writes.get(w, {}),
+                                      reads.get(w, {}), values)
+            return [True] * len(sets), values
+
+    def _batch_class(self, cls: _SizeClass, writes: Dict[int, bytes],
+                     reads: Dict[int, List[Tuple[int, int]]],
+                     values: List) -> None:
+        """One class's share of a batch: ``writes`` row -> value,
+        ``reads`` length -> [(GET index, row)]; each read's array goes to
+        ``values``.  The page with most writes takes them with the reads;
+        a page is donated to one program at a time, never read beside."""
+        rpp = cls.rows_per_page
+        by_page: Dict[int, List[Tuple[int, bytes]]] = {}
+        for row, v in writes.items():
+            by_page.setdefault(row // rpp, []).append((row % rpp, v))
+        if by_page:
+            main = max(by_page, key=lambda q: len(by_page[q]))
+            length = len(by_page[main][0][1])
+        else:
+            main = next(iter(reads.values()))[0][1] // rpp
+        # the reads, BATCH_MAX a program, one length after another
+        rchunks = [(n, rs[j:j + BATCH_MAX]) for n, rs in reads.items()
+                   for j in range(0, len(rs), BATCH_MAX)] or [(length, [])]
+        # every write lands before the first read: further pages first,
+        # the main page last, its last writes with the first reads
+        wchunks = [(q, by_page[q][j:j + BATCH_MAX])
+                   for q in sorted(by_page, key=lambda q: q == main)
+                   for j in range(0, len(by_page[q]), BATCH_MAX)]
+        last = wchunks.pop()[1] if wchunks else []
+        for q, ws in wchunks:
+            self._batch_program(cls, q, ws, rchunks[0][0], [], values)
+        for k, (n, rs) in enumerate(rchunks):
+            self._batch_program(cls, main, [] if k else last, n, rs, values)
+
+    def _batch_program(self, cls: _SizeClass, p: int,
+                       ws: List[Tuple[int, bytes]], length: int,
+                       rs: List[Tuple[int, int]], values: List) -> None:
+        """One ``cache_slab_scatter_gather``: ``ws`` (row in page p,
+        value) written into page p (donated), then ``rs`` (GET index,
+        row) read, each as its own ``length``-byte array."""
+        b = next(x for x in BATCH_BUCKETS if x >= max(len(ws), len(rs)))
+        self._warm_batch(cls, p, length)
+        # a page read beside p (padding reads, reads of p): any other one
+        others = tuple(islice((pg for q, pg in cls.pages.items() if q != p), 1))
+        packed, ints = _batch_buffer(b, cls.width, cls.rows_per_page)
+        vals = packed[_BATCH_INTS * 4 * b:].reshape(b, cls.width)
+        for j, (r, v) in enumerate(ws):
+            ints[0, j] = r
+            vals[j, :len(v)] = np.frombuffer(v, np.uint8)
+        srcs = [None] * b
+        for j, (_, row) in enumerate(rs):
+            q, ints[1, j] = divmod(row, cls.rows_per_page)
+            if q != p:
+                ints[2, j] = 0
+                srcs[j] = cls.pages[q]
+        if others:
+            filler = next((s for s in srcs if s is not None), others[0])
+            others = tuple(filler if s is None else s for s in srcs)
+        with kernel_section("cache.slab_batch"):
+            out = cls.batch(cls.pages[p], others, packed, length=length)
+        cls.pages[p] = out[0]
+        for j, (i, _) in enumerate(rs):
+            values[i] = hand_off(out[1 + j])
+        slab_batch_programs << 1
+
+    def _warm_key(self, key) -> None:
+        """Compile the batch program for the slab row of ``key``, if any
+        (a lone request's class, length and page)."""
+        with self._lock:
+            ent = self._d.get(bytes(key))
+            if type(ent) is int:
+                n = ent & _LEN_MASK
+                cls = self._classes[_width(n)]
+                self._warm_batch(cls, (ent >> _LEN_BITS) // cls.rows_per_page, n)
+
+    def _warm_batch(self, cls: _SizeClass, p: int, length: int) -> None:
+        """The first time a (length, one page or more) of ``cls`` is
+        seen, compile the batch program for every bucket by running it
+        with every write dropped, and the programs of a lone GET and SET
+        (a batch of one): no later batch of ``BATCH_MAX`` or fewer
+        requests compiles."""
+        key = (length, len(cls.pages) > 1)
+        if key in cls.warm:
+            return
+        cls.warm.add(key)
+        others = tuple(islice((pg for q, pg in cls.pages.items() if q != p), 1))
+        for b in BATCH_BUCKETS:
+            packed, _ = _batch_buffer(b, cls.width, cls.rows_per_page)
+            cls.pages[p] = cls.batch(cls.pages[p], others * b, packed,
+                                     length=length)[0]
+        ds = cls.digits
+        cls.read(cls.pages[p], ds[0], ds[0], length=length)
+        # a lone SET's write, of zeros into a free row, if any
+        q = next(iter(cls.open), None)
+        if q is not None:
+            r = cls.freed[q][-1] if cls.freed[q] else cls.fresh[q]
+            cls.pages[q] = cls.write_row(cls.pages[q], ds[r // _DIGIT],
+                                         ds[r % _DIGIT],
+                                         np.zeros(cls.width, np.uint8))
 
     def keys(self) -> List[bytes]:
         """Snapshot of live keys (LRU order, oldest first) — the

@@ -38,6 +38,7 @@ from incubator_brpc_tpu.utils.segmentation import (
     DEVICE_CHUNK_BYTES,
     MIN_CHUNKS,
 )
+from incubator_brpc_tpu.runtime.drain import close_drain, open_drain
 from incubator_brpc_tpu.runtime.execution_queue import ExecutionQueue
 from incubator_brpc_tpu.metrics.reducer import Adder
 from incubator_brpc_tpu.transport import socket as socket_mod
@@ -235,6 +236,12 @@ class IciPort:
         # lock instead of len(batch)
         released = 0
         entries = list(batch)
+        # a server port's batch runs in a drain scope (runtime/drain.py):
+        # a service may defer commands to the batch's close, where the
+        # scope runs them before the window credits go back
+        scoped = self.server is not None
+        if scoped:
+            prev = open_drain()
         try:
             for i, ((frame, peer_coords, parent), received_us) in enumerate(
                 entries
@@ -265,6 +272,11 @@ class IciPort:
                 except Exception as e:  # noqa: BLE001
                     log_error("ici completion processing failed: %r", e)
         finally:
+            if scoped:
+                try:
+                    close_drain(prev)
+                except Exception as e:  # noqa: BLE001
+                    log_error("ici drain flush failed: %r", e)
             if released:
                 with self._qb_lock:
                     self._queued_bytes -= released

@@ -25,6 +25,7 @@ on the server and the client accumulates replies per (cid, count).
 
 from __future__ import annotations
 
+import functools
 import threading
 import time
 from typing import Dict, List, Optional, Tuple
@@ -32,6 +33,7 @@ from typing import Dict, List, Optional, Tuple
 from incubator_brpc_tpu import errors
 from incubator_brpc_tpu.protocols import ParseResult, Protocol, register_protocol
 from incubator_brpc_tpu.runtime.call_id import default_pool as _id_pool
+from incubator_brpc_tpu.runtime.drain import current_drain
 from incubator_brpc_tpu.utils.iobuf import DeviceRef, IOBuf
 from incubator_brpc_tpu.utils.logging import log_error
 
@@ -818,6 +820,19 @@ def process_request(msg: _WireMsg, sock) -> None:
             swap_current_span(prev_parent)
 
 
+class DeferredReply:
+    """What a connection-aware service returns for a command it answers
+    at the close of the drain batch (``runtime/drain.py``).
+    ``_serve_command`` leaves the reply's pack and write, the span's
+    ``callback_done_us`` and the admission release to ``send``, which
+    the service calls once, with the reply."""
+
+    __slots__ = ("send",)
+
+    def __init__(self):
+        self.send = None
+
+
 def _serve_command(sock, server, service, parts, name, span) -> None:
     ticket = None
     if service is None:
@@ -862,17 +877,60 @@ def _serve_command(sock, server, service, parts, name, span) -> None:
                 if span is not None:
                     span.end(errors.EINTERNAL)
                 raise
+            if isinstance(reply, DeferredReply):
+                # the socket is held until the reply is written, so its
+                # slot cannot be reborn under the deferred write
+                reply.send = functools.partial(
+                    _send_deferred, sock, span, ticket, sock._inuse_acquire())
+                return
             if span is not None:
                 span.callback_done_us = time.time_ns() // 1000
-    out = IOBuf()
-    pack_reply_into(reply, out)
-    if span is not None:
-        # closes at write completion (write_done), as tpu_std's does
-        span.response_size = len(out)
-        span.response_write_us = time.time_ns() // 1000
-    sock.write(out, ignore_eovercrowded=True, span=span)
-    if ticket is not None:
-        ticket.release()
+    # RESP answers in arrival order: replies deferred in this drain
+    # batch leave first
+    scope = current_drain()
+    if scope is not None and scope.pending:
+        scope.flush()
+    _send_reply(sock, span, ticket, reply)
+
+
+def _send_reply(sock, span, ticket, reply) -> None:
+    try:
+        out = IOBuf()
+        pack_reply_into(reply, out)
+        if span is not None:
+            # closes at write completion (write_done), as tpu_std's does
+            span.response_size = len(out)
+            span.response_write_us = time.time_ns() // 1000
+        sock.write(out, ignore_eovercrowded=True, span=span)
+    finally:
+        if ticket is not None:
+            ticket.release()
+
+
+def _send_deferred(sock, span, ticket, held, reply) -> None:
+    """A deferred command's reply, at the drain batch's close: its span
+    is the current one again, so the reply's fabric leg joins it."""
+    from incubator_brpc_tpu.observability.span import swap_current_span
+
+    try:
+        if not held:  # the socket was already dying: nobody to answer
+            if ticket is not None:
+                ticket.release()
+            if span is not None:
+                span.end(errors.ECLOSE)
+            return
+        prev = None
+        if span is not None:
+            span.callback_done_us = time.time_ns() // 1000
+            prev = swap_current_span(span)
+        try:
+            _send_reply(sock, span, ticket, reply)
+        finally:
+            if span is not None:
+                swap_current_span(prev)
+    finally:
+        if held:
+            sock._inuse_release()
 
 
 def verify(msg: _WireMsg, sock) -> bool:
