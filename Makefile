@@ -43,11 +43,9 @@ inventory:
 device-census:
 	$(PY) tools/check.py --dump-device-census
 
-# the ICI data-plane segments only: mode × chunk-size sweep (off/
-# fused/pipelined/pallas), 64MB headline under the best config, and
-# the resharding bulk-move collective-step proof (docs/ici_pipeline.md)
+# the ICI data-plane segments only: the 64MB headline echo and the
+# resharding bulk-move collective-step proof (docs/ici_pipeline.md)
 bench-ici:
 	$(PY) -c "import json, bench; \
-	print(json.dumps({**bench.bench_ici_pipeline_curve(), \
-	**bench.bench_ici_rpc(), \
+	print(json.dumps({**bench.bench_ici_rpc(), \
 	**bench.bench_resharding_bulk_move()}, indent=2))"

@@ -563,197 +563,6 @@ def bench_tcp_echo(payload=4096, calls=4000, threads=8):
     return out
 
 
-def bench_transmit_op(mb=64, hi=200, lo=8, reps=3):
-    """Marginal-cost bandwidth of the fabric's transmit op.
-
-    Chains `hi` (resp. `lo`) data-dependent transmissions of a 64MB
-    payload inside one jit program, fetches a scalar folded from the
-    final output (forcing every pass to complete), and divides the time
-    difference by (hi - lo) transmissions. Counts 2x payload per pass
-    (HBM read + write), the same accounting as reference rdma_perf.
-    """
-    try:
-        import jax
-        import jax.numpy as jnp
-
-        from incubator_brpc_tpu.ops.transfer import device_copy_with_checksum
-
-        rows = (mb << 20) // (2048 * 4)
-
-        def chain(iters):
-            # csum accumulates through the loop carry (scalar adds only —
-            # no extra full-array op rides the measured pass), and the
-            # final fetch depends on it, so every copy+verify completes
-            @jax.jit
-            def loop(a):
-                def body(i, carry):
-                    y, s = carry
-                    out, csum = device_copy_with_checksum(y)
-                    return out, s + csum
-
-                y, s = jax.lax.fori_loop(0, iters, body, (a, jnp.float32(0.0)))
-                return y[0, 0] + y[-1, -1] + 0.0 * s
-
-            return loop
-
-        loop_hi, loop_lo = chain(hi), chain(lo)
-        base = jnp.linspace(0.0, 1.0, rows * 2048, dtype=jnp.float32).reshape(
-            rows, 2048
-        )
-        xs = [base + i for i in range(2 * reps + 2)]
-        for x in xs:
-            x.block_until_ready()
-        float(loop_hi(xs[0]))  # compile
-        float(loop_lo(xs[1]))
-        best_per = None
-        k = 2
-        for _ in range(reps):
-            t0 = time.perf_counter()
-            float(loop_hi(xs[k]))
-            t_hi = time.perf_counter() - t0
-            t0 = time.perf_counter()
-            float(loop_lo(xs[k + 1]))
-            t_lo = time.perf_counter() - t0
-            k += 2
-            per = (t_hi - t_lo) / (hi - lo)
-            if per > 0 and (best_per is None or per < best_per):
-                best_per = per
-        if not best_per:
-            return {"pallas_transmit_64mb_gbps": -1}
-        return {
-            "pallas_transmit_64mb_gbps": round(2 * mb / 1024 / best_per, 1),
-            "pallas_transmit_64mb_us": round(best_per * 1e6, 1),
-        }
-    except Exception as e:  # noqa: BLE001
-        return {"pallas_transmit_64mb_gbps": -1, "pallas_error": repr(e)[:160]}
-
-
-def bench_ici_pipeline_curve(mb=64, hi=10, lo=2, reps=3):
-    """Chunk-size/mode sweep of the fabric's large-frame transmit
-    path (docs/ici_pipeline.md): the SAME chained marginal-cost method
-    as bench_transmit_op, but driven through IciFabric's chunk policy
-    so the sweep measures exactly what a 64MB frame pays per hop under
-    each config:
-
-      - off        — whole-frame transmit (pre-chunking behavior),
-      - fused      — K-chunk pipeline compiled as one program,
-      - pipelined  — one launch per chunk over a StagingRing,
-      - pallas     — ONE double-buffered Pallas DMA kernel per frame
-                     (explicit send/recv semaphores overlap stage k+1's
-                     HBM→VMEM pull with stage k's checksum and stage
-                     k-2's drain; docs/ici_pipeline.md).
-
-    The best config is APPLIED to the fabric before bench_ici_rpc runs,
-    the same way echo_4kb picks its best curve point for the headline —
-    the headline's definition (median marginal per echo, zero_copy off)
-    is unchanged; only the chunk policy, an operator knob, is tuned."""
-    try:
-        return _bench_ici_pipeline_curve_impl(mb, hi, lo, reps)
-    except Exception as e:  # noqa: BLE001 — keep the one-JSON-line contract
-        return {"ici_pipeline_error": repr(e)[:200]}
-
-
-def _bench_ici_pipeline_curve_impl(mb, hi, lo, reps):
-    import jax.numpy as jnp
-
-    from incubator_brpc_tpu.parallel.ici import (
-        StagingRing,
-        get_fabric,
-        ici_pallas_fallbacks,
-        ici_pallas_frames,
-    )
-
-    fabric = get_fabric()
-    rows = (mb << 20) // (2048 * 4)
-    x0 = jnp.linspace(0.0, 1.0, rows * 2048, dtype=jnp.float32).reshape(
-        rows, 2048
-    )
-    x0.block_until_ready()
-
-    class _PortShim:
-        """Staging-ring host for the sweep (no live port needed)."""
-
-        coords = (0, 0)
-        device = None
-
-        def __init__(self):
-            self.staging = StagingRing()
-
-    shim = _PortShim()
-
-    def transmit(arr):
-        out, _ = fabric._transmit_segment(arr, shim, None)
-        # pallas mode donates ring slots into the kernel's output; the
-        # consumed input is this hop's recyclable buffer — releasing it
-        # keeps frame 2+ allocation-free (the StagingRing contract)
-        if fabric.chunk_mode == "pallas" and arr is not x0:
-            shim.staging.release(arr)
-        return out
-
-    def chain(n):
-        t0 = time.perf_counter()
-        y = x0
-        for _ in range(n):
-            y = transmit(y)
-        float(y[0, 0] + y[-1, -1])  # forces every chunk of every pass
-        return time.perf_counter() - t0
-
-    configs = [
-        ("off", 0),
-        ("fused", 4 << 20), ("fused", 8 << 20), ("fused", 16 << 20),
-        ("pipelined", 4 << 20), ("pipelined", 8 << 20),
-        ("pipelined", 16 << 20),
-        ("pallas", 4 << 20), ("pallas", 8 << 20), ("pallas", 16 << 20),
-    ]
-    saved = (fabric.chunk_mode, fabric.chunk_bytes)
-    curve = []
-    try:
-        for mode, cb in configs:
-            fabric.chunk_mode = mode
-            if cb:
-                fabric.chunk_bytes = cb
-            f0 = int(ici_pallas_frames.get_value())
-            fb0 = int(ici_pallas_fallbacks.get_value())
-            transmits = 2
-            chain(2)  # compile this config's programs
-            per = []
-            for _ in range(reps):
-                d = (chain(hi) - chain(lo)) / (hi - lo)
-                transmits += hi + lo
-                if d > 0:
-                    per.append(d)
-            per.sort()
-            med = per[len(per) // 2] if per else -1
-            entry = {
-                "mode": mode,
-                "chunk_mb": cb >> 20,
-                "gbps": round(2 * mb / 1024 / med, 1) if med > 0 else -1,
-                "per_pass_us": round(med * 1e6, 1) if med > 0 else -1,
-            }
-            if mode == "pallas":
-                # proof-by-step-log: on the hit path every frame is ONE
-                # fused kernel dispatch (dispatches == transmits and
-                # zero fallbacks); a silent fallback to the legacy
-                # pipeline shows up here, not as a quiet slowdown
-                entry["pallas_dispatches"] = (
-                    int(ici_pallas_frames.get_value()) - f0
-                )
-                entry["pallas_fallbacks"] = (
-                    int(ici_pallas_fallbacks.get_value()) - fb0
-                )
-                entry["pallas_transmits"] = transmits
-            curve.append(entry)
-    finally:
-        fabric.chunk_mode, fabric.chunk_bytes = saved
-    best = max(curve, key=lambda p: p["gbps"])
-    if best["gbps"] > 0:
-        # tune the fabric for the headline run (and record the choice)
-        fabric.chunk_mode = best["mode"]
-        if best["chunk_mb"]:
-            fabric.chunk_bytes = best["chunk_mb"] << 20
-    return {"ici_pipeline_curve": curve, "ici_pipeline_best": best}
-
-
 def bench_ici_rpc(mb=64, hi=48, lo=8, reps=9):
     """Measured END-TO-END 64MB device-payload echo over the ICI
     transport — THE headline. zero_copy stays OFF (the fabric default),
@@ -3876,10 +3685,6 @@ def main():
     extra.update(bench_dcn_bulk())
     extra.update(bench_python_protocols())
     extra.update(bench_tail_cdf())
-    extra.update(bench_transmit_op())
-    # sweep first: the best chunk-policy config is applied to the
-    # fabric before the headline end-to-end run measures it
-    extra.update(bench_ici_pipeline_curve())
     extra.update(bench_ici_rpc())
 
     value = extra.get("ici_64mb_echo_gbps", 0.0)

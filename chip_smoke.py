@@ -8,11 +8,11 @@
 Phases (one JSON line each, then the contract's last line
 ``{"ok": true, "device": {"platform", "kind", "count"}}``):
 
-1. ``ici_echo``   — 64 MiB f32 (8192, 2048) device echoes over ICI in
-   every chunk mode with zero_copy off (the reference's
-   rdma_performance 64 MB transfer, bench.py's shape), one 4 KiB ICI
-   echo, one 4 KiB echo through the native C++ engine over TCP, and the
-   transmit kernels' checksums compared with each other.
+1. ``ici_echo``   — 64 MiB f32 (8192, 2048) device echoes over ICI with
+   zero_copy off (the reference's rdma_performance 64 MB transfer,
+   bench.py's shape; the fused chunk program), one 4 KiB ICI echo (the
+   whole-frame kernel), one 4 KiB echo through the native C++ engine
+   over TCP, and the two transmit kernels' checksums compared.
 2. ``hbm_cache``  — 1 GiB of 1 MiB values (plus odd lengths) SET into
    ``HBMCacheService`` behind the redis front, a sample read back from
    an ICI peer as device-resident values, bit-equal to the host bytes.
@@ -26,8 +26,8 @@ The script is one process and starts no child that touches JAX.  It
 exits non-zero at once when the first JAX device is not a TPU; a failed
 check raises, and the exit code is then non-zero.  The phase functions
 run anywhere (tests/test_chip_smoke.py drives them on the CPU at tiny
-sizes); what only a TPU can show (Pallas frames, no fallbacks, every
-segment checksummed) is asserted in :func:`main`.
+sizes); what only a TPU can show (every segment checksummed) is
+asserted in :func:`main`.
 """
 
 from __future__ import annotations
@@ -38,7 +38,6 @@ import sys
 import time
 
 ECHO_SHAPE = (8192, 2048)  # 64 MiB f32
-CHUNK_MODES = ("fused", "pipelined", "pallas", "off")
 CACHE_VALUES = 1024
 CACHE_VALUE_BYTES = 1 << 20
 CACHE_ODD_BYTES = (1000, 4097)
@@ -109,8 +108,8 @@ def _random_on(device, shape, seed):
 
 
 def phase_ici_echo(device, shape=ECHO_SHAPE, echoes=3, seed=0):
-    """Bulk echoes in every chunk mode, plus the 4 KiB ICI and native
-    TCP echoes.  Returns per-mode counter deltas for main() to judge."""
+    """Bulk echoes, plus the 4 KiB ICI and native TCP echoes.  Returns
+    the unchecked-segment counter's delta for main() to judge."""
     from incubator_brpc_tpu import native
     from incubator_brpc_tpu.client.channel import Channel, ChannelOptions
     from incubator_brpc_tpu.client.controller import Controller
@@ -119,59 +118,28 @@ def phase_ici_echo(device, shape=ECHO_SHAPE, echoes=3, seed=0):
     from incubator_brpc_tpu.protos.echo_pb2 import EchoRequest
     from incubator_brpc_tpu.server.server import Server, ServerOptions
 
-    fabric = ici.get_fabric()
-    assert fabric.zero_copy is False, "zero_copy must be off"
+    assert ici.get_fabric().zero_copy is False, "zero_copy must be off"
     x = _random_on(device, shape, seed)
     srv = Server(ServerOptions(usercode_in_dispatcher=True))
     srv.add_service(EchoService())
     assert srv.start_ici(0, 0, device=device) == 0
     ch = Channel(ChannelOptions(timeout_ms=120000, ici_device=device))
-    saved_mode = fabric.chunk_mode
-    out = {"bytes": int(x.nbytes), "modes": {}}
+    out = {"bytes": int(x.nbytes), "echoes": echoes}
+    unchecked0 = _counter(ici.ici_unchecked_segments)
     try:
         assert ch.init("ici://slice0/chip0") == 0
         stub = echo_stub(ch)
-        client_ring = ch._ici_port().staging
-        server_ring = fabric.port((0, 0)).staging
-        for mode in CHUNK_MODES:
-            fabric.chunk_mode = mode
-            before = [
-                _counter(ici.ici_pallas_frames),
-                _counter(ici.ici_pallas_fallbacks),
-                _counter(ici.ici_unchecked_segments),
-                client_ring.hits + server_ring.hits,
-            ]
-            for _ in range(echoes):
-                resp = _echo(stub, x)
-                _check_echo(resp, x, device)
-                if mode == "pallas":
-                    # recycle the delivered buffer, as a client that
-                    # reuses response memory does: the next reply hop
-                    # hits the ring and runs the donated-slot DMA kernel
-                    client_ring.release(resp)
-                del resp
-            after = [
-                _counter(ici.ici_pallas_frames),
-                _counter(ici.ici_pallas_fallbacks),
-                _counter(ici.ici_unchecked_segments),
-                client_ring.hits + server_ring.hits,
-            ]
-            d = [a - b for a, b in zip(after, before)]
-            out["modes"][mode] = {
-                "echoes": echoes,
-                "pallas_frames": d[0],
-                "pallas_fallbacks": d[1],
-                "unchecked_segments": d[2],
-                "ring_hits": d[3],
-            }
-        fabric.chunk_mode = saved_mode
+        for _ in range(echoes):
+            _check_echo(_echo(stub, x), x, device)
         small = _random_on(device, (8, 128), seed + 1)  # 4 KiB
         _check_echo(_echo(stub, small), small, device)
         out["ici_4k_echo"] = True
     finally:
-        fabric.chunk_mode = saved_mode
         ch.close()
         srv.stop()
+    out["unchecked_segments"] = (
+        _counter(ici.ici_unchecked_segments) - unchecked0
+    )
 
     # the C++ engine, built from the committed source on this machine
     assert native.available(), "native engine did not build"
@@ -195,33 +163,19 @@ def phase_ici_echo(device, shape=ECHO_SHAPE, echoes=3, seed=0):
 
 
 def transmit_kernel_checksums(x):
-    """TPU only: every transmit kernel on one frame.  Each must copy x
+    """TPU only: both transmit kernels on one frame.  Each must copy x
     exactly and give the SAME checksum (transfer.py's bit-equality
-    invariant across the whole-frame, fused, pipelined and DMA lanes,
-    the donated-slot kernels included)."""
+    invariant between the whole-frame kernel and the fused chunk
+    program)."""
     import jax.numpy as jnp
 
     from incubator_brpc_tpu.ops import transfer as T
     from incubator_brpc_tpu.utils.segmentation import DEVICE_CHUNK_BYTES
 
     v, block_rows, chunks = T.chunk_plan_for(x, DEVICE_CHUNK_BYTES)
-    stage_rows = T.pallas_stage_rows(v, block_rows)
-    acc = jnp.zeros((1, v.shape[1]), jnp.float32)
-    pieces = []
-    for off, rows in chunks:
-        xc = v[off:off + rows]
-        oc, acc = T.device_copy_with_checksum_chunk_into(
-            xc, acc, jnp.zeros_like(xc), block_rows
-        )
-        pieces.append(oc)
     runs = {
         "whole": T.device_copy_with_checksum(v),
         "fused": T._chunked_copy_csum(v, tuple(chunks), block_rows, False),
-        "pipelined": (jnp.concatenate(pieces), T.fold_checksum(acc)),
-        "dma": T.device_copy_with_checksum_dma(v, block_rows, stage_rows),
-        "dma_into": T.device_copy_with_checksum_dma_into(
-            v, jnp.zeros_like(v), block_rows, stage_rows
-        ),
     }
     sums = {}
     for name, (out, csum) in runs.items():
@@ -516,15 +470,9 @@ def main(argv=None) -> int:
             return out
 
         out, line = _run("ici_echo", clock, ici_phase)
-        for mode, m in out["modes"].items():
-            assert m["pallas_fallbacks"] == 0, (mode, m)
-            assert m["unchecked_segments"] == 0, (mode, m)
-        pallas = out["modes"]["pallas"]
-        # both hops of every echo ride the DMA kernel; recycled replies
-        # hit the ring (the donated-slot kernel), as pipelined chunks do
-        assert pallas["pallas_frames"] == 2 * pallas["echoes"], pallas
-        assert pallas["ring_hits"] > 0, pallas
-        assert out["modes"]["pipelined"]["ring_hits"] > 0, out["modes"]
+        # both hops of every echo, bulk and 4 KiB, ride a checksumming
+        # kernel: none took the XLA-copy lane
+        assert out["unchecked_segments"] == 0, out
         emit(line, cache_dir=cache_dir, **out)
 
         out, line = _run("hbm_cache", clock, phase_hbm_cache, dev)

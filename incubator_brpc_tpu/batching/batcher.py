@@ -191,10 +191,10 @@ class Batcher:
         self._stopped = False
         # batch service time EMA (us) the deadline guard subtracts
         self._service_ema_us = float(policy.expected_service_us)
-        # padding freelist: donated device rows for pad slots, the
-        # StagingRing shape from PR 4's ICI pipeline reused verbatim
-        # (keyed by (shape, dtype), LRU-bounded); built lazily via the
-        # pad_freelist property — host-padding handlers never touch it
+        # padding freelist: donated device rows for pad slots, a
+        # fused.StagingRing (keyed by (shape, dtype), LRU-bounded);
+        # built lazily via the pad_freelist property — host-padding
+        # handlers never touch it
         self._pad_freelist = None
         # -- stats / exposed variables --
         safe = full_name.replace(".", "_").lower()
@@ -633,7 +633,7 @@ class Batcher:
     def pad_freelist(self):
         """Donated device rows for pad slots (see __init__)."""
         if self._pad_freelist is None:
-            from incubator_brpc_tpu.parallel.ici import StagingRing
+            from incubator_brpc_tpu.batching.fused import StagingRing
 
             self._pad_freelist = StagingRing(depth=4, max_keys=8)
         return self._pad_freelist

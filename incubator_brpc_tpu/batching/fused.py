@@ -8,9 +8,9 @@ bytes — the inputs are the jax.Arrays the IOBuf ``DeviceRef`` segments
 already hold, and the outputs go back out as DeviceRefs.
 
 Padding rows are DONATED from the caller's freelist (the Batcher's
-per-method StagingRing — PR 4's staging-slot shape): steady state pads
-with recycled buffers instead of allocating, and every pad returns to
-the ring right after the stack copies it.  Pad VALUES are never read
+per-method ``StagingRing``, below): steady state pads with recycled
+buffers instead of allocating, and every pad returns to the ring right
+after the stack copies it.  Pad VALUES are never read
 (their output rows are discarded), so recycled contents are fine.
 
 Because jit specializes on the leading dim, padding to buckets bounds
@@ -21,9 +21,17 @@ running total so tests can assert the bound.
 from __future__ import annotations
 
 import threading
-from typing import Callable, List, Optional
+from collections import deque
+from typing import Callable, Dict, List, Optional, Tuple
 
-from incubator_brpc_tpu.observability.profiling import kernel_section
+from incubator_brpc_tpu.observability.profiling import (
+    hbm_account,
+    kernel_section,
+)
+
+# HBM heap profiler tag (observability/profiling.py): ring-resident
+# freelist slots
+_FREELIST_ACCT = hbm_account("batching.pad_freelist")
 
 _trace_count = [0]
 _jit_stack = None
@@ -31,6 +39,67 @@ _jit_stack = None
 # racing first calls would each build a wrapper, double-tracing one shape
 # and breaking the retraces <= buckets bound trace_count() exists to assert
 _init_lock = threading.Lock()
+
+
+class StagingRing:
+    """Ring of persistent device buffers keyed by (shape, dtype) — the
+    RDMA block_pool analog (rdma_endpoint.h:63-227 pre-registered
+    memory), here the Batcher's pad freelist.
+
+    The ring holds at most ``depth`` slots per key and at most
+    ``max_keys`` shapes (LRU-evicted — a caller cycling many shapes
+    degrades to plain allocation, never to unbounded HBM)."""
+
+    def __init__(self, depth: int = 4, max_keys: int = 8):
+        self.depth = depth
+        self.max_keys = max_keys
+        self._slots: Dict[Tuple, deque] = {}
+        self._lock = threading.Lock()
+        self.hits = 0
+        self.misses = 0
+
+    def acquire(self, shape, dtype):
+        """A reusable buffer of (shape, dtype), or None (caller
+        allocates; release() later seeds the ring).  An acquired slot
+        leaves the ``batching.pad_freelist`` HBM ledger — it is the
+        caller's memory until released back."""
+        key = (tuple(shape), str(dtype))
+        with self._lock:
+            q = self._slots.get(key)
+            if q:
+                # LRU touch: move key to the back of the eviction order
+                self._slots[key] = self._slots.pop(key)
+                self.hits += 1
+                arr, charge = q.popleft()
+                _FREELIST_ACCT.release(charge)
+                return arr
+            self.misses += 1
+            return None
+
+    def release(self, arr) -> None:
+        """Return a buffer to the ring.  Only call for buffers nothing
+        downstream holds."""
+        key = (tuple(arr.shape), str(arr.dtype))
+        with self._lock:
+            q = self._slots.get(key)
+            if q is None:
+                while len(self._slots) >= self.max_keys:
+                    # LRU eviction: dict preserves insertion order and
+                    # acquire() re-inserts on hit, so the first key is
+                    # the least recently used
+                    evq = self._slots.pop(next(iter(self._slots)))
+                    for _, charge in evq:
+                        _FREELIST_ACCT.release(charge)
+                q = self._slots[key] = deque()
+            if len(q) < self.depth:
+                q.append((arr, _FREELIST_ACCT.adopt(arr)))
+
+    def clear(self) -> None:
+        with self._lock:
+            for q in self._slots.values():
+                for _, charge in q:
+                    _FREELIST_ACCT.release(charge)
+            self._slots.clear()
 
 
 def trace_count() -> int:

@@ -6,7 +6,7 @@ off-the-shelf redis or binary-memcached client reads the cluster cache.
 
 Reply residency is decided PER CONNECTION: an ICI-peer socket
 (``sock.ici_port``) gets the value as a DeviceRef segment — HBM to HBM
-through the staging-ring pipeline, zero pulls — while a host transport
+through the fabric's transmit, zero pulls — while a host transport
 (TCP/DCN client) gets exact bytes through the store's manifested
 ``cache.host-spill`` choke point.
 

@@ -1090,26 +1090,13 @@ class ShardRoutedChannel(PartitionChannel):
         # delivery burst (per-port CQ wakes once for the whole fan-out)
         # with the fan-out span as task-local parent, so per-leg client
         # spans — and the collective legs under them — join one trace
-        from incubator_brpc_tpu.parallel.ici import (
-            get_fabric,
-            ici_pallas_stacked_segments,
-        )
+        from incubator_brpc_tpu.parallel.ici import get_fabric
 
         prev_span = (
             swap_current_span(fanout_span) if fanout_span is not None else None
         )
-        fabric = get_fabric()
-        # on the Pallas data plane, same-shape device payloads of a
-        # fan-out burst coalesce into stacked kernel dispatches at the
-        # fabric layer — count the coalesced segments so the trace
-        # proves the collective lowering fired (or didn't)
-        stacked_before = (
-            int(ici_pallas_stacked_segments.get_value())
-            if fabric.chunk_mode == "pallas" and fanout_span is not None
-            else None
-        )
         try:
-            with fabric.delivery_burst():
+            with get_fabric().delivery_burst():
                 for i in range(n):
                     sc = sub_ctrls[i]
                     if sc is None:
@@ -1134,16 +1121,6 @@ class ShardRoutedChannel(PartitionChannel):
                             )
                         leg_done()
         finally:
-            if stacked_before is not None:
-                stacked = (
-                    int(ici_pallas_stacked_segments.get_value())
-                    - stacked_before
-                )
-                if stacked:
-                    fanout_span.annotate(
-                        f"pallas stacked fan-out: {stacked} segments "
-                        f"coalesced"
-                    )
             if fanout_span is not None:
                 swap_current_span(prev_span)
         if done is None:

@@ -10,7 +10,7 @@ registry:
 
 1. **HBM heap profiler** — ``hbm_account(tag)`` hands out a per-tag
    accounting handle every HBM-pinning subsystem adopts: the cache
-   store's values and gather pads, StagingRing slots, sharded PS
+   store's values and gather pads, the batcher's pad freelist, sharded PS
    params, decode row state, in-flight ICI DeviceRefs.  Adopted bytes
    aggregate into ``rpc_hbm_bytes{component}``; /hotspots/hbm renders
    the per-tag profile and cross-checks the ledger against the

@@ -27,17 +27,13 @@ import contextlib
 import threading
 import time as _time
 import weakref
-from collections import deque
 from typing import Dict, List, Optional, Tuple
 
 from incubator_brpc_tpu import errors
 from incubator_brpc_tpu.chaos import injector as _chaos
 from incubator_brpc_tpu.observability.profiling import hbm_account, kernel_section
 from incubator_brpc_tpu.observability.span import Span
-from incubator_brpc_tpu.utils.segmentation import (
-    DEVICE_CHUNK_BYTES,
-    MIN_CHUNKS,
-)
+from incubator_brpc_tpu.utils.segmentation import DEVICE_CHUNK_BYTES
 from incubator_brpc_tpu.runtime.drain import close_drain, open_drain
 from incubator_brpc_tpu.runtime.execution_queue import ExecutionQueue
 from incubator_brpc_tpu.metrics.reducer import Adder
@@ -63,25 +59,15 @@ _BURST_TLS = threading.local()
 # overlap for nothing.  Coalescing is a small-RPC optimization.
 BURST_BYPASS_BYTES = 256 << 10
 
-# HBM heap profiler tags (observability/profiling.py): ring-resident
-# staging slots, and device payloads placed for an in-flight frame
-# (charged from device_put until the carrying DeviceRef dies)
-_STAGING_ACCT = hbm_account("ici.staging")
+# HBM heap profiler tag (observability/profiling.py): device payloads
+# placed for an in-flight frame (charged from device_put until the
+# carrying DeviceRef dies)
 _INFLIGHT_ACCT = hbm_account("ici.inflight")
 
-# Pallas DMA lane counters (chunk_mode="pallas"; registered in
-# analysis.invariants.METRIC_MODULES for the render lint).  ``frames``
-# counts fused kernel dispatches — the bench structure guard pins
-# frames == dispatches so a silent fallback to the chunked pipeline
-# fails loudly; ``fallbacks`` counts frames the lane declined
-# (off-TPU, untileable) and routed to the legacy transmit instead.
+# Nothing adds to these two any more; they read 0 and stay exposed
+# only because the benchmark's echo adapter prints them.
 ici_pallas_frames = Adder(0).expose("rpc_ici_pallas_frames")
-ici_pallas_bytes = Adder(0).expose("rpc_ici_pallas_bytes")
 ici_pallas_fallbacks = Adder(0).expose("rpc_ici_pallas_fallbacks")
-ici_pallas_stacked_frames = Adder(0).expose("rpc_ici_pallas_stacked_frames")
-ici_pallas_stacked_segments = Adder(0).expose(
-    "rpc_ici_pallas_stacked_segments"
-)
 # Same-chip segments delivered WITHOUT a transmit checksum: they took
 # the XLA-copy lane (off-TPU, a dtype outside transfer.KERNEL_DTYPES,
 # an untileable shape).  On a TPU moving tileable payloads it stays 0 —
@@ -116,74 +102,6 @@ def _fmt(coords) -> str:
         return str(coords)
 
 
-class StagingRing:
-    """Ring of persistent per-peer device staging buffers — the RDMA
-    block_pool analog (rdma_endpoint.h:63-227 pre-registered memory).
-
-    The pipelined chunked send donates a ring slot to each chunk's
-    copy+checksum kernel (ops/transfer.device_copy_with_checksum_chunk_
-    into): the kernel output aliases the slot's memory, the output goes
-    back into the ring after the frame assembles, and steady-state
-    sends perform ZERO per-call device allocation for chunk staging.
-    Slots are keyed by (shape, dtype); the ring holds at most ``depth``
-    slots per key (2-4 covers the double-buffer plus one in flight) and
-    at most ``max_keys`` shapes (LRU-evicted — a port cycling many
-    payload shapes degrades to plain allocation, never to unbounded
-    HBM)."""
-
-    def __init__(self, depth: int = 4, max_keys: int = 8):
-        self.depth = depth
-        self.max_keys = max_keys
-        self._slots: Dict[Tuple, deque] = {}
-        self._lock = threading.Lock()
-        self.hits = 0
-        self.misses = 0
-
-    def acquire(self, shape, dtype):
-        """A reusable buffer of (shape, dtype), or None (caller
-        allocates; release() later seeds the ring).  An acquired slot
-        leaves the ``ici.staging`` HBM ledger — it is the caller's
-        (in-flight frame's) memory until released back."""
-        key = (tuple(shape), str(dtype))
-        with self._lock:
-            q = self._slots.get(key)
-            if q:
-                # LRU touch: move key to the back of the eviction order
-                self._slots[key] = self._slots.pop(key)
-                self.hits += 1
-                arr, charge = q.popleft()
-                _STAGING_ACCT.release(charge)
-                return arr
-            self.misses += 1
-            return None
-
-    def release(self, arr) -> None:
-        """Return a frame's staging output to the ring.  Only call for
-        buffers nothing downstream holds (the chunked send releases
-        chunk outputs only after a concat copied them out)."""
-        key = (tuple(arr.shape), str(arr.dtype))
-        with self._lock:
-            q = self._slots.get(key)
-            if q is None:
-                while len(self._slots) >= self.max_keys:
-                    # LRU eviction: dict preserves insertion order and
-                    # acquire() re-inserts on hit, so the first key is
-                    # the least recently used
-                    evq = self._slots.pop(next(iter(self._slots)))
-                    for _, charge in evq:
-                        _STAGING_ACCT.release(charge)
-                q = self._slots[key] = deque()
-            if len(q) < self.depth:
-                q.append((arr, _STAGING_ACCT.adopt(arr)))
-
-    def clear(self) -> None:
-        with self._lock:
-            for q in self._slots.values():
-                for _, charge in q:
-                    _STAGING_ACCT.release(charge)
-            self._slots.clear()
-
-
 class IciPort:
     """One endpoint on the fabric (analog RdmaEndpoint). Owns the
     completion queue whose consumer parses frames through the shared
@@ -212,10 +130,6 @@ class IciPort:
         self._conns: Dict[Tuple[int, int], int] = {}
         self._lock = threading.Lock()
         self.closed = False
-        # chunk-staging buffer ring for pipelined sends INTO this port
-        # (the destination owns the staging memory, like the RDMA
-        # endpoint's registered receive blocks)
-        self.staging = StagingRing()
         # opt-in inline request dispatch (the usercode_in_dispatcher
         # threading model): a local same-process send may run this
         # server port's handlers on the SENDER's thread, trading the
@@ -397,7 +311,6 @@ class IciPort:
     def close(self):
         self.closed = True
         self._cq.stop()
-        self.staging.clear()
         with self._lock:
             conns = list(self._conns.values())
             self._conns.clear()
@@ -416,32 +329,15 @@ class IciFabric:
         self._ports: Dict[Tuple[int, int], IciPort] = {}
         self._lock = threading.Lock()
         # False (default): same-chip delivery runs every device segment
-        # through the Pallas transmit op (ops/transfer.transmit_array) so
+        # through the transmit op (ops/transfer.transmit_array_chunked) so
         # the payload demonstrably traverses HBM once per hop — the
         # honest model of an ICI transmission (a segment its producer
         # handed off, iobuf.hand_off, made that traversal already and
         # moves by reference). True: move by reference (the in-process
         # fast path; no device bytes move).
         self.zero_copy = False
-        # Large-frame chunk policy (shared with the DCN planner via
-        # utils/segmentation.py; docs/ici_pipeline.md):
-        #   "fused"     — the K-chunk pipeline compiled as ONE program
-        #                 (one host dispatch per hop; default — pays
-        #                 the per-launch host latency once per frame),
-        #   "pipelined" — one launch per chunk over the destination
-        #                 port's StagingRing (chunk k's kernel runs
-        #                 while chunk k+1's launch stages; per-chunk
-        #                 rpcz stamps show the overlap),
-        #   "pallas"    — the whole frame as ONE double-buffered Pallas
-        #                 DMA kernel (explicit semaphores: stage k+1
-        #                 pulls while stage k checksums and k-2 drains;
-        #                 ops/transfer.device_copy_with_checksum_dma);
-        #                 multi-segment frames additionally coalesce
-        #                 into one stacked per-destination transmit,
-        #   "off"       — whole-frame transmit (pre-chunking behavior).
-        # bench.py's ici_pipeline_curve sweeps mode x chunk size and
-        # applies the best measured config before the headline run.
-        self.chunk_mode = "fused"
+        # chunk size of the fused chunk program that frames of
+        # MIN_CHUNKS chunks or more take (utils/segmentation.py)
         self.chunk_bytes = DEVICE_CHUNK_BYTES
 
     @contextlib.contextmanager
@@ -572,7 +468,7 @@ class IciFabric:
             try:
                 if dst_port.device is not None:
                     zc = self.zero_copy if zero_copy is None else zero_copy
-                    self._place_segments(frame, dst_port, zc, leg)
+                    self._place_segments(frame, dst_port, zc)
             except BaseException as e:
                 # close the leg with an error first: the trace must
                 # show the hop that failed, not silently lose it
@@ -675,11 +571,10 @@ class IciFabric:
         return _bridge is not None and _bridge.route(coords) is not None
 
     def _place_segments(self, frame: IOBuf, dst_port: IciPort,
-                        zero_copy: bool, leg=None):
+                        zero_copy: bool):
         import jax
 
         device = dst_port.device
-        same_chip: List[Tuple] = []  # (ref, arr) headed for transmit
         for ref in frame.device_segments():
             arr = ref.whole_array()
             if arr is None:
@@ -695,268 +590,47 @@ class IciFabric:
                 if charged:
                     weakref.finalize(ref, _INFLIGHT_ACCT.release, charged)
             elif not zero_copy and not take_handed_off(arr):
-                # a handed-off buffer (a cache slab read's row slice)
-                # already traversed HBM when its program made it
-                same_chip.append((ref, arr))
-        if len(same_chip) > 1 and self.chunk_mode == "pallas":
-            # per-destination stacked transmit: same-shape segments of
-            # ONE frame (a DMSET bulk, a fan-out leg's tensor set)
-            # coalesce into a single stacked DMA kernel dispatch —
-            # the bulk-move collective lowering (docs/ici_pipeline.md)
-            same_chip = self._transmit_stacked(same_chip, dst_port, leg)
-        for ref, arr in same_chip:
-            # same-chip hop: the payload traverses HBM once through
-            # the fused copy+checksum kernel — receiver gets a fresh
-            # buffer plus a device-resident integrity checksum
-            ref.array, ref.csum = self._transmit_segment(
-                arr, dst_port, leg
-            )
-            if ref.csum is None:
-                ici_unchecked_segments << 1
+                # same-chip hop: the payload traverses HBM once through
+                # the copy+checksum kernel — receiver gets a fresh
+                # buffer plus a device-resident integrity checksum (a
+                # handed-off buffer, a cache slab read's row slice,
+                # already traversed HBM when its program made it)
+                ref.array, ref.csum = self._transmit_segment(arr, dst_port)
+                if ref.csum is None:
+                    ici_unchecked_segments << 1
 
-    def _transmit_stacked(self, pairs, dst_port: IciPort, leg):
-        """Coalesce a frame's same-(shape, dtype) device segments into
-        one stacked Pallas DMA transmit per group — one kernel dispatch
-        moves every segment headed to this destination, and each ref
-        gets its row back as a lazy device slice.  Integrity is at
-        stack granularity: ONE checksum per collective step (the
-        bulk-move contract; per-ref ``csum`` stays None).  Segments the
-        stack can't take (off-TPU, non-numeric, untileable, singleton
-        shapes) return for the per-segment path."""
-        import jax.numpy as jnp
+    def _transmit_segment(self, arr, dst_port: IciPort):
+        """One device segment through the transmit op; the op picks the
+        whole-frame kernel or the fused chunk program by size
+        (ops/transfer.transmit_array_chunked, docs/ici_pipeline.md)."""
+        from incubator_brpc_tpu.ops.transfer import transmit_array_chunked
 
-        from incubator_brpc_tpu.ops.transfer import (
-            chunk_plan_for,
-            device_copy_with_checksum_pallas,
-            kernel_lane,
-            pallas_stage_rows,
-        )
-
-        rest: List[Tuple] = []
-        groups: Dict[Tuple, List[Tuple]] = {}
-        for ref, arr in pairs:
-            if kernel_lane(arr):
-                key = (tuple(arr.shape), str(arr.dtype))
-                groups.setdefault(key, []).append((ref, arr))
-            else:
-                rest.append((ref, arr))
-        for grp in groups.values():
-            if len(grp) < 2:
-                rest.extend(grp)
-                continue
-            stacked = jnp.stack([a for _, a in grp])
-            plan = chunk_plan_for(stacked, self.chunk_bytes)
-            if plan[0] is None or not pallas_stage_rows(*plan[:2]):
-                rest.extend(grp)
-                continue
-            if _chaos.armed:
-                # same pre-dispatch walk as the fused/pallas frame path
-                self._chaos_walk_chunks(len(plan[2] or ()), dst_port)
-            with kernel_section("ici.pallas"):
-                out, _stack_csum = device_copy_with_checksum_pallas(
-                    stacked, self.chunk_bytes, plan=plan
-                )
-            for i, (ref, _) in enumerate(grp):
-                ref.array = out[i]
-                ref.csum = None  # integrity rides the stack checksum
-            ici_pallas_frames << 1
-            ici_pallas_bytes << int(stacked.nbytes)
-            ici_pallas_stacked_frames << 1
-            ici_pallas_stacked_segments << len(grp)
-            if leg is not None:
-                leg.annotate(
-                    f"pallas stacked transmit: {len(grp)} segments, "
-                    f"one dispatch"
-                )
-        return rest
-
-    def _transmit_segment(self, arr, dst_port: IciPort, leg):
-        """One device segment through the transmit op, per the fabric's
-        chunk policy (docs/ici_pipeline.md)."""
-        from incubator_brpc_tpu.ops.transfer import (
-            chunk_plan_for,
-            transmit_array,
-            transmit_array_chunked,
-        )
-
-        mode = self.chunk_mode
-        if (
-            mode == "off"
-            or int(arr.nbytes) < MIN_CHUNKS * self.chunk_bytes
-        ):
-            return transmit_array(arr)
-        if mode == "pipelined":
-            return self._transmit_pipelined(arr, dst_port, leg)
-        if mode == "pallas":
-            return self._transmit_pallas(arr, dst_port, leg)
-        plan = None
+        walk = None
         if _chaos.armed:
-            # the fused pipeline is ONE compiled program, so the
-            # per-chunk ici.chunk site is walked over the SAME plan
-            # before dispatch: a FaultPlan targeting chunk k faults the
-            # frame under either chunk mode, with identical traversal
-            # indices (chunk_plan_for is the one plan source)
-            plan = chunk_plan_for(arr, self.chunk_bytes)
-            self._chaos_walk_chunks(len(plan[2] or ()), dst_port)
-        return transmit_array_chunked(arr, self.chunk_bytes, plan=plan)
+            # the fused program is ONE dispatch, so the per-chunk
+            # ici.chunk site is walked over its plan before dispatch
+            def walk(total_chunks):
+                self._chaos_walk_chunks(total_chunks, dst_port)
+
+        return transmit_array_chunked(arr, self.chunk_bytes, walk=walk)
 
     @staticmethod
-    def _chaos_walk_chunks_step(k: int, total_chunks: int, dst_port: IciPort):
-        """One consult of the ici.chunk site (armed plans only).
-        reset abandons the frame mid-stream — send() turns it into ONE
-        ERPC error, and no window credit was reserved yet, so nothing
-        leaks (regression-tested under a seeded FaultPlan); delay_us
-        stretches one pipeline stage."""
-        spec = _chaos.check("ici.chunk", peer=_LazyPeer(dst_port.coords))
-        if spec is not None:
+    def _chaos_walk_chunks(total_chunks: int, dst_port: IciPort):
+        """Walk every planned chunk through the ici.chunk site (armed
+        plans only).  reset abandons the frame mid-stream — send() turns
+        it into ONE ERPC error, and no window credit was reserved yet,
+        so nothing leaks (regression-tested under a seeded FaultPlan);
+        delay_us stretches one chunk's stage."""
+        for k in range(total_chunks):
+            spec = _chaos.check("ici.chunk", peer=_LazyPeer(dst_port.coords))
+            if spec is None:
+                continue
             if spec.action == "delay_us":
                 _chaos.sleep_us(spec.arg)
             elif spec.action == "reset":
                 raise ConnectionResetError(
                     f"chaos: ici chunk {k}/{total_chunks} reset"
                 )
-
-    @staticmethod
-    def _chaos_walk_chunks(total_chunks: int, dst_port: IciPort):
-        """Walk every planned chunk through the ici.chunk site — the
-        fused mode's pre-dispatch equivalent of the pipelined mode's
-        inline per-chunk consults (identical traversal indices)."""
-        for k in range(total_chunks):
-            IciFabric._chaos_walk_chunks_step(k, total_chunks, dst_port)
-
-    def _transmit_pallas(self, arr, dst_port: IciPort, leg):
-        """Whole-frame transmit as ONE double-buffered Pallas DMA
-        kernel (ops/transfer.device_copy_with_checksum_dma): explicit
-        in/out DMA semaphores overlap stage k+1's HBM→VMEM pull with
-        stage k's checksum and stage k-2's VMEM→HBM drain — no
-        per-chunk launch gap, no emitter round trips.  Rides the same
-        segmentation plan as the other modes (chunk_plan_for — chaos
-        traversal indices agree), and opportunistically donates a
-        frame-shaped StagingRing slot so callers that recycle response
-        buffers (``dst_port.staging.release``) get allocation-free
-        steady state.  Off-TPU (tests, JAX_PLATFORMS=cpu), or for a
-        dtype the kernels do not compile for (float16), the lane falls
-        back to the legacy transmit — the interpret flavor exists for
-        tier-1 coverage, not the data plane (kernel_lane gate, counted
-        in rpc_ici_pallas_fallbacks)."""
-        from incubator_brpc_tpu.ops.transfer import (
-            chunk_plan_for,
-            device_copy_with_checksum_dma,
-            device_copy_with_checksum_dma_into,
-            kernel_lane,
-            pallas_stage_rows,
-            transmit_array,
-        )
-
-        shape = arr.shape
-        v, block_rows, chunks = chunk_plan_for(arr, self.chunk_bytes)
-        if v is None:
-            ici_pallas_fallbacks << 1
-            return transmit_array(arr)
-        total_chunks = len(chunks or ())
-        if _chaos.armed:
-            # ONE compiled program per frame, so the per-chunk
-            # ici.chunk site walks the SAME plan pre-dispatch — the
-            # fused-mode discipline, identical traversal indices.
-            # Walked BEFORE the platform gate: off-TPU fallback frames
-            # stay chaos-covered, exactly like fused/pipelined mode
-            self._chaos_walk_chunks(total_chunks, dst_port)
-        stage_rows = pallas_stage_rows(v, block_rows)
-        if not (kernel_lane(arr) and stage_rows):
-            ici_pallas_fallbacks << 1
-            return transmit_array(arr)
-        slot = dst_port.staging.acquire(v.shape, v.dtype)
-        with kernel_section("ici.pallas"):
-            if slot is not None:
-                out, csum = device_copy_with_checksum_dma_into(
-                    v, slot, block_rows, stage_rows
-                )
-            else:
-                out, csum = device_copy_with_checksum_dma(
-                    v, block_rows, stage_rows
-                )
-        ici_pallas_frames << 1
-        ici_pallas_bytes << int(arr.nbytes)
-        if leg is not None:
-            leg.chunk_mark("ici", 0, 1, int(arr.nbytes))
-        return (out.reshape(shape) if out.shape != shape else out), csum
-
-    def _transmit_pipelined(self, arr, dst_port: IciPort, leg):
-        """Launch-per-chunk transmit: chunk k's copy+checksum kernel
-        runs on device while the host stages chunk k+1's launch and
-        chunk k-1's staging slot recycles through the destination
-        port's StagingRing.  The lane accumulator chains through the
-        chunks, so the receiver still verifies ONE integrity value for
-        the whole frame (and it equals the whole-frame checksum
-        bit-for-bit).  Falls back to the whole-frame op for shapes the
-        kernel doesn't tile."""
-        import jax
-        import jax.numpy as jnp
-
-        from incubator_brpc_tpu.ops.transfer import (
-            chunk_plan_for,
-            device_copy_with_checksum_chunk,
-            device_copy_with_checksum_chunk_into,
-            fold_checksum,
-            kernel_lane,
-            transmit_array,
-        )
-
-        shape = arr.shape
-        x, block_rows, chunks = chunk_plan_for(arr, self.chunk_bytes)
-        if x is None:
-            return transmit_array(arr)  # untileable: whole-frame path
-        if len(chunks) < MIN_CHUNKS:
-            return transmit_array(arr)
-        m, n = x.shape
-        row_bytes = n * jnp.dtype(x.dtype).itemsize
-        # off-TPU (tests, JAX_PLATFORMS=cpu) the Mosaic kernel can't
-        # run: the pipeline orchestration is identical but each chunk
-        # is an XLA copy and no checksum accumulates (matching the
-        # whole-frame off-TPU behavior)
-        use_csum = kernel_lane(x)
-        acc = jnp.zeros((1, n), jnp.float32) if use_csum else None
-        ring = dst_port.staging if use_csum else None
-        outs = []
-        total_chunks = len(chunks)
-        if ring is not None:
-            # a frame holds every chunk output until the end-of-frame
-            # concat, so zero-alloc steady state needs a slot per chunk
-            # in flight — grow the ring to this frame's chunk count
-            # (bounded: 2 x the default 64MB/8MB plan)
-            ring.depth = max(ring.depth, min(total_chunks, 16))
-        for k, (off, rows) in enumerate(chunks):
-            if _chaos.armed:
-                self._chaos_walk_chunks_step(k, total_chunks, dst_port)
-            # device-time attribution: one dispatch window per chunk
-            # launch (the pipeline's overlap unit)
-            with kernel_section("ici.chunk"):
-                xc = jax.lax.slice_in_dim(x, off, off + rows)
-                if use_csum:
-                    slot = ring.acquire((rows, n), x.dtype)
-                    if slot is not None:
-                        oc, acc = device_copy_with_checksum_chunk_into(
-                            xc, acc, slot, block_rows
-                        )
-                    else:
-                        oc, acc = device_copy_with_checksum_chunk(
-                            xc, acc, block_rows
-                        )
-                else:
-                    oc = jnp.array(xc, copy=True)
-            outs.append(oc)
-            if leg is not None:
-                leg.chunk_mark("ici", k, total_chunks, rows * row_bytes)
-        out = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=0)
-        if ring is not None and len(outs) > 1:
-            # the concat copied the chunk outputs out of the staging
-            # slots — they are free to recycle.  (With a single chunk
-            # `out` IS the slot-backed array and now belongs to the
-            # receiver: never recycle it.)
-            for oc in outs:
-                ring.release(oc)
-        csum = fold_checksum(acc) if use_csum else None
-        return (out.reshape(shape) if out.shape != shape else out), csum
 
 
 _fabric: Optional[IciFabric] = None

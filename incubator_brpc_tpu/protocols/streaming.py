@@ -8,7 +8,7 @@ frames ride the same connection and are routed to the Stream by id.
 Framing: b"TSTM" + stream_id(u64 BE) + frame_type(u8) + size(u32 BE)
 + payload. Over the ICI transport the payload IOBuf may carry device
 segments — chunked ring-style neighbor exchange of HBM tensors uses
-exactly this path (the fabric's staging-ring pipeline chunks them;
+exactly this path (the fabric's same-chip transmit chunks them;
 see docs/streaming.md).  Host payloads larger than the shared wire
 chunk are split by the Stream into DATA_PART frames closed by one
 DATA frame, so message boundaries survive segmentation.

@@ -20,8 +20,8 @@ docs/streaming.md for the full contract):
     closed by one DATA frame, so one oversized write can neither stall
     the connection's writer role nor deadlock against max_buf_size;
     device payloads are NEVER split here — over an ICI socket the
-    fabric's chunked staging-ring pipeline (PR 4) moves them zero-copy
-    with chained checksums;
+    fabric's same-chip transmit moves them HBM to HBM with chained
+    checksums;
   * feedback batching — a receiver accumulates consumed bytes until
     ``min_buf_size`` before sending FEEDBACK (capped at half the
     peer's max_buf_size so batching can never starve a blocked
@@ -323,8 +323,8 @@ class Stream:
 
     def write_device(self, array, timeout: Optional[float] = 10.0) -> int:
         """Stream one HBM-resident array as a single message.  Over an
-        ICI socket the payload rides the fabric's chunked staging-ring
-        pipeline zero-copy with chained checksums (docs/ici_pipeline.md)
+        ICI socket the payload rides the fabric's same-chip transmit, HBM
+        to HBM with chained checksums (docs/ici_pipeline.md)
         — this layer never splits or materializes device payloads."""
         buf = IOBuf()
         buf.append_device(array)

@@ -16,20 +16,13 @@ Three knobs, one per layer:
   to amortize per-chunk syscall/staging cost, small enough that the
   send window (a handful of chunks) bounds memory and a mid-stream
   fault loses little.
-- ``DEVICE_CHUNK_BYTES`` — device-payload chunks for the chunked
-  copy+checksum transmit (ops/transfer.py): the unit the pipelined ICI
-  send double-buffers.  ~8MB: a 64MB frame becomes 8 chunks, enough
-  overlap stages to hide per-chunk launch/staging latency without
-  shrinking each Pallas grid below its efficient size.
+- ``DEVICE_CHUNK_BYTES`` — device-payload chunks of the fused chunk
+  program (ops/transfer.py): the unit the same-chip ICI transmit
+  pipelines.  ~8MB: a 64MB frame becomes 8 chunks, enough overlap
+  stages without shrinking each Pallas grid below its efficient size.
 - ``MIN_CHUNKS`` — frames smaller than this many chunks skip chunking
-  entirely (whole-frame path): pipelining needs at least two stages in
-  flight to overlap anything.
-
-The Pallas DMA transmit (``chunk_mode="pallas"``) adds one on-chip
-knob: its double-buffered VMEM staging slots are sized here too
-(``PALLAS_STAGE_BYTES``/``fit_stage_rows``), so the kernel's DMA stage
-plan is a pure function of the SAME row/block decomposition the fused
-and pipelined modes chunk by — one planner, three transports.
+  entirely (whole-frame kernel): pipelining needs at least two stages
+  in flight to overlap anything.
 """
 
 from __future__ import annotations
@@ -39,39 +32,6 @@ from typing import Iterable, Iterator, List, Tuple
 WIRE_CHUNK_BYTES = 4 << 20
 DEVICE_CHUNK_BYTES = 8 << 20
 MIN_CHUNKS = 2
-
-# Pallas DMA transmit staging (ops/transfer.py device_copy_with_
-# checksum_dma): each VMEM staging slot holds up to this many bytes and
-# PALLAS_DB_DEPTH slots double-buffer each direction (in + out), so the
-# kernel's resident VMEM footprint is ≤ 2 * depth * PALLAS_STAGE_BYTES
-# — comfortably inside the ~16MB VMEM the pipelined grids already
-# assume, while keeping individual DMAs ≥~2MB (large enough that the
-# HBM controller runs at line rate instead of descriptor rate).
-PALLAS_STAGE_BYTES = 2 << 20
-PALLAS_DB_DEPTH = 2
-
-
-def fit_stage_rows(rows: int, row_bytes: int, align_rows: int,
-                   budget_bytes: int = PALLAS_STAGE_BYTES) -> int:
-    """Rows per DMA stage for the Pallas double-buffered transmit.
-
-    The stage is a multiple of ``align_rows`` (the checksum kernel's
-    block rows — compute granularity can never straddle a stage) that
-    DIVIDES ``rows`` (every stage identical, so the kernel's DMA loop
-    has static sizes) and fits ``budget_bytes``.  Falls back to one
-    block per stage when nothing larger fits — correctness never
-    depends on the budget, only DMA efficiency does."""
-    if align_rows <= 0 or rows % align_rows:
-        raise ValueError(
-            f"rows={rows} not a multiple of align_rows={align_rows}"
-        )
-    nblocks = rows // align_rows
-    k = max(1, budget_bytes // max(1, align_rows * row_bytes))
-    k = min(k, nblocks)
-    while nblocks % k:
-        k -= 1
-    return k * align_rows
-
 
 def plan_chunks(total: int, chunk_bytes: int = WIRE_CHUNK_BYTES) -> List[Tuple[int, int]]:
     """(offset, length) chunk windows covering ``total`` bytes in order.

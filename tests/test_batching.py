@@ -377,7 +377,7 @@ def test_padding_buckets_bound_jit_retraces():
     import jax.numpy as jnp
 
     from incubator_brpc_tpu.batching import fused
-    from incubator_brpc_tpu.parallel.ici import StagingRing
+    from incubator_brpc_tpu.batching.fused import StagingRing
 
     policy = BatchPolicy(max_batch_size=8, padding_buckets=(1, 2, 4, 8))
     ring = StagingRing(depth=8, max_keys=4)
@@ -591,3 +591,31 @@ def test_disable_method_batching_restores_direct_path():
         assert c.response_attachment.to_bytes() == b"v"
     finally:
         srv.stop()
+
+
+def test_staging_ring_bookkeeping():
+    import numpy as np
+
+    from incubator_brpc_tpu.batching.fused import StagingRing
+
+    ring = StagingRing(depth=2, max_keys=2)
+    assert ring.acquire((4, 4), "float32") is None  # cold: caller allocates
+    a = np.zeros((4, 4), dtype=np.float32)
+    ring.release(a)
+    got = ring.acquire((4, 4), "float32")
+    assert got is a
+    assert ring.acquire((4, 4), "float32") is None  # ring emptied
+    # depth bound: a third same-shape release is dropped
+    b, c, d = (np.zeros((4, 4), dtype=np.float32) for _ in range(3))
+    for arr in (b, c, d):
+        ring.release(arr)
+    assert ring.acquire((4, 4), "float32") is b
+    assert ring.acquire((4, 4), "float32") is c
+    assert ring.acquire((4, 4), "float32") is None
+    # key bound: LRU shape evicted when a third shape arrives
+    ring.release(np.zeros((4, 4), dtype=np.float32))    # key A (recent)
+    ring.release(np.zeros((8, 8), dtype=np.float32))    # key B
+    ring.acquire((4, 4), "float32")                     # touch A → B is LRU
+    ring.release(np.zeros((2, 2), dtype=np.float32))    # key C evicts B
+    assert ring.acquire((8, 8), "float32") is None
+    assert ring.acquire((2, 2), "float32") is not None

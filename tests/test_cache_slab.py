@@ -482,9 +482,9 @@ def test_a_get_reply_moves_by_reference(monkeypatch):
     calls = []
     real = IciFabric._transmit_segment
 
-    def counting(self, arr, dst_port, leg):
+    def counting(self, arr, dst_port):
         calls.append(tuple(arr.shape))
-        return real(self, arr, dst_port, leg)
+        return real(self, arr, dst_port)
 
     monkeypatch.setattr(IciFabric, "_transmit_segment", counting)
     try:

@@ -1,8 +1,8 @@
 """The main path's device programs, compiled for a described v5e:2x2 at
 real sizes — no chip (on-chip-measurement guide, section 2).
 
-Interpret-mode tests cannot see what the TPU compiler refuses: the
-DMA-into kernel's missing slot ref, Mosaic's missing uint8 -> f32 cast,
+Interpret-mode tests cannot see what the TPU compiler refuses: a
+donated kernel's missing slot ref, Mosaic's missing uint8 -> f32 cast,
 and VMEM overflow on wide rows all passed every CPU test before PR 21.
 Each case here compiles one program and checks that the Pallas kernel
 (``tpu_custom_call``) or the collective is in it.  The topology is
@@ -68,7 +68,7 @@ def _plan(shape, dtype):
     v, block_rows, chunks = T.chunk_plan_for(
         jax.ShapeDtypeStruct(shape, dtype), DEVICE_CHUNK_BYTES
     )
-    return v.shape, block_rows, T.pallas_stage_rows(v, block_rows), chunks
+    return v.shape, block_rows, chunks
 
 
 def _kernel_case(name):
@@ -79,28 +79,16 @@ def _kernel_case(name):
     from incubator_brpc_tpu.ops import transfer as T
 
     f32 = jnp.float32
-    shape, br, sr, chunks = _plan(MIB64_F32, f32)
+    shape, br, chunks = _plan(MIB64_F32, f32)
     lane = ((1, shape[1]), f32)
     rows = chunks[0][1]
     return {
         "whole": (T.device_copy_with_checksum, [(shape, f32)]),
         "fused": (lambda x: T._chunked_copy_csum(x, tuple(chunks), br, False),
                   [(shape, f32)]),
-        "dma": (lambda x: T.device_copy_with_checksum_dma(x, br, sr),
-                [(shape, f32)]),
-        "dma_into": (
-            lambda x, s: T.device_copy_with_checksum_dma_into(x, s, br, sr),
-            [(shape, f32), (shape, f32)],
-        ),
         "chunk": (
             lambda x, c: T.device_copy_with_checksum_chunk(x, c, br),
             [((rows, shape[1]), f32), lane],
-        ),
-        "chunk_into": (
-            lambda x, c, s: T.device_copy_with_checksum_chunk_into(
-                x, c, s, br
-            ),
-            [((rows, shape[1]), f32), lane, ((rows, shape[1]), f32)],
         ),
     }[name]
 
@@ -115,7 +103,8 @@ def _compile(fn, args, sharding):
 @pytest.mark.parametrize(
     "case",
     [
-        "whole", "fused", "dma", "dma_into", "chunk", "chunk_into",
+        "whole", "fused", "chunk",
+        "chunked_uint8_64mib", "chunked_bf16_64mib", "chunked_int32_64mib",
         "transmit_uint8_1mib", "transmit_f32_4kib", "transmit_f32_4096x4096",
         "transmit_bf16_1024x8192", "transmit_int32_1024x4096",
         "sharded_ps_forward",
@@ -138,6 +127,22 @@ def test_main_path_compiles_for_v5e(case, topo, one_chip, on_tpu):
             "transmit_int32_1024x4096": ((1024, 4096), jnp.int32),
         }[case]
         compiled = _compile(T.transmit_array, [(shape, dtype)], one_chip)
+    elif case.startswith("chunked_"):
+        # the fabric's entry at the bulk size: the size rule sends these
+        # through the fused chunk program, which only f32 compiled on
+        # before (uint8 is where the missing unsigned cast showed)
+        from incubator_brpc_tpu.utils.segmentation import DEVICE_CHUNK_BYTES
+
+        shape, dtype = {
+            "chunked_uint8_64mib": ((64 << 20,), jnp.uint8),
+            "chunked_bf16_64mib": ((8192, 4096), jnp.bfloat16),
+            "chunked_int32_64mib": (MIB64_F32, jnp.int32),
+        }[case]
+        compiled = _compile(
+            lambda x: T.transmit_array_chunked(x, DEVICE_CHUNK_BYTES),
+            [(shape, dtype)], one_chip,
+        )
+        assert "_chunked_copy_csum" in compiled.as_text(), case
     else:
         fn, args = _kernel_case(case)
         compiled = _compile(fn, args, one_chip)

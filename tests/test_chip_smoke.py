@@ -22,66 +22,48 @@ import chip_smoke  # noqa: E402
 
 @pytest.fixture
 def interpret_kernels(monkeypatch):
-    """Every transmit kernel through the Pallas interpreter, the DMA
-    kernel's donated-slot flavor included; small fabric chunks so a
-    512 KiB frame takes each chunk mode's multi-chunk path."""
-    import jax.numpy as jnp
-
+    """Every transmit kernel through the Pallas interpreter; small
+    fabric chunks so a 512 KiB frame takes the fused chunk program.
+    Yields the count of fused-program dispatches."""
     from incubator_brpc_tpu.ops import transfer as T
     from incubator_brpc_tpu.parallel.ici import get_fabric
 
     chunked = T._chunked_copy_csum
     chunk = T.device_copy_with_checksum_chunk
+    fused_calls = []
 
-    def dma_into(x, slot, block_rows, stage_rows):
-        carry = jnp.zeros((1, x.shape[1]), jnp.float32)
-        out, acc = T._dma_call(x, carry, block_rows, stage_rows, True,
-                               slot=slot)
-        return out, jnp.sum(acc)
+    def fused(x, chunks, block_rows, interpret):
+        fused_calls.append(len(chunks))
+        return chunked(x, chunks=chunks, block_rows=block_rows,
+                       interpret=True)
 
     monkeypatch.setattr(T, "_on_tpu", lambda arr: True)
     monkeypatch.setattr(
         T, "device_copy_with_checksum",
         functools.partial(T.device_copy_with_checksum, interpret=True),
     )
-    monkeypatch.setattr(
-        T, "_chunked_copy_csum",
-        lambda x, chunks, block_rows, interpret: chunked(
-            x, chunks=chunks, block_rows=block_rows, interpret=True
-        ),
-    )
+    monkeypatch.setattr(T, "_chunked_copy_csum", fused)
     monkeypatch.setattr(
         T, "device_copy_with_checksum_chunk",
         lambda x, acc, br, interpret=False: chunk(x, acc, br, True),
     )
-    monkeypatch.setattr(
-        T, "device_copy_with_checksum_chunk_into",
-        lambda x, acc, slot, br: chunk(x, acc, br, True),
-    )
-    monkeypatch.setattr(
-        T, "device_copy_with_checksum_dma",
-        functools.partial(T.device_copy_with_checksum_dma, interpret=True),
-    )
-    monkeypatch.setattr(T, "device_copy_with_checksum_dma_into", dma_into)
-    fabric = get_fabric()
-    saved = fabric.chunk_bytes
-    fabric.chunk_bytes = 64 << 10
-    yield
-    fabric.chunk_bytes = saved
+    monkeypatch.setattr(get_fabric(), "chunk_bytes", 64 << 10)
+    yield fused_calls
 
 
 def test_ici_echo_phase_every_chunk_mode(interpret_kernels):
+    """The one same-chip path: bulk echoes take the fused chunk program,
+    the 4 KiB echo the whole-frame kernel, and every segment of both
+    is checksummed."""
     import jax
 
     out = chip_smoke.phase_ici_echo(jax.devices()[0], shape=(512, 256),
                                     echoes=2)
-    assert set(out["modes"]) == set(chip_smoke.CHUNK_MODES)
-    for mode, m in out["modes"].items():
-        assert m["pallas_fallbacks"] == 0, (mode, m)
-        assert m["unchecked_segments"] == 0, (mode, m)
-    assert out["modes"]["pallas"]["pallas_frames"] == 4
-    assert out["modes"]["pallas"]["ring_hits"] > 0
-    assert out["modes"]["pipelined"]["ring_hits"] > 0
+    assert out["echoes"] == 2 and out["bytes"] == 512 * 256 * 4
+    # both hops of both bulk echoes, 2 chunks each; the 4 KiB echo is
+    # under the size line
+    assert interpret_kernels == [2] * 4, interpret_kernels
+    assert out["unchecked_segments"] == 0, out
     assert out["ici_4k_echo"] and out["native_tcp_4k_echo"]
 
 

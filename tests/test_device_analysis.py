@@ -51,20 +51,16 @@ def test_census_scale_and_known_sites_on_tree():
                      "donation", "slot-acquire", "slot-release",
                      "host-sync", "allow-scope", "pallas-call"):
         assert expected in kinds, f"census never saw a {expected} site"
-    # the Pallas DMA data plane is visible: transfer.py's pallas_call
-    # kernels (incl. the double-buffered DMA grid) are census sites
-    # (four since PR 21 removed the unused plain device_copy)
+    # the transmit kernels are visible: transfer.py's two pallas_call
+    # sites, the whole-frame kernel and the fused program's chunk kernel
     pallas_sites = census.by_kind("pallas-call")
-    assert len(pallas_sites) >= 4, pallas_sites
-    assert any(s.func == "_dma_call" for s in pallas_sites), pallas_sites
-    # the donation map learned ops/transfer's donating kernels, the
-    # anchor of the read-after-donate rule on the real tree
-    assert any("chunk_into" in name for name in census.donating), (
-        census.donating
-    )
-    assert any("dma_into" in name for name in census.donating), (
-        census.donating
-    )
+    assert {s.func for s in pallas_sites} >= {
+        "device_copy_with_checksum", "device_copy_with_checksum_chunk",
+    }, pallas_sites
+    # the donations on the tree: the cache slab's in-place programs
+    assert any(
+        s.module == "cache/store.py" for s in census.by_kind("donation")
+    ), census.by_kind("donation")
 
 
 @pytest.fixture(scope="module")

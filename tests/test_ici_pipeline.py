@@ -1,6 +1,7 @@
-"""Pipelined ICI data plane: chunked double-buffered transfers,
-chunk-accumulating checksums, coalesced delivery, and the credit-flow
-invariants under partial pipeline failure (docs/ici_pipeline.md).
+"""The ICI data plane: the same-chip transmit chosen by size (the
+whole-frame kernel or the fused chunk program), chunk-accumulating
+checksums, coalesced delivery, and the credit-flow invariants under
+partial pipeline failure (docs/ici_pipeline.md).
 
 Runs on whatever backend the environment offers; checksum-equality
 tests force Pallas interpret mode so the REAL kernels' semantics are
@@ -99,49 +100,17 @@ def test_chunked_checksum_equals_whole_frame_interpret(m, n, chunk_bytes):
     assert float(whole_csum) == float(chunk_csum)
 
 
-def test_per_chunk_kernel_chain_matches_whole_frame():
-    """The launch-per-chunk flavor (what the pipelined send issues)
-    chained by hand produces the identical checksum and payload."""
-    import numpy as np
-    import jax.numpy as jnp
-
-    from incubator_brpc_tpu.ops.transfer import (
-        _fit_block_rows,
-        device_copy_with_checksum,
-        device_copy_with_checksum_chunk,
-        fold_checksum,
-    )
-
-    m, n = 384, 128
-    x = jnp.asarray(np.random.RandomState(0).randn(m, n).astype(np.float32))
-    block_rows = _fit_block_rows(m, n, x.dtype)
-    acc = jnp.zeros((1, n), jnp.float32)
-    outs = []
-    for off in range(0, m, 128):
-        oc, acc = device_copy_with_checksum_chunk(
-            x[off : off + 128], acc, block_rows, True
-        )
-        outs.append(np.asarray(oc))
-    whole_out, whole_csum = device_copy_with_checksum(x, interpret=True)
-    assert float(fold_checksum(acc)) == float(whole_csum)
-    np.testing.assert_array_equal(
-        np.concatenate(outs), np.asarray(whole_out)
-    )
-
-
-# ---- pipelined transmit through a real RPC ---------------------------------
+# ---- chunked transmit through a real RPC -----------------------------------
 
 
 @pytest.fixture
-def pipelined_fabric():
+def chunked_fabric(monkeypatch):
     from incubator_brpc_tpu.parallel.ici import get_fabric
 
     fabric = get_fabric()
-    saved = (fabric.chunk_mode, fabric.chunk_bytes)
-    fabric.chunk_mode = "pipelined"
-    fabric.chunk_bytes = 64 * 1024  # small: a 1MB payload chunks even here
+    # small: a 1MB payload chunks even here
+    monkeypatch.setattr(fabric, "chunk_bytes", 64 * 1024)
     yield fabric
-    fabric.chunk_mode, fabric.chunk_bytes = saved
 
 
 def _ici_echo_server():
@@ -157,7 +126,7 @@ def _ici_echo_server():
     return srv, f"ici://slice{s}/chip{c}"
 
 
-def test_pipelined_chunked_echo_content_and_fresh_buffer(pipelined_fabric):
+def test_fused_chunked_echo_content(chunked_fabric):
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -174,57 +143,145 @@ def test_pipelined_chunked_echo_content_and_fresh_buffer(pipelined_fabric):
         )
         assert ch.init(addr) == 0
         stub = echo_stub(ch)
-        x = jnp.arange(1024 * 256, dtype=jnp.float32).reshape(1024, 256)
+        x = jnp.arange(512 * 512, dtype=jnp.float32).reshape(512, 512)
         c = Controller()
         c.request_attachment.append_device(x)
         stub.Echo(c, EchoRequest(message="bulk"))
         assert not c.failed(), c.error_text()
         arrs = c.response_attachment.device_arrays()
-        assert len(arrs) == 1 and arrs[0].shape == (1024, 256)
+        assert len(arrs) == 1 and arrs[0].shape == (512, 512)
         assert arrs[0] is not x, "chunked transmit must produce a fresh buffer"
         np.testing.assert_array_equal(np.asarray(arrs[0]), np.asarray(x))
     finally:
         srv.stop()
 
 
-def test_fused_chunked_echo_content(pipelined_fabric):
+# ---- one transmit path, chosen by size --------------------------------------
+
+
+@pytest.mark.parametrize(
+    "shape,dtype,program,chunks",
+    [
+        # 4 KiB: the whole-frame kernel
+        ((8, 128), "float32", "whole", 0),
+        # 8 rows under MIN_CHUNKS x 64 KiB: still the whole-frame kernel
+        ((248, 128), "float32", "whole", 0),
+        # exactly at the line: fused — one chunk, since chunks align to
+        # the frame's one 256-row block
+        ((256, 128), "float32", "fused", 1),
+        # 4 chunks of 128 rows plus an 8-row tail
+        ((520, 128), "float32", "fused", 5),
+        # 1-D bytes, lane-viewed as (256, 4096)
+        ((1 << 20,), "uint8", "fused", 2),
+        ((512, 256), "bfloat16", "fused", 2),
+        # no kernel compiles for float16: the XLA copy, unchecked
+        ((512, 256), "float16", "xla", 0),
+        # 40,000 f32 is not a multiple of 128 lanes: nothing tiles
+        ((40_000,), "float32", "xla", 0),
+    ],
+)
+def test_transmit_program_is_chosen_by_size(
+    chunked_fabric, monkeypatch, shape, dtype, program, chunks
+):
+    """A segment sent through the fabric takes exactly one program,
+    picked by its size (and, below that, by whether a kernel can take
+    it): the output is bit-equal and a fresh buffer, and a kernel's
+    checksum equals the whole-frame kernel's on the same data."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from incubator_brpc_tpu.client.channel import Channel, ChannelOptions
-    from incubator_brpc_tpu.client.controller import Controller
-    from incubator_brpc_tpu.models.echo import echo_stub
-    from incubator_brpc_tpu.protos.echo_pb2 import EchoRequest
+    from incubator_brpc_tpu.ops import transfer as T
+    from incubator_brpc_tpu.parallel.ici import ici_unchecked_segments
 
-    pipelined_fabric.chunk_mode = "fused"
-    srv, addr = _ici_echo_server()
+    whole, fused, xla = (
+        T.device_copy_with_checksum, T._chunked_copy_csum, T._xla_copy
+    )
+    calls = []
+
+    def count_whole(x, interpret=False):
+        calls.append(("whole", 0))
+        return whole(x, interpret=True)
+
+    def count_fused(x, chunks, block_rows, interpret):
+        calls.append(("fused", len(chunks)))
+        return fused(x, chunks=chunks, block_rows=block_rows, interpret=True)
+
+    def count_xla(x):
+        calls.append(("xla", 0))
+        return xla(x)
+
+    monkeypatch.setattr(T, "_on_tpu", lambda arr: True)
+    monkeypatch.setattr(T, "device_copy_with_checksum", count_whole)
+    monkeypatch.setattr(T, "_chunked_copy_csum", count_fused)
+    monkeypatch.setattr(T, "_xla_copy", count_xla)
+
+    rs = np.random.RandomState(len(shape) + shape[0])
+    if dtype == "uint8":
+        x = jnp.asarray(rs.randint(0, 256, shape).astype(np.uint8))
+    else:
+        x = jnp.asarray(rs.randn(*shape).astype(np.float32), dtype)
+    fabric = chunked_fabric
+    _, coords, drained, _ = _stub_port(fabric, device=jax.devices()[0])
+    unchecked0 = int(ici_unchecked_segments.get_value())
     try:
-        ch = Channel(
-            ChannelOptions(timeout_ms=30000, ici_device=jax.devices()[0])
-        )
-        assert ch.init(addr) == 0
-        stub = echo_stub(ch)
-        x = jnp.ones((512, 512), jnp.float32)
-        c = Controller()
-        c.request_attachment.append_device(x)
-        stub.Echo(c, EchoRequest(message="bulk"))
-        assert not c.failed(), c.error_text()
-        out = c.response_attachment.device_arrays()[0]
-        assert out is not x
-        np.testing.assert_array_equal(np.asarray(out), np.asarray(x))
+        frame = IOBuf()
+        frame.append_device(x)
+        assert fabric.send(frame, coords, fresh_coords()) == 0
+        assert _wait_for(lambda: len(drained) == 1)
+        assert drained[0] is frame
     finally:
-        srv.stop()
+        fabric.unregister(coords)
+    (ref,) = frame.device_segments()
+    assert calls == [(program, chunks)], calls
+    assert ref.array is not x, "transmit must produce a fresh buffer"
+    assert ref.array.shape == x.shape and ref.array.dtype == x.dtype
+    np.testing.assert_array_equal(np.asarray(ref.array), np.asarray(x))
+    unchecked = int(ici_unchecked_segments.get_value()) - unchecked0
+    if program == "xla":
+        assert ref.csum is None and unchecked == 1
+    else:
+        want = whole(T.lanes_view(x), interpret=True)[1]
+        assert float(ref.csum) == float(want) and unchecked == 0
+
+
+@pytest.mark.parametrize(
+    "shape,walked",
+    [
+        ((248, 128), None),  # under the line: no chunks, no walk
+        ((520, 128), 5),     # the fused program's 5 planned chunks
+        ((40_000,), 0),      # at the line but untileable: an empty plan
+    ],
+)
+def test_chunk_walk_follows_the_fused_plan(shape, walked):
+    """The ici.chunk walk runs only for frames at or above the size
+    line, over the plan the fused program would run — so a FaultPlan's
+    traversal indices are the chunks that dispatch."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from incubator_brpc_tpu.ops import transfer as T
+
+    x = jnp.ones(shape, jnp.float32)
+    seen = []
+    out, _ = T.transmit_array_chunked(x, 64 << 10, walk=seen.append)
+    assert seen == ([] if walked is None else [walked]), seen
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(x))
 
 
 # ---- partial pipeline failure: credits must not leak (satellite) -----------
 
 
-def test_chunk_fault_releases_window_and_surfaces_one_error(pipelined_fabric):
-    """Seeded FaultPlan fires an ici.chunk reset mid-frame: the sender
-    gets ONE ERPC error (EINTERNAL — the fabric connection stays up),
-    the receive window shows zero queued bytes afterwards, and the very
-    next call on the same socket succeeds."""
+@pytest.mark.parametrize("warm", [True, False], ids=["warm", "cold"])
+def test_chunk_fault_releases_window_and_surfaces_one_error(
+    chunked_fabric, warm
+):
+    """Seeded FaultPlan fires an ici.chunk reset mid-frame — the fused
+    program walks its chunk plan through the site before dispatch, on
+    a warm connection or on its very first frame: the sender gets ONE
+    ERPC error (EINTERNAL — the fabric connection stays up), the site
+    fired once, the receive window shows zero queued bytes afterwards,
+    and the very next call on the same socket succeeds."""
     import jax
     import jax.numpy as jnp
 
@@ -244,14 +301,15 @@ def test_chunk_fault_releases_window_and_surfaces_one_error(pipelined_fabric):
         assert ch.init(addr) == 0
         stub = echo_stub(ch)
         x = jnp.ones((1024, 256), jnp.float32)  # 1MB → 16 chunks of 64KB
-        warm = Controller()
-        warm.request_attachment.append_device(x)
-        stub.Echo(warm, EchoRequest(message="warm"))
-        assert not warm.failed(), warm.error_text()
+        if warm:
+            c0 = Controller()
+            c0.request_attachment.append_device(x)
+            stub.Echo(c0, EchoRequest(message="warm"))
+            assert not c0.failed(), c0.error_text()
 
         plan = FaultPlan(
             [FaultSpec("ici.chunk", "reset", probability=1.0, max_hits=1)],
-            seed=1234,
+            seed=1234 if warm else 77,
             name="chunk-fault",
         )
         chaos_injector.arm(plan)
@@ -264,6 +322,8 @@ def test_chunk_fault_releases_window_and_surfaces_one_error(pipelined_fabric):
             assert c.error_code == errors.EINTERNAL, (
                 c.error_code, c.error_text(),
             )
+            hits = chaos_injector.site_hits().get("ici.chunk", {})
+            assert sum(hits.values()) == 1, hits
         finally:
             chaos_injector.disarm()
         # the faulted frame reserved no window credit — nothing leaks
@@ -277,66 +337,22 @@ def test_chunk_fault_releases_window_and_surfaces_one_error(pipelined_fabric):
         srv.stop()
 
 
-def test_chunk_fault_fires_under_fused_mode_too(pipelined_fabric):
-    """The ici.chunk site must cover the DEFAULT chunk mode: fused
-    sends walk the same chunk plan through the site before dispatch,
-    so a plan targeting chunk k faults the frame under either mode."""
-    import jax
-    import jax.numpy as jnp
-
-    from incubator_brpc_tpu.chaos import FaultPlan
-    from incubator_brpc_tpu.chaos import injector as chaos_injector
-    from incubator_brpc_tpu.chaos.plan import FaultSpec
-    from incubator_brpc_tpu.client.channel import Channel, ChannelOptions
-    from incubator_brpc_tpu.client.controller import Controller
-    from incubator_brpc_tpu.models.echo import echo_stub
-    from incubator_brpc_tpu.protos.echo_pb2 import EchoRequest
-
-    pipelined_fabric.chunk_mode = "fused"
-    srv, addr = _ici_echo_server()
-    try:
-        ch = Channel(
-            ChannelOptions(timeout_ms=30000, ici_device=jax.devices()[0])
-        )
-        assert ch.init(addr) == 0
-        stub = echo_stub(ch)
-        x = jnp.ones((1024, 256), jnp.float32)
-        chaos_injector.arm(FaultPlan(
-            [FaultSpec("ici.chunk", "reset", probability=1.0, max_hits=1)],
-            seed=77, name="fused-chunk-fault",
-        ))
-        try:
-            c = Controller()
-            c.max_retry = 0
-            c.request_attachment.append_device(x)
-            stub.Echo(c, EchoRequest(message="bulk"))
-            assert c.failed() and c.error_code == errors.EINTERNAL, (
-                c.error_code, c.error_text(),
-            )
-            hits = chaos_injector.site_hits().get("ici.chunk", {})
-            assert sum(hits.values()) == 1, hits
-        finally:
-            chaos_injector.disarm()
-        assert srv._ici_port._queued_bytes == 0
-    finally:
-        srv.stop()
-
-
 # ---- coalesced delivery: send_batch / delivery_burst / execute_batch -------
 
 
-def _stub_port(fabric, window_bytes=None):
+def _stub_port(fabric, window_bytes=None, device=None):
     """Server port whose completion queue records drained frames and
     releases window credits like _drain_completions does."""
     coords = fresh_coords()
-    port = fabric.register(coords, server=object())
+    port = fabric.register(coords, server=object(), device=device)
     drained = []
     calls = []
 
     def consumer(batch):
         calls.append(len(batch))
         for (frame, src, _sender), _accepted_us in batch:
-            drained.append(bytes(frame.to_bytes()))
+            drained.append(frame if device is not None
+                           else bytes(frame.to_bytes()))
             with port._qb_lock:
                 port._queued_bytes -= len(frame)
 
@@ -514,134 +530,11 @@ def test_execution_queue_execute_batch_orders_and_drains():
     assert seen == list(range(10))
 
 
-# ---- staging ring ----------------------------------------------------------
-
-
-def test_staging_ring_bookkeeping():
-    import numpy as np
-
-    from incubator_brpc_tpu.parallel.ici import StagingRing
-
-    ring = StagingRing(depth=2, max_keys=2)
-    assert ring.acquire((4, 4), "float32") is None  # cold: caller allocates
-    a = np.zeros((4, 4), dtype=np.float32)
-    ring.release(a)
-    got = ring.acquire((4, 4), "float32")
-    assert got is a
-    assert ring.acquire((4, 4), "float32") is None  # ring emptied
-    # depth bound: a third same-shape release is dropped
-    b, c, d = (np.zeros((4, 4), dtype=np.float32) for _ in range(3))
-    for arr in (b, c, d):
-        ring.release(arr)
-    assert ring.acquire((4, 4), "float32") is b
-    assert ring.acquire((4, 4), "float32") is c
-    assert ring.acquire((4, 4), "float32") is None
-    # key bound: LRU shape evicted when a third shape arrives
-    ring.release(np.zeros((4, 4), dtype=np.float32))    # key A (recent)
-    ring.release(np.zeros((8, 8), dtype=np.float32))    # key B
-    ring.acquire((4, 4), "float32")                     # touch A → B is LRU
-    ring.release(np.zeros((2, 2), dtype=np.float32))    # key C evicts B
-    assert ring.acquire((8, 8), "float32") is None
-    assert ring.acquire((2, 2), "float32") is not None
-
-
-def test_pipelined_ring_reaches_zero_alloc_steady_state(
-    pipelined_fabric, monkeypatch
-):
-    """The staging ring's contract: frame 1 seeds the ring (all
-    misses), frame 2 onwards runs entirely on recycled slots (all
-    hits, zero new allocations).  The TPU-only kernels are routed
-    through interpret mode so the REAL orchestration — acquire,
-    chained accumulator, concat, release — runs on CPU; the checksum
-    must still equal the whole-frame kernel's."""
-    import jax.numpy as jnp
-    import numpy as np
-
-    from incubator_brpc_tpu.ops import transfer as T
-    from incubator_brpc_tpu.parallel.ici import StagingRing
-
-    chunk_op = T.device_copy_with_checksum_chunk
-    monkeypatch.setattr(T, "_on_tpu", lambda arr: True)
-    monkeypatch.setattr(
-        T,
-        "device_copy_with_checksum_chunk",
-        lambda x, acc, br, interpret=False: chunk_op(x, acc, br, True),
-    )
-    monkeypatch.setattr(
-        T,
-        "device_copy_with_checksum_chunk_into",
-        lambda x, acc, slot, br: chunk_op(x, acc, br, True),
-    )
-
-    class _Shim:
-        coords = (0, 0)
-        device = None
-        staging = StagingRing(depth=4)
-
-    shim = _Shim()
-    # 512KB at 64KB chunks, block rows 256 → chunk alignment clamps to
-    # 4 chunks of 256 rows (128KB each) = exactly ring depth
-    x = jnp.asarray(
-        np.random.RandomState(3).randn(1024, 128).astype(np.float32)
-    )
-    out, csum = pipelined_fabric._transmit_pipelined(x, shim, None)
-    np.testing.assert_array_equal(np.asarray(out), np.asarray(x))
-    whole_csum = T.device_copy_with_checksum(x, interpret=True)[1]
-    assert float(csum) == float(whole_csum)
-    seed_misses = shim.staging.misses
-    assert seed_misses == 4 and shim.staging.hits == 0
-
-    out2, csum2 = pipelined_fabric._transmit_pipelined(x, shim, None)
-    np.testing.assert_array_equal(np.asarray(out2), np.asarray(x))
-    assert float(csum2) == float(whole_csum)
-    assert shim.staging.hits == 4, "steady state must recycle every slot"
-    assert shim.staging.misses == seed_misses, "steady state must not allocate"
-
-# ---- pallas DMA lane (interpret mode = REAL kernels, DMA included) ---------
-
-
-@pytest.mark.parametrize(
-    "m,n,chunk_bytes",
-    [
-        (512, 256, 128 * 256 * 4),   # exact chunk multiples
-        (320, 256, 100 * 256 * 4),   # m not a chunk multiple (short tail)
-        (1000, 128, 4096 * 128),     # odd m: block rows fall to 8
-        (1, 128, 64),                # single-row frame, one stage
-    ],
-)
-def test_pallas_dma_checksum_equals_pr4_kernels_interpret(m, n, chunk_bytes):
-    """The double-buffered DMA kernel must agree BIT-FOR-BIT with BOTH
-    PR 4 kernels (whole-frame and fused-chunked): the DMA stage is an
-    aligned multiple of the checksum block rows, so splitting the frame
-    into semaphored stages cannot reorder the chained f32 additions.
-    Interpret mode runs the SAME kernel — DMA semaphores included —
-    through the Pallas TPU interpreter (pallas_guide)."""
-    import numpy as np
-    import jax.numpy as jnp
-
-    from incubator_brpc_tpu.ops.transfer import (
-        device_copy_with_checksum,
-        device_copy_with_checksum_chunked,
-        device_copy_with_checksum_pallas,
-    )
-
-    x = jnp.asarray(np.random.RandomState(m).randn(m, n).astype(np.float32))
-    whole_out, whole_csum = device_copy_with_checksum(x, interpret=True)
-    _, chunk_csum = device_copy_with_checksum_chunked(
-        x, chunk_bytes=chunk_bytes, interpret=True
-    )
-    dma_out, dma_csum = device_copy_with_checksum_pallas(
-        x, chunk_bytes=chunk_bytes, interpret=True
-    )
-    assert dma_out.shape == x.shape
-    np.testing.assert_array_equal(np.asarray(dma_out), np.asarray(whole_out))
-    assert float(dma_csum) == float(whole_csum) == float(chunk_csum)
-
-
-def test_pallas_one_byte_wire_tail_survives_pallas_mode(pipelined_fabric):
+def test_pallas_one_byte_wire_tail_survives_pallas_mode(chunked_fabric):
     """A host-bytes attachment whose size leaves a ONE-byte wire tail
-    must reassemble byte-exact while the fabric runs in pallas mode —
-    the device lane swap must not disturb the byte-plane chunker."""
+    must reassemble byte-exact while the fabric chunks device frames at
+    64 KiB — the device transmit must not disturb the byte-plane
+    chunker."""
     import jax
 
     from incubator_brpc_tpu.client.channel import Channel, ChannelOptions
@@ -649,7 +542,6 @@ def test_pallas_one_byte_wire_tail_survives_pallas_mode(pipelined_fabric):
     from incubator_brpc_tpu.models.echo import echo_stub
     from incubator_brpc_tpu.protos.echo_pb2 import EchoRequest
 
-    pipelined_fabric.chunk_mode = "pallas"
     srv, addr = _ici_echo_server()
     try:
         ch = Channel(
@@ -659,7 +551,7 @@ def test_pallas_one_byte_wire_tail_survives_pallas_mode(pipelined_fabric):
         stub = echo_stub(ch)
         # 4 full 64KB wire chunks + a one-byte tail
         payload = bytes(range(256)) * 1024 + b"\x7f"
-        assert len(payload) == 4 * pipelined_fabric.chunk_bytes + 1
+        assert len(payload) == 4 * chunked_fabric.chunk_bytes + 1
         c = Controller()
         c.request_attachment.append(payload)
         stub.Echo(c, EchoRequest(message="tail"))
@@ -667,241 +559,6 @@ def test_pallas_one_byte_wire_tail_survives_pallas_mode(pipelined_fabric):
         assert c.response_attachment.to_bytes() == payload
     finally:
         srv.stop()
-
-
-def test_pallas_mode_echo_content_and_fresh_buffer(
-    pipelined_fabric, monkeypatch
-):
-    """End-to-end pallas-mode echo on the HIT path (TPU check
-    monkeypatched true, DMA kernels through the interpreter): content
-    round-trips through a REAL RPC, the receiver gets a fresh buffer,
-    and the frame rode exactly one fused dispatch per direction."""
-    import functools
-
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from incubator_brpc_tpu.client.channel import Channel, ChannelOptions
-    from incubator_brpc_tpu.client.controller import Controller
-    from incubator_brpc_tpu.models.echo import echo_stub
-    from incubator_brpc_tpu.ops import transfer as T
-    from incubator_brpc_tpu.protos.echo_pb2 import EchoRequest
-    from incubator_brpc_tpu.parallel.ici import (
-        ici_pallas_fallbacks,
-        ici_pallas_frames,
-    )
-
-    orig_dma = T.device_copy_with_checksum_dma
-    monkeypatch.setattr(T, "_on_tpu", lambda arr: True)
-    monkeypatch.setattr(
-        T, "device_copy_with_checksum_dma",
-        functools.partial(orig_dma, interpret=True),
-    )
-    monkeypatch.setattr(
-        T, "device_copy_with_checksum_dma_into",
-        lambda x, slot, br, sr: orig_dma(x, br, sr, interpret=True),
-    )
-    monkeypatch.setattr(
-        T, "device_copy_with_checksum",
-        functools.partial(T.device_copy_with_checksum, interpret=True),
-    )
-
-    pipelined_fabric.chunk_mode = "pallas"
-    frames0 = int(ici_pallas_frames.get_value())
-    falls0 = int(ici_pallas_fallbacks.get_value())
-    srv, addr = _ici_echo_server()
-    try:
-        ch = Channel(
-            ChannelOptions(timeout_ms=30000, ici_device=jax.devices()[0])
-        )
-        assert ch.init(addr) == 0
-        stub = echo_stub(ch)
-        x = jnp.arange(1024 * 256, dtype=jnp.float32).reshape(1024, 256)
-        c = Controller()
-        c.request_attachment.append_device(x)
-        stub.Echo(c, EchoRequest(message="bulk"))
-        assert not c.failed(), c.error_text()
-        arrs = c.response_attachment.device_arrays()
-        assert len(arrs) == 1 and arrs[0].shape == (1024, 256)
-        assert arrs[0] is not x, "pallas transmit must produce a fresh buffer"
-        np.testing.assert_array_equal(np.asarray(arrs[0]), np.asarray(x))
-    finally:
-        srv.stop()
-    # one fused dispatch per direction (request + response), no
-    # silent fallback to the legacy pipeline
-    assert int(ici_pallas_frames.get_value()) - frames0 == 2
-    assert int(ici_pallas_fallbacks.get_value()) - falls0 == 0
-
-
-def test_chunk_fault_fires_under_pallas_mode_too(pipelined_fabric):
-    """Satellite regression: the ici.chunk site covers the pallas lane.
-    A seeded FaultPlan reset walks the SAME chunk plan pre-dispatch
-    (before the platform gate, so the off-TPU fallback frame is covered
-    too): ONE ERPC EINTERNAL, no socket teardown, zero queued bytes
-    left in the receive window, and the next call on the same fabric
-    connection succeeds."""
-    import jax
-    import jax.numpy as jnp
-
-    from incubator_brpc_tpu.chaos import FaultPlan
-    from incubator_brpc_tpu.chaos import injector as chaos_injector
-    from incubator_brpc_tpu.chaos.plan import FaultSpec
-    from incubator_brpc_tpu.client.channel import Channel, ChannelOptions
-    from incubator_brpc_tpu.client.controller import Controller
-    from incubator_brpc_tpu.models.echo import echo_stub
-    from incubator_brpc_tpu.protos.echo_pb2 import EchoRequest
-
-    pipelined_fabric.chunk_mode = "pallas"
-    srv, addr = _ici_echo_server()
-    try:
-        ch = Channel(
-            ChannelOptions(timeout_ms=30000, ici_device=jax.devices()[0])
-        )
-        assert ch.init(addr) == 0
-        stub = echo_stub(ch)
-        x = jnp.ones((1024, 256), jnp.float32)  # 1MB → 16 chunks of 64KB
-        warm = Controller()
-        warm.request_attachment.append_device(x)
-        stub.Echo(warm, EchoRequest(message="warm"))
-        assert not warm.failed(), warm.error_text()
-
-        chaos_injector.arm(FaultPlan(
-            [FaultSpec("ici.chunk", "reset", probability=1.0, max_hits=1)],
-            seed=4321, name="pallas-chunk-fault",
-        ))
-        try:
-            c = Controller()
-            c.max_retry = 0
-            c.request_attachment.append_device(x)
-            stub.Echo(c, EchoRequest(message="bulk"))
-            assert c.failed()
-            assert c.error_code == errors.EINTERNAL, (
-                c.error_code, c.error_text(),
-            )
-            hits = chaos_injector.site_hits().get("ici.chunk", {})
-            assert sum(hits.values()) == 1, hits
-        finally:
-            chaos_injector.disarm()
-        # the faulted frame reserved no window credit — nothing leaks
-        assert srv._ici_port._queued_bytes == 0
-        # and the fabric connection survived: same socket, next call ok
-        c2 = Controller()
-        c2.request_attachment.append_device(x)
-        stub.Echo(c2, EchoRequest(message="after"))
-        assert not c2.failed(), c2.error_text()
-    finally:
-        srv.stop()
-
-
-def test_pallas_ring_slot_recycles_to_allocation_free_steady_state(
-    pipelined_fabric, monkeypatch
-):
-    """The pallas lane's StagingRing contract: a released frame-shaped
-    slot is re-acquired by the next transmit of that shape (ring hit,
-    no new allocation) and the donated-slot kernel runs — with the
-    checksum still bit-equal to the whole-frame kernel's."""
-    import functools
-
-    import jax.numpy as jnp
-    import numpy as np
-
-    from incubator_brpc_tpu.ops import transfer as T
-    from incubator_brpc_tpu.parallel.ici import StagingRing
-
-    orig_dma = T.device_copy_with_checksum_dma
-    into_calls = []
-    monkeypatch.setattr(T, "_on_tpu", lambda arr: True)
-    monkeypatch.setattr(
-        T, "device_copy_with_checksum_dma",
-        functools.partial(orig_dma, interpret=True),
-    )
-
-    def _into(x, slot, br, sr):
-        into_calls.append(slot.shape)
-        return orig_dma(x, br, sr, interpret=True)
-
-    monkeypatch.setattr(T, "device_copy_with_checksum_dma_into", _into)
-
-    class _Shim:
-        coords = (0, 0)
-        device = None
-        staging = StagingRing(depth=2)
-
-    shim = _Shim()
-    pipelined_fabric.chunk_mode = "pallas"
-    x = jnp.asarray(
-        np.random.RandomState(11).randn(1024, 128).astype(np.float32)
-    )
-    whole_csum = float(T.device_copy_with_checksum(x, interpret=True)[1])
-
-    # frame 1: cold ring — miss, allocating kernel
-    out1, csum1 = pipelined_fabric._transmit_pallas(x, shim, None)
-    assert shim.staging.misses == 1 and shim.staging.hits == 0
-    assert into_calls == []
-    assert float(csum1) == whole_csum
-    # the receiver hands the delivered buffer back (response recycled)
-    shim.staging.release(out1)
-    # frame 2: ring hit — the donated-slot kernel runs on the slot
-    out2, csum2 = pipelined_fabric._transmit_pallas(x, shim, None)
-    assert shim.staging.hits == 1, "steady state must recycle the slot"
-    assert into_calls == [x.shape]
-    assert float(csum2) == whole_csum
-    np.testing.assert_array_equal(np.asarray(out2), np.asarray(x))
-
-
-def test_pallas_stacked_transmit_coalesces_same_shape_segments(
-    pipelined_fabric, monkeypatch
-):
-    """The bulk-move collective lowering at the segment level: 4
-    same-shape refs of one frame coalesce into ONE stacked kernel
-    dispatch (per-ref csum None — integrity rides the stack checksum),
-    while odd shapes return for the per-segment path."""
-    import functools
-
-    import jax.numpy as jnp
-    import numpy as np
-
-    from incubator_brpc_tpu.ops import transfer as T
-    from incubator_brpc_tpu.parallel.ici import (
-        StagingRing,
-        ici_pallas_stacked_frames,
-        ici_pallas_stacked_segments,
-    )
-
-    monkeypatch.setattr(T, "_on_tpu", lambda arr: True)
-    monkeypatch.setattr(
-        T, "device_copy_with_checksum_pallas",
-        functools.partial(T.device_copy_with_checksum_pallas, interpret=True),
-    )
-
-    class _Ref:
-        array = None
-        csum = "sentinel"
-
-    class _Shim:
-        coords = (0, 0)
-        device = None
-        staging = StagingRing(depth=2)
-
-    rng = np.random.RandomState(5)
-    same = [jnp.asarray(rng.randn(64, 128).astype(np.float32))
-            for _ in range(4)]
-    odd = jnp.asarray(rng.randn(32, 128).astype(np.float32))
-    pairs = [(_Ref(), a) for a in same] + [(_Ref(), odd)]
-
-    frames0 = int(ici_pallas_stacked_frames.get_value())
-    segs0 = int(ici_pallas_stacked_segments.get_value())
-    pipelined_fabric.chunk_mode = "pallas"
-    rest = pipelined_fabric._transmit_stacked(pairs, _Shim(), None)
-
-    # the singleton shape came back for the per-segment path
-    assert [a is odd for _, a in rest] == [True]
-    assert int(ici_pallas_stacked_frames.get_value()) - frames0 == 1
-    assert int(ici_pallas_stacked_segments.get_value()) - segs0 == 4
-    for (ref, a) in pairs[:4]:
-        assert ref.csum is None, "integrity rides the stack checksum"
-        np.testing.assert_array_equal(np.asarray(ref.array), np.asarray(a))
 
 
 # ---- PR 21: what the TPU compiler refused (tests/test_chip_compile.py
@@ -912,8 +569,8 @@ def test_pallas_stacked_transmit_coalesces_same_shape_segments(
     "dtype", ["float32", "uint8", "uint16", "uint32", "int8", "bfloat16"]
 )
 def test_every_lane_agrees_per_kernel_dtype_interpret(dtype):
-    """Whole-frame, fused-chunked, DMA and donated-slot DMA kernels copy
-    exactly and give ONE checksum for every kernel dtype — unsigned
+    """The whole-frame kernel and the fused chunk program copy exactly
+    and give ONE checksum for every kernel dtype — unsigned
     payloads (the cache's uint8 values) widen through int32, since
     Mosaic has no unsigned->f32 cast (uint32 wraps, deterministically)."""
     import numpy as np
@@ -931,21 +588,13 @@ def test_every_lane_agrees_per_kernel_dtype_interpret(dtype):
             .astype(dtype)
         )
     assert x.dtype in T.KERNEL_DTYPES
-    v, br, chunks = T.chunk_plan_for(x, 64 << 10)
-    sr = T.pallas_stage_rows(v, br)
-    assert len(chunks) >= 2 and sr, (br, chunks, sr)
-    carry = jnp.zeros((1, 256), jnp.float32)
-    into_out, into_acc = T._dma_call(v, carry, br, sr, True,
-                                     slot=jnp.zeros_like(v))
+    _, br, chunks = T.chunk_plan_for(x, 64 << 10)
+    assert len(chunks) >= 2, (br, chunks)
     runs = {
         "whole": T.device_copy_with_checksum(x, interpret=True),
         "fused": T.device_copy_with_checksum_chunked(
             x, chunk_bytes=64 << 10, interpret=True
         ),
-        "dma": T.device_copy_with_checksum_pallas(
-            x, chunk_bytes=64 << 10, interpret=True
-        ),
-        "dma_into": (into_out, jnp.sum(into_acc)),
     }
     for name, (out, _) in runs.items():
         np.testing.assert_array_equal(np.asarray(out), np.asarray(x), name)
@@ -977,37 +626,3 @@ def test_block_rows_are_sized_by_bytes():
     assert lanes_view(jnp.zeros(1 << 20, u8)).shape == (256, 4096)
     assert lanes_view(jnp.zeros(4096 * 1000, u8)).shape == (4000, 1024)
     assert lanes_view(jnp.zeros(1000, u8)) is None
-
-
-def test_dma_lane_declines_stages_of_partial_packed_rows(
-    pipelined_fabric, monkeypatch
-):
-    """Mosaic refuses a DMA stage that is not a whole number of packed
-    rows (2 rows of bf16, 4 of uint8): such a frame takes the whole-frame
-    kernel, counted as a pallas fallback, and still arrives intact."""
-    import functools
-
-    import jax.numpy as jnp
-
-    from incubator_brpc_tpu.ops import transfer as T
-    from incubator_brpc_tpu.parallel.ici import StagingRing, ici_pallas_fallbacks
-
-    monkeypatch.setattr(T, "_on_tpu", lambda arr: True)
-    monkeypatch.setattr(
-        T, "device_copy_with_checksum",
-        functools.partial(T.device_copy_with_checksum, interpret=True),
-    )
-
-    class _Shim:
-        coords = (0, 0)
-        device = None
-        staging = StagingRing(depth=2)
-
-    x = jnp.arange(3 * 4096, dtype=jnp.int32).astype(jnp.uint8).reshape(3, 4096)
-    v, br, _ = T.chunk_plan_for(x, 64)
-    assert T.pallas_stage_rows(v, br) == 0
-    pipelined_fabric.chunk_mode = "pallas"
-    falls0 = int(ici_pallas_fallbacks.get_value())
-    out, csum = pipelined_fabric._transmit_pallas(x, _Shim(), None)
-    assert int(ici_pallas_fallbacks.get_value()) == falls0 + 1
-    assert csum is not None and bool(jnp.array_equal(out, x))

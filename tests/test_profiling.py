@@ -139,13 +139,13 @@ def test_cache_store_accounting_exact_across_evict_replace_flush():
 
 
 def test_staging_ring_accounting_acquire_release_evict():
-    """ici.staging holds exactly the ring-RESIDENT slots: release()
-    charges, acquire() un-charges (the buffer becomes the frame's),
-    depth overflow drops (never charges), LRU key eviction and clear()
-    release every evicted slot's charge."""
-    from incubator_brpc_tpu.parallel.ici import StagingRing
+    """batching.pad_freelist holds exactly the ring-RESIDENT slots:
+    release() charges, acquire() un-charges (the buffer becomes the
+    caller's), depth overflow drops (never charges), LRU key eviction
+    and clear() release every evicted slot's charge."""
+    from incubator_brpc_tpu.batching.fused import StagingRing
 
-    acct = profiling.hbm_account("ici.staging")
+    acct = profiling.hbm_account("batching.pad_freelist")
     b0 = acct.live_bytes()
     ring = StagingRing(depth=2, max_keys=1)
     a = jnp.zeros((64,), jnp.float32)  # 256 bytes
@@ -163,7 +163,7 @@ def test_staging_ring_accounting_acquire_release_evict():
     ring.release(jnp.zeros((32,), jnp.float32))  # 128 bytes
     assert acct.live_bytes() - b0 == 128
     ring.clear()
-    assert acct.live_bytes() - b0 == 0, "clear leaked ici.staging charge"
+    assert acct.live_bytes() - b0 == 0, "clear leaked batching.pad_freelist charge"
 
 
 def test_ps_params_accounting_exact_put_replace_delete():

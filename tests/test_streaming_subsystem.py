@@ -562,8 +562,8 @@ def test_stream_rpcz_span_joined_to_rpc_trace():
 
 def test_stream_over_ici_device_payload():
     """The transport half of the tentpole: a stream negotiated over an
-    ici:// connection moves an HBM tensor through the fabric's chunked
-    staging-ring pipeline (frames never split device payloads here —
+    ici:// connection moves an HBM tensor through the fabric's same-chip
+    transmit (frames never split device payloads here —
     the fabric owns that), and the frames round-trip bit-exact."""
     import jax.numpy as jnp
     import numpy as np
