@@ -185,6 +185,28 @@ def transmit_kernel_checksums(x):
     return sums
 
 
+def batch_transmit_checksums(device):
+    """TPU only: a drain batch's reply transmit (``transmit_batch``) on
+    five 4 KiB f32 segments and two 4 KiB uint8 ones.  Each copy must be
+    exact and each checksum the whole-frame kernel's, bit for bit.
+    Returns the segments of each batch program."""
+    import jax
+    import jax.numpy as jnp
+
+    from incubator_brpc_tpu.ops import transfer as T
+
+    xs = [_random_on(device, (8, 128), 10 + k) for k in range(5)]
+    xs += [jax.lax.bitcast_convert_type(_random_on(device, (1024,), 20 + k),
+                                        jnp.uint8).reshape(4096)
+           for k in range(2)]
+    results, sizes = T.transmit_batch(xs)
+    for x, (out, csum) in zip(xs, results):
+        assert out is not x and bool(jnp.array_equal(out, x)), "batch copy"
+        want = float(T.device_copy_with_checksum(T.lanes_view(x))[1])
+        assert float(csum) == want, f"batch checksum {float(csum)} != {want}"
+    return sizes
+
+
 def phase_hbm_cache(device, n_values=CACHE_VALUES,
                     value_bytes=CACHE_VALUE_BYTES, odd=CACHE_ODD_BYTES,
                     sample_every=16, seed=1):
@@ -467,6 +489,7 @@ def main(argv=None) -> int:
             out["kernel_checksums"] = transmit_kernel_checksums(
                 _random_on(dev, ECHO_SHAPE, 0)
             )
+            out["batch_program_segments"] = batch_transmit_checksums(dev)
             return out
 
         out, line = _run("ici_echo", clock, ici_phase)

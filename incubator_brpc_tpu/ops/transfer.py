@@ -13,6 +13,11 @@ which picks by size alone:
 - whatever does not tile, or a dtype outside ``KERNEL_DTYPES``, or an
   array off the TPU, takes an XLA copy with no checksum.
 
+A server port's drain batch may hand the segments that would take the
+whole-frame kernel (``batchable``) to :func:`transmit_batch` instead:
+one program copies and checksums them all, block for block as the
+whole-frame kernel would.
+
 Each Pallas grid is double-buffered by the pipeline emitter (HBM→VMEM
 →HBM DMAs without hand-rolled semaphores), and both kernels decompose a
 frame into the same block sequence, so their checksums are bit-equal.
@@ -276,6 +281,102 @@ def transmit_array_chunked(arr, chunk_bytes: int = 8 << 20, walk=None):
             )
             return (out if v is arr else out.reshape(arr.shape)), csum
     return transmit_array(arr)
+
+
+# Slot counts of the drain-batch program (transmit_batch): a group of
+# same-shape segments runs in the smallest bucket that holds it, its
+# spare slots padded with its first segment; a larger group is cut into
+# programs of the largest.  Each slot is an output the host pays ~80 us
+# of dispatch for, so the buckets stay few.
+BATCH_BUCKETS = (2, 4, 8)
+
+
+def batchable(arr, chunk_bytes: int) -> bool:
+    """True when ``arr``'s same-chip transmit is the whole-frame kernel:
+    under the size line, on the kernel lane, and tiling.  Only such
+    segments may share a drain batch's program."""
+    from incubator_brpc_tpu.utils.segmentation import MIN_CHUNKS
+
+    return (
+        int(arr.nbytes) < MIN_CHUNKS * chunk_bytes
+        and kernel_lane(arr)
+        and lanes_view(arr) is not None
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _batch_copy_csum(xs, interpret: bool = False):
+    """The drain-batch transmit: every segment of ``xs`` through the
+    whole-frame kernel, in one program.  Each copy is an exact-shape
+    output of its own; the checksums come back stacked in one."""
+    outs, sums = [], []
+    for x in xs:
+        v = lanes_view(x)
+        out, csum = device_copy_with_checksum(v, interpret=interpret)
+        outs.append(out if v is x else out.reshape(x.shape))
+        sums.append(csum)
+    return tuple(outs), jnp.stack(sums)
+
+
+class StackedChecksum:
+    """One segment's checksum inside a batch program's stacked output.
+    Nothing on the hot path reads it; ``float()`` fetches it."""
+
+    __slots__ = ("stacked", "index")
+
+    def __init__(self, stacked, index: int):
+        self.stacked = stacked
+        self.index = index
+
+    def __float__(self) -> float:
+        return float(self.stacked[self.index])
+
+
+# (shape, dtype, sharding) groups whose bucket programs are compiled
+_batch_warm: set = set()
+
+
+def _warm_batch(x) -> None:
+    """The first time a (shape, dtype, device) is batched, compile the
+    program of every bucket, and the whole-frame kernel a lone segment
+    takes: no later batch of that shape compiles."""
+    for b in BATCH_BUCKETS:
+        _batch_copy_csum((x,) * b)
+    transmit_array(x)
+
+
+def transmit_batch(arrs):
+    """Same-chip transmit of ``arrs``, each ``batchable``: the segments
+    are grouped by shape, dtype and device, and each group of two or
+    more runs as programs of up to ``BATCH_BUCKETS[-1]`` segments; a
+    lone segment takes the whole-frame kernel.  Every copy and checksum
+    is bit-equal to ``device_copy_with_checksum`` on that segment.
+    Returns ``(results, sizes)``: ``(new_array, csum)`` per segment, in
+    order, and the segment count of each batch program that ran."""
+    groups = {}
+    for i, a in enumerate(arrs):
+        groups.setdefault((a.shape, a.dtype, a.sharding), []).append(i)
+    results = [None] * len(arrs)
+    sizes = []
+    top = BATCH_BUCKETS[-1]
+    for key, idx in groups.items():
+        for s in range(0, len(idx), top):
+            part = idx[s:s + top]
+            if len(part) == 1:
+                results[part[0]] = transmit_array(arrs[part[0]])
+                continue
+            xs = [arrs[i] for i in part]
+            if key not in _batch_warm:
+                _warm_batch(xs[0])
+                _batch_warm.add(key)
+            b = next(b for b in BATCH_BUCKETS if b >= len(xs))
+            copies, sums = _batch_copy_csum(
+                tuple(xs + xs[:1] * (b - len(xs)))
+            )
+            for j, i in enumerate(part):
+                results[i] = (copies[j], StackedChecksum(sums, j))
+            sizes.append(len(part))
+    return results, sizes
 
 
 @jax.jit

@@ -27,14 +27,18 @@ import contextlib
 import threading
 import time as _time
 import weakref
-from typing import Dict, List, Optional, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 from incubator_brpc_tpu import errors
 from incubator_brpc_tpu.chaos import injector as _chaos
 from incubator_brpc_tpu.observability.profiling import hbm_account, kernel_section
 from incubator_brpc_tpu.observability.span import Span
 from incubator_brpc_tpu.utils.segmentation import DEVICE_CHUNK_BYTES
-from incubator_brpc_tpu.runtime.drain import close_drain, open_drain
+from incubator_brpc_tpu.runtime.drain import (
+    close_drain,
+    current_drain,
+    open_drain,
+)
 from incubator_brpc_tpu.runtime.execution_queue import ExecutionQueue
 from incubator_brpc_tpu.metrics.reducer import Adder
 from incubator_brpc_tpu.transport import socket as socket_mod
@@ -74,6 +78,25 @@ ici_pallas_fallbacks = Adder(0).expose("rpc_ici_pallas_fallbacks")
 # chip_smoke.py holds it there, so a frame that silently leaves the
 # Pallas lane fails the smoke.
 ici_unchecked_segments = Adder(0).expose("rpc_ici_unchecked_segments")
+# Drain-batch transmits (IciFabric._flush_transmits): the programs that
+# copied two or more deferred reply segments at once, and the segments
+# they copied.  segments / programs is the batch size achieved.
+ici_batch_transmit_programs = Adder(0).expose(
+    "rpc_ici_batch_transmit_programs")
+ici_batch_transmit_segments = Adder(0).expose(
+    "rpc_ici_batch_transmit_segments")
+
+
+class _Deferred(NamedTuple):
+    """A frame whose transmit waits for its drain batch's close."""
+
+    frame: IOBuf
+    dst_port: "IciPort"
+    src: Tuple[int, int]
+    leg: Optional[Span]
+    parent: Optional[Tuple[int, int]]
+    force: bool
+    todo: List[Tuple[Any, Any]]  # (DeviceRef, array) to transmit
 
 
 class _LazyPeer:
@@ -152,10 +175,12 @@ class IciPort:
         entries = list(batch)
         # a server port's batch runs in a drain scope (runtime/drain.py):
         # a service may defer commands to the batch's close, where the
-        # scope runs them before the window credits go back
+        # scope runs them before the window credits go back; a batch of
+        # two or more frames also defers this port's small same-chip
+        # reply transmits there (IciFabric.send)
         scoped = self.server is not None
         if scoped:
-            prev = open_drain()
+            prev = open_drain(self.coords if len(entries) > 1 else None)
         try:
             for i, ((frame, peer_coords, parent), received_us) in enumerate(
                 entries
@@ -423,14 +448,26 @@ class IciFabric:
         same-device segments traverse HBM through the Pallas transmit
         op unless zero_copy — then they move by reference. Coords not
         registered in this process route over the DCN bridge
-        (parallel/dcn.py), the RDMA-TCP-bootstrap analog."""
+        (parallel/dcn.py), the RDMA-TCP-bootstrap analog.
+
+        A frame sent from a server port while that port drains a batch
+        of two or more frames on this thread defers the segments the
+        whole-frame kernel would take (``transfer.batchable``) to the
+        batch's close (``_flush_transmits``), where it is delivered; it
+        counts as sent.  Any other frame from that port delivers what
+        was deferred first, so a connection keeps its order."""
         dst_port = self.port(dst)
+        scope = current_drain()
+        if scope is not None and (_local_only or scope.owner != src):
+            scope = None
         if dst_port is None:
             if not _local_only:
                 from incubator_brpc_tpu.parallel.dcn import get_bridge
 
                 route = get_bridge().route(dst)
                 if route is not None:
+                    if scope is not None:
+                        scope.flush_one(self._flush_transmits)
                     # the DCN bridge records its own collective leg span
                     rc = route.send_frame(frame, dst, src)
                     if rc == 0:
@@ -466,9 +503,13 @@ class IciFabric:
             parent = (leg.trace_id, leg.parent_span_id)
         try:
             try:
+                todo = None
                 if dst_port.device is not None:
                     zc = self.zero_copy if zero_copy is None else zero_copy
-                    self._place_segments(frame, dst_port, zc)
+                    todo = self._place_segments(
+                        frame, dst_port, zc,
+                        defer=scope is not None and not close_after_deliver,
+                    )
             except BaseException as e:
                 # close the leg with an error first: the trace must
                 # show the hop that failed, not silently lose it
@@ -484,6 +525,14 @@ class IciFabric:
                     log_error("ici send %s->%s failed: %r", src, dst, e)
                     return errors.EINTERNAL
                 raise
+            if todo:
+                socket_mod.g_out_bytes << len(frame)
+                socket_mod.g_out_messages << 1
+                scope.defer(self._flush_transmits, _Deferred(
+                    frame, dst_port, src, leg, parent, ignore_eovercrowded,
+                    todo,
+                ))
+                return 0
             if leg is not None:
                 # placement and transmit dispatch done; delivery (inline:
                 # the whole receive side) not begun
@@ -494,6 +543,8 @@ class IciFabric:
                 # outbound metrics
                 socket_mod.g_out_bytes << len(frame)
                 socket_mod.g_out_messages << 1
+            if scope is not None:
+                scope.flush_one(self._flush_transmits)
             try:
                 delivered = dst_port.deliver(
                     frame, src, inline_ok=not _local_only,
@@ -533,6 +584,60 @@ class IciFabric:
             leg.end(0)
         return 0
 
+    def _flush_transmits(self, items) -> None:
+        """A drain batch's deferred frames, at its close: every deferred
+        segment through one batch program a shape
+        (``transfer.transmit_batch``), then each frame delivered in
+        arrival order, as ``send`` would have.  Each leg starts at the
+        flush and is placed at the program's return: the shared program
+        is charged whole to every member.  The senders were told 0, so
+        a frame refused here (a closed port, a full window, a failed
+        program) ends its leg with the error and is lost like a frame
+        in flight: its caller recovers through its deadline."""
+        from incubator_brpc_tpu.ops.transfer import transmit_batch
+
+        start = _time.time_ns() // 1000
+        segs = [ref_arr for it in items for ref_arr in it.todo]
+        try:
+            placed, sizes = transmit_batch([arr for _, arr in segs])
+        except Exception as e:  # noqa: BLE001
+            log_error("ici batch transmit of %d frame(s) failed: %r",
+                      len(items), e)
+            for it in items:
+                if it.leg is not None:
+                    it.leg.end(errors.EINTERNAL)
+            return
+        if sizes:
+            ici_batch_transmit_programs << len(sizes)
+            ici_batch_transmit_segments << sum(sizes)
+        for (ref, _), (arr, csum) in zip(segs, placed):
+            ref.array, ref.csum = arr, csum
+        placed_us = _time.time_ns() // 1000
+        refused = 0
+        for frame, dst_port, src, leg, parent, force, _ in items:
+            if leg is not None:
+                leg.start_us, leg.placed_us = start, placed_us
+            try:
+                delivered = dst_port.deliver(
+                    frame, src, inline_ok=True, force=force, parent=parent,
+                )
+            except Exception as e:  # noqa: BLE001
+                log_error("ici deferred delivery to %s failed: %r",
+                          dst_port.coords, e)
+                delivered = None
+            if not delivered:
+                refused += 1
+            if leg is not None:
+                leg.end(
+                    0 if delivered
+                    else errors.EINTERNAL if delivered is None
+                    else errors.EFAILEDSOCKET if dst_port.closed
+                    else errors.EOVERCROWDED
+                )
+        if refused:
+            log_error("ici drain batch: %d deferred frame(s) refused at "
+                      "delivery; senders recover via deadline", refused)
+
     def local_server_coords(self):
         """Server ports registered in THIS process (what the DCN hello
         advertises to peers)."""
@@ -571,10 +676,14 @@ class IciFabric:
         return _bridge is not None and _bridge.route(coords) is not None
 
     def _place_segments(self, frame: IOBuf, dst_port: IciPort,
-                        zero_copy: bool):
+                        zero_copy: bool, defer: bool = False):
+        """Place ``frame``'s device segments for ``dst_port``.  With
+        ``defer``, the segments a drain batch may transmit together are
+        left as they are and returned as ``[(ref, array), ...]``."""
         import jax
 
         device = dst_port.device
+        todo = []
         for ref in frame.device_segments():
             arr = ref.whole_array()
             if arr is None:
@@ -595,9 +704,16 @@ class IciFabric:
                 # buffer plus a device-resident integrity checksum (a
                 # handed-off buffer, a cache slab read's row slice,
                 # already traversed HBM when its program made it)
+                if defer:
+                    from incubator_brpc_tpu.ops.transfer import batchable
+
+                    if batchable(arr, self.chunk_bytes):
+                        todo.append((ref, arr))
+                        continue
                 ref.array, ref.csum = self._transmit_segment(arr, dst_port)
                 if ref.csum is None:
                     ici_unchecked_segments << 1
+        return todo
 
     def _transmit_segment(self, arr, dst_port: IciPort):
         """One device segment through the transmit op; the op picks the

@@ -10,7 +10,10 @@ the batch's device work is one program (``cache/service.py``).  The
 scope follows the fabric's ``delivery_burst`` thread-local pattern;
 nested drains (a handler whose call is served inline on the same
 thread) open scopes of their own, so no deferred reply waits on an
-outer batch.
+outer batch.  A scope's ``owner`` names the port whose batch of two or
+more frames it spans: the fabric defers that port's small same-chip
+reply transmits to the close, so the batch's copies are one program
+(``parallel.ici.IciFabric.send``).
 """
 
 from __future__ import annotations
@@ -27,10 +30,11 @@ class DrainScope:
     order.  Anything that must not overtake deferred work (a command
     that does not defer, a reply written at once) flushes first."""
 
-    __slots__ = ("pending",)
+    __slots__ = ("pending", "owner")
 
-    def __init__(self):
+    def __init__(self, owner=None):
         self.pending: Dict[Callable[[List], None], List] = {}
+        self.owner = owner
 
     def defer(self, flush: Callable[[List], None], item) -> None:
         self.pending.setdefault(flush, []).append(item)
@@ -41,17 +45,23 @@ class DrainScope:
             for fn, items in pending.items():
                 fn(items)
 
+    def flush_one(self, flush: Callable[[List], None]) -> None:
+        """Run ``flush``'s items alone, now."""
+        items = self.pending.pop(flush, None)
+        if items:
+            flush(items)
+
 
 def current_drain() -> Optional[DrainScope]:
     """The scope of the drain batch this thread is running, or None."""
     return getattr(_TLS, "scope", None)
 
 
-def open_drain() -> Optional[DrainScope]:
+def open_drain(owner=None) -> Optional[DrainScope]:
     """Open a scope on this thread; returns the one it replaces, for
     ``close_drain``."""
     prev = getattr(_TLS, "scope", None)
-    _TLS.scope = DrainScope()
+    _TLS.scope = DrainScope(owner)
     return prev
 
 

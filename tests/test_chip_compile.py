@@ -108,6 +108,8 @@ def _compile(fn, args, sharding):
         "transmit_uint8_1mib", "transmit_f32_4kib", "transmit_f32_4096x4096",
         "transmit_bf16_1024x8192", "transmit_int32_1024x4096",
         "sharded_ps_forward",
+        "batch_f32_4kib_2", "batch_f32_4kib_4", "batch_f32_4kib_8",
+        "batch_uint8_4kib_4",
     ],
 )
 def test_main_path_compiles_for_v5e(case, topo, one_chip, on_tpu):
@@ -127,6 +129,18 @@ def test_main_path_compiles_for_v5e(case, topo, one_chip, on_tpu):
             "transmit_int32_1024x4096": ((1024, 4096), jnp.int32),
         }[case]
         compiled = _compile(T.transmit_array, [(shape, dtype)], one_chip)
+    elif case.startswith("batch_"):
+        # a drain batch's reply transmits, one program a bucket; the
+        # 1-D uint8 segments are lane-viewed inside it
+        _, kind, _, bucket = case.split("_")
+        shape, dtype = {"f32": ((8, 128), jnp.float32),
+                        "uint8": ((4096,), jnp.uint8)}[kind]
+        compiled = _compile(
+            lambda *xs: T._batch_copy_csum(xs),
+            [(shape, dtype)] * int(bucket), one_chip,
+        )
+        text = compiled.as_text()
+        assert text.count("tpu_custom_call") >= int(bucket), case
     elif case.startswith("chunked_"):
         # the fabric's entry at the bulk size: the size rule sends these
         # through the fused chunk program, which only f32 compiled on

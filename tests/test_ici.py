@@ -286,3 +286,352 @@ def test_receive_window_released_when_port_closes_mid_batch():
         assert port._queued_bytes == 0
     finally:
         fabric.unregister(port.coords)
+
+
+# ---- drain-batched reply transmits ------------------------------------------
+
+
+@pytest.fixture
+def kernel_lane_here(monkeypatch):
+    """Same-chip segments take the Pallas kernels here, through the
+    interpreter (the CPU has no Mosaic), as they do on the TPU."""
+    from incubator_brpc_tpu.ops import transfer as T
+
+    whole, batch, fused = (
+        T.device_copy_with_checksum, T._batch_copy_csum, T._chunked_copy_csum
+    )
+    monkeypatch.setattr(T, "_on_tpu", lambda arr: True)
+    monkeypatch.setattr(
+        T, "device_copy_with_checksum",
+        lambda x, interpret=False: whole(x, interpret=True),
+    )
+    monkeypatch.setattr(
+        T, "_batch_copy_csum",
+        lambda xs, interpret=False: batch(xs, interpret=True),
+    )
+    monkeypatch.setattr(
+        T, "_chunked_copy_csum",
+        lambda x, chunks, block_rows, interpret: fused(
+            x, chunks=chunks, block_rows=block_rows, interpret=True),
+    )
+
+
+def _batch_counters():
+    from incubator_brpc_tpu.parallel import ici
+
+    return (int(ici.ici_batch_transmit_programs.get_value()),
+            int(ici.ici_batch_transmit_segments.get_value()))
+
+
+def _recording_port(fabric, window_bytes=None):
+    """A server port on the first device whose completion queue records
+    each frame it drains, releasing window credits as the real drain
+    does."""
+    import jax
+
+    coords = fresh_coords()
+    port = fabric.register(coords, server=object(), device=jax.devices()[0])
+    drained = []
+
+    def consumer(batch):
+        for (frame, _src, _sender), _accepted_us in batch:
+            drained.append(frame)
+            with port._qb_lock:
+                port._queued_bytes -= len(frame)
+
+    port._cq._consumer = consumer
+    if window_bytes is not None:
+        port.overcrowded_bytes = window_bytes
+    return port, drained
+
+
+def _device_frame(value, shape=(8, 128), dtype="float32"):
+    import jax.numpy as jnp
+
+    from incubator_brpc_tpu.utils.iobuf import IOBuf
+
+    x = jnp.full(shape, value, dtype)
+    frame = IOBuf()
+    frame.append_device(x)
+    return frame, x
+
+
+def _wait(pred, timeout=5.0):
+    import time as _t
+
+    deadline = _t.monotonic() + timeout
+    while _t.monotonic() < deadline:
+        if pred():
+            return True
+        _t.sleep(0.01)
+    return pred()
+
+
+def test_deferred_replies_keep_their_order_on_a_connection(kernel_lane_here):
+    """Inside a drain batch (a scope its port owns), small same-chip
+    replies wait for the close; a frame that is not deferred (host
+    bytes) delivers the pending ones first, so the receiver sees every
+    frame in the order it was sent, each device segment a fresh copy."""
+    import numpy as np
+
+    from incubator_brpc_tpu.parallel.ici import get_fabric
+    from incubator_brpc_tpu.runtime.drain import close_drain, open_drain
+    from incubator_brpc_tpu.utils.iobuf import IOBuf
+
+    fabric = get_fabric()
+    port, drained = _recording_port(fabric)
+    src = fresh_coords()
+    p0, s0 = _batch_counters()
+    frames = []
+    try:
+        prev = open_drain(src)
+        try:
+            for v in (1.0, 2.0):
+                f, x = _device_frame(v)
+                frames.append((f, x))
+                assert fabric.send(f, port.coords, src) == 0
+            # deferred: nothing delivered, no window credit taken
+            import time as _t
+
+            _t.sleep(0.05)
+            assert drained == [] and port._queued_bytes == 0
+            host = IOBuf(b"host bytes")
+            frames.append((host, None))
+            assert fabric.send(host, port.coords, src) == 0
+            assert _wait(lambda: len(drained) == 3)
+            f, x = _device_frame(4.0)
+            frames.append((f, x))
+            assert fabric.send(f, port.coords, src) == 0
+            _t.sleep(0.05)
+            assert len(drained) == 3
+        finally:
+            close_drain(prev)
+        assert _wait(lambda: len(drained) == 4)
+        assert drained == [f for f, _ in frames]
+        for f, x in frames:
+            if x is None:
+                continue
+            (ref,) = f.device_segments()
+            assert ref.array is not x
+            np.testing.assert_array_equal(np.asarray(ref.array),
+                                          np.asarray(x))
+            assert ref.csum is not None
+        # the first two shared one program; the last went alone
+        assert _batch_counters() == (p0 + 1, s0 + 2)
+        assert port._queued_bytes == 0
+    finally:
+        fabric.unregister(port.coords)
+
+
+@pytest.mark.parametrize(
+    "case",
+    ["batch_of_one", "at_the_line", "zero_copy", "handed_off", "float16",
+     "other_port"],
+)
+def test_frames_the_batch_program_does_not_take_go_at_once(
+    kernel_lane_here, monkeypatch, case
+):
+    """A drain batch of one frame, a segment at the size line (16 MiB
+    by default, the fused chunk program), a zero_copy send, a
+    handed-off buffer, a float16 payload (the XLA-copy lane) and a send
+    from another port than the one draining: each is delivered at once,
+    as before, and no batch program runs."""
+    import numpy as np
+
+    from incubator_brpc_tpu.parallel.ici import get_fabric
+    from incubator_brpc_tpu.runtime.drain import close_drain, open_drain
+    from incubator_brpc_tpu.utils.iobuf import hand_off
+
+    fabric = get_fabric()
+    # small chunks, so the size line is 128 KiB here
+    monkeypatch.setattr(fabric, "chunk_bytes", 64 << 10)
+    port, drained = _recording_port(fabric)
+    src = fresh_coords()
+    p0 = _batch_counters()
+    shape, dtype = (8, 128), "float32"
+    if case == "at_the_line":
+        shape = (256, 128)
+    elif case == "float16":
+        dtype = "float16"
+    f, x = _device_frame(3.0, shape, dtype)
+    if case == "handed_off":
+        hand_off(x)
+    owner = {"batch_of_one": None, "other_port": fresh_coords()}.get(
+        case, src)
+    try:
+        prev = open_drain(owner)
+        try:
+            assert fabric.send(f, port.coords, src,
+                               zero_copy=case == "zero_copy") == 0
+            assert _wait(lambda: len(drained) == 1)
+        finally:
+            close_drain(prev)
+        (ref,) = f.device_segments()
+        if case in ("zero_copy", "handed_off"):
+            assert ref.array is x
+        else:
+            assert ref.array is not x
+        np.testing.assert_array_equal(np.asarray(ref.array), np.asarray(x))
+        assert (ref.csum is None) == (case in ("zero_copy", "handed_off",
+                                               "float16"))
+        assert _batch_counters() == p0
+    finally:
+        fabric.unregister(port.coords)
+
+
+@pytest.mark.parametrize("refusal", ["closed", "window_full"])
+def test_refused_deferred_frames_hold_no_window_credit(
+    kernel_lane_here, refusal
+):
+    """A destination that closes (or whose window fills) between the
+    deferral and the flush refuses the frames at the flush: no window
+    credit stays reserved, nothing raises, and the legs end there."""
+    from incubator_brpc_tpu.parallel.ici import get_fabric
+    from incubator_brpc_tpu.runtime.drain import close_drain, open_drain
+
+    fabric = get_fabric()
+    port, drained = _recording_port(fabric)
+    src = fresh_coords()
+    try:
+        prev = open_drain(src)
+        try:
+            for v in range(3):
+                assert fabric.send(_device_frame(float(v))[0], port.coords,
+                                   src) == 0
+            if refusal == "closed":
+                port.close()
+            else:
+                port.overcrowded_bytes = 0
+        finally:
+            close_drain(prev)
+        import time as _t
+
+        _t.sleep(0.05)
+        assert drained == []
+        assert port._queued_bytes == 0
+    finally:
+        fabric.unregister(port.coords)
+
+
+@pytest.mark.parametrize("action", ["drop", "reset", "delay_us"])
+def test_chaos_ici_send_still_acts_on_deferred_frames(
+    kernel_lane_here, action
+):
+    """The ici.send site sees every frame of a drain batch as it is
+    sent: a drop loses the frame (the sender hears 0), a reset fails
+    it, a delay stretches the send and the frame is still deferred."""
+    import time as _t
+
+    from incubator_brpc_tpu.chaos import FaultPlan
+    from incubator_brpc_tpu.chaos import injector as chaos_injector
+    from incubator_brpc_tpu.chaos.plan import FaultSpec
+    from incubator_brpc_tpu.parallel.ici import get_fabric
+    from incubator_brpc_tpu.runtime.drain import close_drain, open_drain
+
+    fabric = get_fabric()
+    port, drained = _recording_port(fabric)
+    src = fresh_coords()
+    first, _ = _device_frame(1.0)
+    second, _ = _device_frame(2.0)
+    plan = FaultPlan(
+        [FaultSpec("ici.send", action, arg=20_000, max_hits=1)],
+        seed=5, name="deferred-send",
+    )
+    try:
+        prev = open_drain(src)
+        try:
+            chaos_injector.arm(plan)
+            try:
+                t0 = _t.monotonic()
+                rc = fabric.send(first, port.coords, src)
+                took = _t.monotonic() - t0
+            finally:
+                chaos_injector.disarm()
+            assert fabric.send(second, port.coords, src) == 0
+            assert drained == []
+        finally:
+            close_drain(prev)
+        if action == "drop":
+            assert rc == 0
+            want = [second]
+        elif action == "reset":
+            assert rc == errors.EFAILEDSOCKET
+            want = [second]
+        else:
+            assert rc == 0 and took >= 0.02
+            want = [first, second]
+        assert _wait(lambda: len(drained) == len(want))
+        _t.sleep(0.05)
+        assert drained == want
+        assert port._queued_bytes == 0
+    finally:
+        fabric.unregister(port.coords)
+
+
+def test_echo_replies_of_one_drain_batch_share_one_program(kernel_lane_here):
+    """End to end under usercode_in_dispatcher: while the first call's
+    handler holds the server port's drain, four callers' requests
+    queue; they drain as one batch, and their four replies are copied
+    by one batch program — each caller still gets its own payload back,
+    bit-equal, in a fresh buffer."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from incubator_brpc_tpu.server.server import Server, ServerOptions
+
+    gate = threading.Event()
+
+    class GatedEcho(EchoService):
+        SERVICE_NAME = "EchoService"
+
+        def Echo(self, controller, request, response, done):
+            if request.message == "gate":
+                gate.wait(20)
+            super().Echo(controller, request, response, done)
+
+    dev = jax.devices()[0]
+    srv = Server(ServerOptions(usercode_in_dispatcher=True))
+    srv.add_service(GatedEcho())
+    s, c = fresh_coords()
+    assert srv.start_ici(s, c, device=dev) == 0
+    addr = f"ici://slice{s}/chip{c}"
+    results = {}
+
+    def call(name, x):
+        ch = Channel(ChannelOptions(timeout_ms=30000, ici_device=dev))
+        assert ch.init(addr) == 0
+        ctrl = Controller()
+        ctrl.request_attachment.append_device(x)
+        echo_stub(ch).Echo(ctrl, EchoRequest(message=name))
+        results[name] = (ctrl, x)
+
+    try:
+        warm = jnp.zeros((8, 128), jnp.float32)
+        call("warm", warm)  # compiles the single-frame programs first
+        p0, s0 = _batch_counters()
+        first = threading.Thread(target=call, args=("gate", warm))
+        first.start()
+        assert _wait(lambda: srv._ici_port._cq._running, timeout=30)
+        xs = {f"c{i}": jnp.full((8, 128), float(i), jnp.float32)
+              for i in range(4)}
+        ths = [threading.Thread(target=call, args=(n, x))
+               for n, x in xs.items()]
+        for t in ths:
+            t.start()
+        assert _wait(lambda: len(srv._ici_port._cq) == 4, timeout=30)
+        gate.set()
+        for t in [first, *ths]:
+            t.join(60)
+        for name, x in xs.items():
+            ctrl, _ = results[name]
+            assert not ctrl.failed(), ctrl.error_text()
+            (out,) = ctrl.response_attachment.device_arrays()
+            assert out is not x
+            np.testing.assert_array_equal(np.asarray(out), np.asarray(x))
+        assert not results["gate"][0].failed()
+        assert _batch_counters() == (p0 + 1, s0 + 4)
+        assert srv._ici_port._queued_bytes == 0
+    finally:
+        gate.set()
+        srv.stop()

@@ -626,3 +626,142 @@ def test_block_rows_are_sized_by_bytes():
     assert lanes_view(jnp.zeros(1 << 20, u8)).shape == (256, 4096)
     assert lanes_view(jnp.zeros(4096 * 1000, u8)).shape == (4000, 1024)
     assert lanes_view(jnp.zeros(1000, u8)) is None
+
+
+# ---- the drain-batch transmit program (transfer.transmit_batch) -------------
+
+
+@pytest.fixture
+def batch_lane(monkeypatch):
+    """The kernel lane here: arrays count as on the TPU, and the
+    whole-frame kernel and the batch program run through the Pallas
+    interpreter.  Yields the bucket of every batch program run."""
+    from incubator_brpc_tpu.ops import transfer as T
+
+    whole, batch = T.device_copy_with_checksum, T._batch_copy_csum
+    buckets = []
+
+    def run_batch(xs, interpret=False):
+        buckets.append(len(xs))
+        return batch(xs, interpret=True)
+
+    monkeypatch.setattr(T, "_on_tpu", lambda arr: True)
+    monkeypatch.setattr(
+        T, "device_copy_with_checksum",
+        lambda x, interpret=False: whole(x, interpret=True),
+    )
+    monkeypatch.setattr(T, "_batch_copy_csum", run_batch)
+    yield buckets
+
+
+def _segments(spec):
+    """Arrays from ``[(count, shape, dtype), ...]``, each distinct."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    out = []
+    for count, shape, dtype in spec:
+        for _ in range(count):
+            rs = np.random.RandomState(len(out) + 1)
+            if dtype == "uint8":
+                out.append(jnp.asarray(rs.randint(0, 256, shape), jnp.uint8))
+            else:
+                out.append(jnp.asarray(rs.randn(*shape), dtype))
+    return out
+
+
+@pytest.mark.parametrize(
+    "spec,sizes,buckets",
+    [
+        ([(2, (8, 128), "float32")], [2], [2]),
+        # 3 in a bucket of 4: one padded slot
+        ([(3, (8, 128), "float32")], [3], [4]),
+        ([(4, (8, 128), "float32")], [4], [4]),
+        # 5 in a bucket of 8: three padded slots
+        ([(5, (8, 128), "float32")], [5], [8]),
+        ([(8, (8, 128), "float32")], [8], [8]),
+        # beyond the largest bucket: cut into programs of 8; a lone
+        # leftover takes the whole-frame kernel
+        ([(11, (8, 128), "float32")], [8, 3], [8, 4]),
+        ([(9, (8, 128), "float32")], [8], [8]),
+        # mixed shapes and dtypes: one program a shape; uint8 1-D is
+        # lane-viewed, and the lone bf16 goes alone
+        ([(3, (8, 128), "float32"), (2, (4096,), "uint8"),
+          (1, (4, 8, 128), "bfloat16")], [3, 2], [4, 2]),
+    ],
+    ids=["2", "3_padded", "4", "5_padded", "8", "11_cut", "9_lone",
+         "mixed"],
+)
+def test_batch_program_equals_whole_frame_kernel_interpret(
+    batch_lane, spec, sizes, buckets
+):
+    """Every segment of a drain batch comes back as a fresh, bit-equal
+    copy of its own shape, with the whole-frame kernel's checksum on
+    that segment bit for bit, for every bucket, padding included."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from incubator_brpc_tpu.ops import transfer as T
+
+    xs = _segments(spec)
+    # interleave the shapes, as replies of a drain batch arrive
+    xs = xs[::2] + xs[1::2]
+    assert all(T.batchable(x, 8 << 20) for x in xs)
+    T.transmit_batch(xs)  # the first batch of a shape warms its buckets
+    batch_lane.clear()
+    results, got_sizes = T.transmit_batch(xs)
+    assert got_sizes == sizes
+    assert batch_lane == buckets, batch_lane
+    assert len(results) == len(xs)
+    for x, (out, csum) in zip(xs, results):
+        assert out is not x
+        assert out.shape == x.shape and out.dtype == x.dtype
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(x))
+        want = T.device_copy_with_checksum(T.lanes_view(x))[1]
+        assert float(csum) == float(want)
+    stacked = [c.stacked for _, c in results
+               if isinstance(c, T.StackedChecksum)]
+    # one stacked checksum output a program, sized by its bucket
+    assert sorted({s.shape[0] for s in stacked}) == sorted(set(buckets))
+    assert all(s.dtype == jnp.float32 for s in stacked)
+
+
+def test_batch_warm_up_compiles_every_bucket_once(batch_lane):
+    """The first batch of a shape runs every bucket's program (and the
+    whole-frame kernel a lone segment takes); a later batch of that
+    shape runs only its own program."""
+    from incubator_brpc_tpu.ops import transfer as T
+
+    T._batch_warm.clear()
+    T.transmit_batch(_segments([(3, (16, 128), "float32")]))
+    assert batch_lane == [*T.BATCH_BUCKETS, 4]
+    T.transmit_batch(_segments([(2, (16, 128), "float32")]))
+    assert batch_lane == [*T.BATCH_BUCKETS, 4, 2]
+
+
+@pytest.mark.parametrize(
+    "shape,dtype,nbytes_line",
+    [
+        ((512, 256), "float16", 8 << 20),   # no kernel compiles for it
+        ((40_000,), "float32", 8 << 20),    # not a multiple of 128 lanes
+        ((256, 128), "float32", 64 << 10),  # at the size line: fused
+    ],
+    ids=["float16", "untileable", "at_the_line"],
+)
+def test_what_the_whole_frame_kernel_does_not_take_is_not_batched(
+    monkeypatch, shape, dtype, nbytes_line
+):
+    """A float16 or untileable segment keeps the XLA-copy lane, and one
+    at the size line keeps the fused chunk program: none is batchable."""
+    import jax.numpy as jnp
+
+    from incubator_brpc_tpu.ops import transfer as T
+
+    monkeypatch.setattr(T, "_on_tpu", lambda arr: True)
+    x = jnp.ones(shape, dtype)
+    assert not T.batchable(x, nbytes_line)
+    if dtype == "float16" or shape == (40_000,):
+        out, csum = T.transmit_array(x)
+        assert csum is None and bool(jnp.array_equal(out, x))
+    monkeypatch.setattr(T, "_on_tpu", lambda arr: False)
+    assert not T.batchable(jnp.ones((8, 128), jnp.float32), nbytes_line)
